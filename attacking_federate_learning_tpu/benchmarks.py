@@ -3,14 +3,16 @@
 BASELINE.md lists five benchmark configurations (from BASELINE.json) to
 fill with measured numbers.  This driver runs them end to end through the
 real engine and emits one JSON line per cell (rounds/sec, final accuracy,
-ASR where applicable):
+ASR where applicable), each stamped with the device it ran on:
 
     python -m attacking_federate_learning_tpu.benchmarks --rounds 10
 
-``--scale`` shrinks client counts for CPU runs (defaults to 1.0 on an
-accelerator, 0.1 on CPU — the shapes stay faithful, only n shrinks);
-``--cells`` selects a subset.  Cell 5 (the 10k-client non-IID grid) is the
-overnight north star and only runs when asked for explicitly.
+The command line is a device measurement: it needs the TPU and exits
+non-zero without one (no CPU fallback); :func:`run_cells` is the
+backend-agnostic runner the CPU tests drive.  ``--scale`` shrinks client
+counts (the shapes stay faithful, only n shrinks); ``--cells`` selects a
+subset.  Cell 5 (the 10k-client non-IID grid) is the overnight north
+star and only runs when asked for explicitly.
 """
 
 from __future__ import annotations
@@ -113,63 +115,28 @@ def run_cell(name, overrides, attack, rounds, scale, log_dir):
     return out
 
 
-def main(argv=None):
-    from attacking_federate_learning_tpu.utils.backend import (
-        enable_compile_cache, ensure_live_backend
-    )
+def run_cells(wanted, rounds, scale, log_dir, strict=True):
+    """Run the selected (1-based) cells on the current backend; print
+    and return one device-stamped record per cell."""
+    from attacking_federate_learning_tpu.utils.backend import device_stamp
 
-    ensure_live_backend()
-    enable_compile_cache()
-    import jax
-
-    p = argparse.ArgumentParser(description=__doc__)
-    p.add_argument("--rounds", type=int, default=10)
-    p.add_argument("--scale", type=float, default=None,
-                   help="client-count multiplier (default 1.0 on an "
-                        "accelerator, 0.1 on CPU)")
-    p.add_argument("--cells", type=str, default=None,
-                   help="comma-separated 1-based cell indices; default "
-                        "1,2,3,4 on an accelerator, 1,2,4 on CPU (cell "
-                        "3's ResNet shadow-train compile is impractical "
-                        "on one CPU core; 5 = the 10k grid north star)")
-    p.add_argument("--log-dir", type=str, default="logs")
-    p.add_argument("--strict", dest="strict", action="store_true",
-                   default=True,
-                   help="exit nonzero if any requested cell failed "
-                        "(default: on — an unattended end-of-round sweep "
-                        "must distinguish 'failed' from 'not requested')")
-    p.add_argument("--no-strict", dest="strict", action="store_false")
-    args = p.parse_args(argv)
-
-    on_accel = jax.devices()[0].platform not in ("cpu",)
-    scale = args.scale if args.scale is not None else (
-        1.0 if on_accel else 0.1)
-    cells_arg = args.cells or ("1,2,3,4" if on_accel else "1,2,4")
-    wanted = {int(x) for x in cells_arg.split(",")}
+    stamp = device_stamp()
     results = []
     for i, (name, overrides, attack, desc) in enumerate(_cells(), 1):
         if i not in wanted:
             continue
-        if name == "noniid_10k_grid" and not on_accel:
-            # The documented CPU-backend policy (BASELINE.md round 5):
-            # 'xla' stays the product default for bit-stability, and the
-            # benchmark drivers opt into the native host kernel
-            # explicitly in the 10k regime — the XLA:CPU stable argsort
-            # at full scale is ~minutes PER ROUND (measured 943.5 s per
-            # call at n=10,240), vs ~27.5 s native.
-            overrides = dict(overrides, trimmed_mean_impl="host",
-                             bulyan_trim_impl="host")
         print(f"# cell {i}: {desc} (scale {scale})", file=sys.stderr,
               flush=True)
         try:
-            cell = run_cell(name, overrides, attack, args.rounds, scale,
-                            args.log_dir)
+            cell = run_cell(name, overrides, attack, rounds, scale,
+                            log_dir)
         except Exception as e:  # record, keep going
             cell = {"cell": name, "failed": f"{type(e).__name__}: {e}"}
+        cell.update(stamp)
         results.append(cell)
         print(json.dumps(cell), flush=True)
     failed = [c["cell"] for c in results if "failed" in c]
-    if args.strict and failed:
+    if strict and failed:
         # Loud failure for unattended sweeps: a failed cell must not look
         # like an unrequested one.  The full result list (successful
         # cells included) rides on the exception for programmatic
@@ -179,6 +146,33 @@ def main(argv=None):
         err.results = results
         raise err
     return results
+
+
+def main(argv=None):
+    from attacking_federate_learning_tpu.utils.backend import (
+        enable_compile_cache, require_tpu
+    )
+
+    p = argparse.ArgumentParser(description=__doc__)
+    p.add_argument("--rounds", type=int, default=10)
+    p.add_argument("--scale", type=float, default=1.0,
+                   help="client-count multiplier")
+    p.add_argument("--cells", type=str, default="1,2,3,4",
+                   help="comma-separated 1-based cell indices "
+                        "(5 = the 10k grid north star)")
+    p.add_argument("--log-dir", type=str, default="logs")
+    p.add_argument("--strict", dest="strict", action="store_true",
+                   default=True,
+                   help="exit nonzero if any requested cell failed "
+                        "(default: on — an unattended end-of-round sweep "
+                        "must distinguish 'failed' from 'not requested')")
+    p.add_argument("--no-strict", dest="strict", action="store_false")
+    args = p.parse_args(argv)
+
+    require_tpu("benchmarks")
+    enable_compile_cache()
+    return run_cells({int(x) for x in args.cells.split(",")}, args.rounds,
+                     args.scale, args.log_dir, strict=args.strict)
 
 
 if __name__ == "__main__":

@@ -49,8 +49,8 @@ def main(argv=None) -> int:
                         "(0 = unbounded; needs --cache-dir)")
     p.add_argument("--deadline", default=None, type=float,
                    metavar="SECS",
-                   help="wall-clock budget for THIS invocation (the "
-                        "relay-window seam): past it the campaign "
+                   help="wall-clock budget for THIS invocation (a "
+                        "time-boxed machine): past it the campaign "
                         "checkpoints cleanly and exits 75")
     p.add_argument("--max-retries", default=2, type=int,
                    help="per-cell supervisor retry budget")

@@ -5,8 +5,8 @@ One campaign = one ordered pass over the expanded cells of a
 journal (journal.py).  Three scheduling decisions live here:
 
 - **Ordering** (:func:`order_cells`): priority bands first (higher
-  runs first — the relay-window rule: the cells you must have land
-  before the window closes), then compile-cache grouping inside each
+  runs first — on a time-boxed machine the cells you must have land
+  before the budget runs out), then compile-cache grouping inside each
   band — cells sharing an HLO signature (spec.py:hlo_signature) run
   adjacently so recompiles of shared programs hit the persistent
   cache while their entries are still resident.  ``--order shuffled``
@@ -28,7 +28,7 @@ journal (journal.py).  Three scheduling decisions live here:
   campaign manifest.
 
 - **Deadline** (``deadline_s``): a wall-clock budget per invocation
-  (the relay-window seam).  The scheduler checks it before launching
+  (a time-boxed machine).  The scheduler checks it before launching
   each cell; past the deadline it writes a clean 'deadline' manifest
   and exits :data:`EXIT_DEADLINE` (75, EX_TEMPFAIL — resumable), and
   a re-invoke completes only the remaining cells.
@@ -575,7 +575,7 @@ class Campaign:
                     continue
                 if (self.deadline_s
                         and self.clock() - t0 > self.deadline_s):
-                    # The relay-window seam: checkpoint cleanly, leave
+                    # Out of budget: checkpoint cleanly, leave
                     # the remaining cells pending, exit resumable.
                     self.emit("deadline",
                               elapsed_s=round(self.clock() - t0, 2),

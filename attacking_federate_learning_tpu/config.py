@@ -314,10 +314,10 @@ class ExperimentConfig:
     # Measured-walls observatory (utils/walls.py): 0 = off; K > 0 times
     # every span/eval on the host clock at the existing eval-boundary
     # fetch (schema-v10 'wall' events, source='host') and captures one
-    # profiler trace per K eval intervals, booked onto the stage
-    # taxonomy (source='trace').  Capture is CPU-safe / TPU-gated
-    # (utils/profiling.py:device_trace); the compiled round programs
-    # are pinned byte-identical with this on or off.
+    # profiler trace per K eval intervals, booked onto the stage set
+    # (source='trace'), on whatever backend the run is on
+    # (utils/profiling.py:xla_trace); the compiled round programs are
+    # pinned byte-identical with this on or off.
     profile_every: int = 0
     checkpoint_acc_threshold: float = 70.0  # reference main.py:84
     output: Optional[str] = None     # tee file, reference main.py:13-18
@@ -454,8 +454,11 @@ class ExperimentConfig:
     # TrimmedMean/Median via the tiled per-d-block selection kernels
     # (masked/weighted seams included, so fault/async/hierarchical
     # rounds compose), Bulyan via pallas distances + the traced
-    # selection loop + the pallas trim tail.  Falls back to
-    # interpret=True off-TPU so CPU CI runs the same kernel bodies.
+    # selection loop + the pallas trim tail.  Off-TPU the kernels run
+    # interpret=True (the CPU test mode); on TPU jax 0.9.0's Pallas
+    # lowering has no sort, so every sort-based kernel of the suite
+    # raises a NotImplementedError naming itself (never interpret, never
+    # the XLA twin) — only the distance kernel compiles through Mosaic.
     # Composition matrix (rejected loudly below): covers the mask-aware
     # kernel family only, excludes the host kernels and the staged
     # (host-eager) backdoor seam, and needs an in-program distance

@@ -461,7 +461,7 @@ class FederatedExperiment:
         check_tier2_args(cfg.defense, cfg.megabatch, self._tier1_f)
         check_tier2_args(self._tier2_name, S, self._tier2_f)
         # Stage ledger: the tier-2 shard reduction carries its own
-        # taxonomy stage, distinct from the per-shard tier-1 kernel.
+        # ledger stage, distinct from the per-shard tier-1 kernel.
         self._tier2_fn = stage_wrapped(TIER2_DEFENSES[self._tier2_name],
                                        "tier2_aggregate")
 
@@ -2639,11 +2639,12 @@ class FederatedExperiment:
         return cache[key]
 
     def _book_span_walls(self, logger, trace_dir: str, count: int):
-        """Book one profiled span capture onto the stage taxonomy and
+        """Book one profiled span capture onto the stage set and
         emit the schema-v10 'wall' event (source='trace').  Returns the
-        WallRecord, or None when the capture produced no trace (the
-        device_trace no-op path on an un-gated accelerator) — walls
-        observability must never sink the run it measures."""
+        WallRecord, or None when booking failed or the capture left no
+        trace — walls observability must never sink the run it
+        measures, so both are counted (``wall_booking_failures``) and
+        reported in the run's exit summary instead of raised."""
         from attacking_federate_learning_tpu.utils.walls import (
             book_trace
         )
@@ -2656,8 +2657,10 @@ class FederatedExperiment:
         except Exception as e:          # noqa: BLE001 — observability
             logger.print(f"[walls] booking failed: "
                          f"{type(e).__name__}: {e}")
-            return None
-        if rec is not None and logger is not None:
+            rec = None
+        if rec is None:
+            self.wall_booking_failures += 1
+        elif logger is not None:
             logger.record(**rec.wall_event())
         return rec
 
@@ -3132,6 +3135,7 @@ class FederatedExperiment:
         logger = logger or RunLogger(cfg, cfg.output, cfg.log_dir)
         test_size = len(self.dataset.test_y)
         self._telemetry_winners = []
+        self.wall_booking_failures = 0
 
         def phase(name, sync=None):
             if timer is None:
@@ -3230,7 +3234,7 @@ class FederatedExperiment:
         # only — the per-round paths already carry --profile's
         # PhaseTimer): every span is timed on the host clock at its
         # existing boundary, and every K-th eval interval additionally
-        # runs under a profiler capture booked onto the stage taxonomy
+        # runs under a profiler capture booked onto the stage set
         # (utils/walls.py).  Off (the default), none of this executes —
         # no extra syncs, no events, and the compiled programs are
         # pinned byte-identical either way (tests/test_walls.py).
@@ -3268,7 +3272,7 @@ class FederatedExperiment:
                                               "walltrace", f"r{epoch}")
                                  if profiled else None)
                     t_span = time.perf_counter()
-                    with _prof.device_trace(trace_dir):
+                    with _prof.xla_trace(trace_dir):
                         self.run_span(epoch, count)
                         # The sync the host wall needs; the span paths
                         # fetch at this boundary anyway, so nothing new
@@ -3351,7 +3355,7 @@ class FederatedExperiment:
                 # Replayed evals (journal) are skipped entirely: eval is
                 # pure observation of the deterministically-recomputed
                 # state, so re-running it would only duplicate 'eval'
-                # events and burn the resume window.
+                # events and waste the resumed attempt's time.
                 # The lambda reads `correct` after the block assigns it, so
                 # the timer blocks on the eval outputs, not stale state.
                 t_eval = time.perf_counter()
@@ -3466,7 +3470,11 @@ class FederatedExperiment:
             except OSError as e:       # an unwritable index must not
                 logger.print(f"[registry] stamp failed: {e}")  # fail a
                 #                                           finished run
+        if self.wall_booking_failures:
+            logger.print(f"[walls] {self.wall_booking_failures} profiled "
+                         f"span(s) produced no wall booking")
         logger.finish()
         return {"accuracies": logger.accuracies,
                 "epochs": logger.accuracies_epochs,
-                "final_weights": self.state.weights}
+                "final_weights": self.state.weights,
+                "wall_booking_failures": self.wall_booking_failures}

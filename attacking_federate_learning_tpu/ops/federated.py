@@ -203,9 +203,6 @@ def _client_map_spmd(shard_fn, placement: Placement, plan, *args,
     shapes and (ulp-band) values to the sequential scan path."""
     import functools
 
-    from attacking_federate_learning_tpu.parallel.distances import (
-        _pvary, shard_map
-    )
     from attacking_federate_learning_tpu.parallel.mesh import CLIENTS
     from jax.sharding import PartitionSpec as P
 
@@ -217,8 +214,8 @@ def _client_map_spmd(shard_fn, placement: Placement, plan, *args,
                 + tuple(P(CLIENTS) for _ in sid_ops))
 
     @functools.partial(
-        shard_map, mesh=plan.mesh, in_specs=in_specs,
-        out_specs=P(), check_rep=False)
+        jax.shard_map, mesh=plan.mesh, in_specs=in_specs,
+        out_specs=P(), check_vma=False)
     def run(*operands):
         dev_grids = operands[:len(grids)]
         dev_sids = operands[len(grids):]
@@ -239,7 +236,8 @@ def _client_map_spmd(shard_fn, placement: Placement, plan, *args,
 
                 xs = grid
             _, stacked = lax.scan(
-                body, _pvary(jnp.zeros((), jnp.int32), CLIENTS), xs)
+                body, lax.pcast(jnp.zeros((), jnp.int32), CLIENTS,
+                                to="varying"), xs)
             pieces.append(stacked)
         local = (pieces[0] if len(pieces) == 1
                  else jax.tree_util.tree_map(

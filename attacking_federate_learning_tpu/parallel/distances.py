@@ -29,28 +29,6 @@ from jax.sharding import PartitionSpec as P
 from attacking_federate_learning_tpu.ops.distances import cross_sq_distances
 from attacking_federate_learning_tpu.parallel.mesh import CLIENTS
 
-# shard_map's spelling has moved across jax versions: top-level
-# ``jax.shard_map`` in current releases, ``jax.experimental.shard_map``
-# before that.  Resolve once at import so these kernels run on either —
-# an AttributeError at call time (the old hardcoded ``jax.shard_map``)
-# took every blockwise-distance test down with it.
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:                                           # pragma: no cover
-    from jax.experimental.shard_map import shard_map
-
-
-def _pvary(x, axis):
-    """Mark a scan carry device-varying where the running jax requires
-    it (``lax.pvary`` in current jax, ``lax.pcast`` in the 0.9-era
-    spelling); older versions have no varying-type system and take the
-    carry as-is."""
-    if hasattr(lax, "pvary"):
-        return lax.pvary(x, axis)
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, axis, to="varying")
-    return x
-
 
 def _tile(a_blk, b_blk):
     # Shared math with the single-device kernel (incl. the bf16 f32-accum
@@ -59,7 +37,7 @@ def _tile(a_blk, b_blk):
 
 
 def pairwise_distances_allgather(G, mesh, axis=CLIENTS):
-    @functools.partial(shard_map, mesh=mesh,
+    @functools.partial(jax.shard_map, mesh=mesh,
                        in_specs=P(axis, None), out_specs=P(axis, None))
     def block(gb):
         g_all = lax.all_gather(gb, axis, tiled=True)      # (n, d)
@@ -73,7 +51,7 @@ def pairwise_distances_allgather(G, mesh, axis=CLIENTS):
 def pairwise_distances_ring(G, mesh, axis=CLIENTS):
     p = mesh.shape[axis]
 
-    @functools.partial(shard_map, mesh=mesh,
+    @functools.partial(jax.shard_map, mesh=mesh,
                        in_specs=P(axis, None), out_specs=P(axis, None))
     def block(gb):
         me = lax.axis_index(axis)
@@ -91,13 +69,13 @@ def pairwise_distances_ring(G, mesh, axis=CLIENTS):
             return (remote, src, out), None
 
         # Varying carry: the accumulator is device-varying (holds
-        # per-shard tiles); jax versions with a varying-type system
-        # require the scan carry marked so (_pvary resolves the
-        # spelling).  f32 always: the cross_sq_distances tiles
-        # accumulate f32 even for bf16 operands
+        # per-shard tiles) and shard_map's varying-type check requires
+        # the scan carry marked so.  f32 always: the cross_sq_distances
+        # tiles accumulate f32 even for bf16 operands
         # (distance_dtype='bfloat16'), and the carry must match the
         # tile dtype.
-        out0 = _pvary(jnp.zeros((blk, n), jnp.float32), axis)
+        out0 = lax.pcast(jnp.zeros((blk, n), jnp.float32), axis,
+                         to="varying")
         src0 = jnp.asarray(me, jnp.int32)
         (_, _, out), _ = lax.scan(step, (gb, src0, out0), None, length=p)
         return out

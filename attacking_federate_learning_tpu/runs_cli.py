@@ -31,7 +31,7 @@ the experiment flag surface stays reference-verbatim).  Verbs:
   rejection reason.  Refreshes the registry first (campaign cells
   finish out-of-band, so a cold index would lie)
 - ``runs attribution Q [B]`` — per-stage cost table (the ISSUE-15
-  taxonomy: deliver/quarantine/protect/tier1_aggregate/
+  stages: deliver/quarantine/protect/tier1_aggregate/
   tier2_aggregate/apply) and per-seam wire-bytes table from a run's
   schema-v9 ``stage_cost``/``wire_bytes`` events (any --cost-report
   run carries them; campaign cells do automatically).  A second query

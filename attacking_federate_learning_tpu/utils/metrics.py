@@ -52,7 +52,7 @@ adopted, deadline checkpoints — written to the campaign's own
 engine); v9 adds the stage & wire ledger kinds (utils/costs.py,
 emitted by CompileLedger.emit under --cost-report) — ``stage_cost``
 (one per compiled entry point: the whole-program FLOPs/bytes/temp
-partitioned across the canonical stage taxonomy ``deliver →
+partitioned across the canonical stage set ``deliver →
 quarantine → protect → tier1_aggregate → tier2_aggregate → apply``
 plus the unattributed residual and the modeled coverage) and
 ``wire_bytes`` (one per run: bytes-per-round on every protocol seam —
@@ -62,7 +62,7 @@ observatory (utils/walls.py, ``--profile-every``): one record per
 measured wall, either host-clock span/eval timing at the engine's
 eval-boundary fetch (``source='host'``: wall_s, rounds, rounds/s —
 no new host callbacks in-jit) or a profiler-trace capture booked
-onto the stage taxonomy (``source='trace'``: per-stage microseconds
+onto the stage set (``source='trace'``: per-stage microseconds
 + unattributed residual summing exactly to wall_s, with op-event
 coverage riding along) — the runtime twin of v9's modeled
 ``stage_cost``; v11 adds ``traffic`` — one population-traffic record
@@ -222,7 +222,7 @@ EVENT_KINDS = {
     # source='host': host-clock timing at the engine's existing eval-
     # boundary fetch (span wall + rounds + rounds/s, eval wall) — cheap,
     # every span.  source='trace': one profiled span per K eval
-    # intervals, booked onto the stage taxonomy ('stages': stage -> us,
+    # intervals, booked onto the stage set ('stages': stage -> us,
     # plus 'unattributed_us'; the partition sums to wall_s exactly) with
     # op-event 'coverage' riding along — the runtime twin of
     # 'stage_cost', joined by 'name' for measured-vs-modeled ratios
@@ -375,8 +375,8 @@ class RunLogger:
     ``heartbeat_every > 0`` starts a daemon thread that appends a small
     'heartbeat' event (schema v2) every N seconds: last-seen round, a
     rounds/s EMA, resident set size, and the age of the last REAL event
-    — so ``tail -f run.jsonl`` distinguishes a stalled TPU capture or a
-    dead relay (age grows unbounded, rss flat) from a long compile or a
+    — so ``tail -f run.jsonl`` distinguishes a stalled run or a hung
+    backend (age grows unbounded, rss flat) from a long compile or a
     long fused span (age grows, then one burst of round events).
     Heartbeats never update the last-event clock — they must not mask
     the very stall they exist to expose."""

@@ -242,7 +242,7 @@ SERIES_FIELDS = ("nonfinite_pre", "nonfinite_post", "nonfinite_agg",
                  "range_log2", "tie_rows", "cancel_bits",
                  "nonfinite_total", "tie_locked")
 
-# Which pipeline stage (utils/costs.py STAGES taxonomy) each numerics
+# Which pipeline stage (utils/costs.py STAGES list) each numerics
 # counter observes — the attribution `runs diff --band` names when two
 # runs first diverge in a margin/numerics record.
 FIELD_STAGE = {

@@ -295,7 +295,7 @@ class RunRegistry:
             entry["problems"] = ["bench JSON missing or torn"]
             return entry
         for k in ("metric", "value", "unit", "valid", "env",
-                  "phases_completed", "window_s", "run_ids"):
+                  "phases_completed", "run_ids"):
             if k in parsed:
                 entry[k] = parsed[k]
         return entry

@@ -1,8 +1,8 @@
 """Measured stage walls: book a ``jax.profiler`` trace onto the stage
-taxonomy (the runtime twin of utils/costs.py:stage_attribution).
+set (the runtime twin of utils/costs.py:stage_attribution).
 
 PR 15 priced every compiled op statically (modeled FLOPs/bytes split
-across the six-stage taxonomy).  This module measures where the *wall
+across the six-stage set).  This module measures where the *wall
 clock* actually goes: it parses the Chrome-trace JSON a
 ``jax.profiler.trace(dir)`` capture writes under
 ``<dir>/plugins/profile/<ts>/*.trace.json.gz`` and books every op
@@ -112,7 +112,7 @@ class WallRecord:
 def hlo_stage_map(text: str) -> dict:
     """Instruction name -> innermost stage token (or None) for one
     compiled HLO text — the static side of the trace join.  The token
-    rule is stage_attribution's, verbatim: the LAST taxonomy token in
+    rule is stage_attribution's, verbatim: the LAST stage token in
     the ``op_name`` scope path wins (an outer engine scope must not
     clobber the finer scopes inside)."""
     out = {}
@@ -133,8 +133,7 @@ def hlo_stage_map(text: str) -> dict:
 def find_trace_file(trace_dir: str) -> Optional[str]:
     """Newest ``*.trace.json.gz`` under a ``jax.profiler.trace`` output
     dir (``<dir>/plugins/profile/<timestamp>/<host>.trace.json.gz``),
-    or None when the capture produced nothing (dead relay, no-op
-    device_trace)."""
+    or None when the capture produced nothing."""
     hits = glob.glob(os.path.join(trace_dir, "**", "*.trace.json.gz"),
                      recursive=True)
     hits += glob.glob(os.path.join(trace_dir, "**", "*.trace.json"),
@@ -156,10 +155,10 @@ def book_events(events, stage_map: dict, name: str = "trace",
                 platform: str = "unknown",
                 rounds: Optional[int] = None,
                 trace_dir: Optional[str] = None) -> WallRecord:
-    """Book trace X events onto the stage taxonomy via the instruction
+    """Book trace X events onto the stage set via the instruction
     name -> stage join.  Every op event (name present in ``stage_map``)
     lands in exactly one bucket — its innermost stage, or
-    ``unattributed`` when its ``op_name`` carries no taxonomy token —
+    ``unattributed`` when its ``op_name`` carries no stage token —
     so the partition is exact by construction.  Non-op events are
     classified (runtime machinery vs unknown) and reported in
     coverage, never booked."""
@@ -199,8 +198,8 @@ def book_events(events, stage_map: dict, name: str = "trace",
         "unknown_us": round(unknown_us, 3),
         "unknown_events": unknown_events,
         # Fraction of non-runtime X-event time the partition explains;
-        # 0.0 on a capture with no op events (flag unset / TPU-gated
-        # no-op trace) — loud, not wrong.
+        # 0.0 on a capture with no op events (xprof flag unset) —
+        # loud, not wrong.
         "op_time_fraction": round(
             booked / (booked + unknown_us), 4)
         if (booked + unknown_us) > 0 else 0.0,
@@ -215,8 +214,8 @@ def book_trace(trace_dir: str, hlo_texts, name: str = "trace",
     """Parse the newest capture under ``trace_dir`` and book it against
     one HLO text or an iterable of texts (their instruction maps are
     unioned — a span capture may interleave several executables).
-    Returns None when the dir holds no trace (the device_trace no-op
-    path), never raises on an empty capture."""
+    Returns None when the dir holds no trace, never raises on an
+    empty capture."""
     path = find_trace_file(trace_dir)
     if path is None:
         return None

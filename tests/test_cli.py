@@ -183,3 +183,59 @@ def test_crash_still_writes_csv(tmp_path):
                 epochs=2)
     logs = tmp_path / "logs"
     assert [f for f in os.listdir(logs) if f.endswith(".jsonl")]
+
+
+# ---------------------------------------------------------------------------
+# device selection and the compile cache (utils/backend.py)
+
+def test_backend_tpu_without_a_tpu_exits_nonzero(tmp_path):
+    """--backend tpu under an inherited JAX_PLATFORMS=cpu must select the
+    TPU over the inherited value and, on a box without one, exit
+    non-zero with a one-line reason — never train on CPU.  Subprocess:
+    the selection happens before backend init, which this process has
+    long passed."""
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.run(
+        [sys.executable, "-m", "attacking_federate_learning_tpu.cli",
+         "--backend", "tpu", "-s", "SYNTH_MNIST", "-e", "2", "-c", "16",
+         "--synth-train", "256", "--synth-test", "64",
+         "--log-dir", str(tmp_path / "logs"),
+         "--run-dir", str(tmp_path / "runs")],
+        capture_output=True, text=True, timeout=300, cwd=root,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert proc.returncode != 0
+    reason = [ln for ln in proc.stderr.splitlines()
+              if ln.startswith("--backend tpu:")]
+    assert len(reason) == 1 and "TPU" in reason[0]
+    assert "Test set:" not in proc.stdout       # no round ever ran
+
+
+def test_config_dump_is_followed_by_the_device_stamp(tmp_path, capsys):
+    run_cli(tmp_path, ["-n", "8"], epochs=1)
+    out = capsys.readouterr().out
+    assert "{'device': {'platform': 'cpu'" in out
+
+
+def test_compile_cache_dir_resolution(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins verbatim (and no other directory
+    is set in code); unset, the cache is the fixed <checkout>/.jax_cache
+    with nothing host-derived under it."""
+    import jax
+
+    from attacking_federate_learning_tpu.utils import backend
+
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+        backend.enable_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == "/some/dir"
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        backend.enable_compile_cache()
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert jax.config.jax_compilation_cache_dir == os.path.join(
+            root, ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)

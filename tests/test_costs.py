@@ -616,8 +616,6 @@ def test_bench_result_embeds_env_and_cache(tmp_path):
     import bench
 
     bench.RESULT.clear()
-    prev = bench._EMITTED
-    bench._EMITTED = False
     try:
         bench.RESULT.update(metric="x", value=1.0, env={"jax": "0.0"})
         import contextlib
@@ -630,4 +628,3 @@ def test_bench_result_embeds_env_and_cache(tmp_path):
         assert set(rec["compile_cache"]) == {"hits", "misses"}
     finally:
         bench.RESULT.clear()
-        bench._EMITTED = prev

@@ -302,16 +302,16 @@ def test_check_events_accepts_v3(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# failure taxonomy + exit codes
+# failure classes + exit codes
 
-def test_classify_failure_taxonomy():
+def test_classify_failure_classes():
     assert classify_failure(EXIT_OK) == "done"
     assert classify_failure(EXIT_PREEMPTED) == "preempted"
     assert classify_failure(EXIT_DIVERGED) == "divergence"
     assert classify_failure(1, "RESOURCE_EXHAUSTED: out of memory") == "oom"
     assert classify_failure(-9, "std::bad_alloc") == "oom"
     assert classify_failure(1, "Unable to initialize backend") == "backend"
-    assert classify_failure(1, "relay connect timed out") == "backend"
+    assert classify_failure(1, "TPU initialization failed") == "backend"
     assert classify_failure(
         1, "FloatingPointError: server state diverged") == "divergence"
     assert classify_failure(-9, "") == "crash"

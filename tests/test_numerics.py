@@ -4,7 +4,7 @@ Acceptance contract: the device health counters hold their units
 (nonfinite by stage, norm dynamic range, tie proximity banded at k ulp
 of the boundary's own scale, Gram cancellation depth); the host ulp
 machinery is the shared f32 lattice (ordinals, NaN conventions, the
-f64-adjudicated verdict taxonomy the divergence ledger persists into
+f64-adjudicated verdict classes the divergence ledger persists into
 NUMERICS_BASELINE.json); numerics-off programs stay HLO byte-identical
 (the kernel seam here, all 62 perf_gate entry points plus the
 bit-identity behavioral twin in CI via --numproof); numerics without
@@ -162,7 +162,7 @@ def test_max_ulp_argmax():
     assert N.max_ulp(a, a) == (0, -1)
 
 
-def test_adjudicate_verdict_taxonomy():
+def test_adjudicate_verdict_classes():
     oracle = np.array([1.0, 2.0, 3.0], np.float64)
     o32 = oracle.astype(np.float32)
     # Bit-identical -> exact.

@@ -357,17 +357,6 @@ def test_validate_trace_names_problems():
     assert any("dur" in p for p in problems)
 
 
-def test_device_trace_noop_without_tpu_gate(tmp_path, monkeypatch):
-    from attacking_federate_learning_tpu.utils.trace_export import (
-        device_trace
-    )
-
-    monkeypatch.delenv("FL_TEST_TPU", raising=False)
-    with device_trace(str(tmp_path / "prof")):
-        pass
-    assert not os.path.exists(tmp_path / "prof")   # no capture started
-
-
 # ---------------------------------------------------------------------------
 # science gate (diff policy; the cell replays are smoke.sh leg 5)
 

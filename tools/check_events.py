@@ -23,7 +23,7 @@ kind: one campaign-scheduler transition per record — campaign
 start/done, cell start/done/failed/skipped verdicts and deadline
 checkpoints — written to runs/campaigns/<id>/events.jsonl,
 campaigns/scheduler.py — plus v9's observability kinds:
-'stage_cost' per-entry stage-taxonomy cost attributions and
+'stage_cost' per-entry per-stage cost attributions and
 'wire_bytes' per-seam wire ledgers, both emitted by --cost-report
 runs via utils/costs.py:CompileLedger.emit; with telemetry/reporting
 off neither kind may appear, the invariant

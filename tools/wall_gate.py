@@ -138,7 +138,7 @@ def measure_cell(name: str, overrides: dict, k: int,
 
     from attacking_federate_learning_tpu.utils import walls
     from attacking_federate_learning_tpu.utils.profiling import (
-        device_trace
+        xla_trace
     )
 
     exp = _pinned_experiment(overrides)
@@ -152,7 +152,7 @@ def measure_cell(name: str, overrides: dict, k: int,
     try:
         for rep in range(k):
             td = os.path.join(root, f"rep{rep}")
-            with device_trace(td):
+            with xla_trace(td):
                 exp.run_span(epoch, ROUNDS)
                 jax.block_until_ready(exp.state.weights)
             epoch += ROUNDS
