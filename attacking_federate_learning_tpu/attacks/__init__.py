@@ -3,6 +3,7 @@ from attacking_federate_learning_tpu.attacks.base import (  # noqa: F401
 )
 from attacking_federate_learning_tpu.attacks.alie import DriftAttack  # noqa: F401
 from attacking_federate_learning_tpu.utils.plugins import Registry
+from attacking_federate_learning_tpu.utils.profiling import span
 
 # Factories with the uniform signature (cfg, dataset) -> Attack, so new
 # attacks plug in the way new defenses do (the reference hardwires its two
@@ -51,6 +52,7 @@ ATTACKS.register("minsum",
                      cfg.num_std, direction=cfg.attack_direction))
 
 
+@span("setup.attacker")
 def make_attacker(cfg, dataset=None, name=None):
     """Attack selection mirroring reference main.py:44-54: a backdoor option
     picks BackdoorAttack, otherwise ALIE DriftAttack."""

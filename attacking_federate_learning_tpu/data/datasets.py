@@ -31,6 +31,7 @@ from typing import NamedTuple, Optional
 import numpy as np
 
 from attacking_federate_learning_tpu import config as C
+from attacking_federate_learning_tpu.utils.profiling import span
 
 
 class Dataset(NamedTuple):
@@ -190,6 +191,7 @@ def make_synthetic(shape, num_classes: int, n_train: int, n_test: int,
 # dispatch
 # --------------------------------------------------------------------------
 
+@span("setup.dataset")
 def load_dataset(name: str, data_dir: str = "data", seed: int = 0,
                  synth_train: int = 10000, synth_test: int = 2000,
                  ) -> Dataset:

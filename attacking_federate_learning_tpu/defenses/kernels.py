@@ -306,12 +306,15 @@ def no_defense(users_grads, users_count, corrupted_count, telemetry=False,
     return agg, {}
 
 
+@functools.partial(stage_wrapped, stage="select")
 def _krum_scores(D, users_count, corrupted_count, alive=None,
                  paper_scoring=False, method="sort"):
-    """Per-user Krum score: sum of the k smallest distances to other
-    (alive) users.  Reference behavior sums k = users_count -
-    corrupted_count (reference defences.py:26, 33-34; note the reference
-    dict holds no self-distance, which the +inf diagonal reproduces);
+    """Per-user Krum score (sub-stage ``select`` of the stage ledger, for
+    Krum and for Bulyan's selection loop): sum of the k smallest
+    distances to other (alive) users.  Reference behavior sums k =
+    users_count - corrupted_count (reference defences.py:26, 33-34; note
+    the reference dict holds no self-distance, which the +inf diagonal
+    reproduces);
     ``paper_scoring`` switches to the NIPS'17 paper's k = n - f - 2
     (SURVEY.md §2.4 #4).
 
@@ -472,10 +475,11 @@ def _krum_scores_and_index(users_grads, users_count, corrupted_count,
     pool)."""
     if D is None and scores_impl == "pallas":
         if mask is None:
-            scores = _pallas_krum_scores_guarded(
-                users_grads, users_count, corrupted_count, paper_scoring,
-                distance_dtype)
-            return scores, jnp.argmin(scores)
+            with stage_scope("select"):
+                scores = _pallas_krum_scores_guarded(
+                    users_grads, users_count, corrupted_count,
+                    paper_scoring, distance_dtype)
+                return scores, jnp.argmin(scores)
         # Masked pool: exact sort scoring over the pallas-computed
         # distance matrix (the fused kernel assumes the static pool).
         D = _distances_for(users_grads, "pallas", distance_dtype)
@@ -498,7 +502,8 @@ def _krum_scores_and_index(users_grads, users_count, corrupted_count,
     else:
         scores = _krum_scores(D, users_count, corrupted_count,
                               paper_scoring=paper_scoring, method=method)
-    return scores, jnp.argmin(scores)
+    with stage_scope("select"):
+        return scores, jnp.argmin(scores)
 
 
 def krum_select(users_grads, users_count, corrupted_count,

@@ -18,6 +18,8 @@ from __future__ import annotations
 import jax.numpy as jnp
 from jax import lax
 
+from attacking_federate_learning_tpu.utils.costs import stage_scope
+
 
 def cross_sq_distances(A, B, precision=None):
     """(m, d), (n, d) -> (m, n) squared Euclidean distances in f32.
@@ -41,8 +43,11 @@ def cross_sq_distances(A, B, precision=None):
 
 
 def pairwise_sq_distances(G, precision=None):
-    """(n, d) -> (n, n) squared Euclidean distance matrix in f32."""
-    return cross_sq_distances(G, G, precision)
+    """(n, d) -> (n, n) squared Euclidean distance matrix in f32.
+    Sub-stage ``gram`` of the stage ledger (utils/costs.py), so Krum
+    and Bulyan both carry it."""
+    with stage_scope("gram"):
+        return cross_sq_distances(G, G, precision)
 
 
 def zero_diagonal(D):
@@ -62,6 +67,10 @@ def zero_diagonal(D):
 
 def pairwise_distances(G, precision=None):
     """(n, d) -> (n, n) Euclidean distance matrix, zero diagonal."""
-    D = jnp.sqrt(pairwise_sq_distances(G, precision))
-    # Exact zeros on the diagonal (the matmul identity can leave ~1e-4 noise).
-    return zero_diagonal(D)
+    # The sqrt and the diagonal fuse into the Gram's epilogue, and a
+    # fusion is named by its root: they carry the sub-stage too.
+    with stage_scope("gram"):
+        D = jnp.sqrt(pairwise_sq_distances(G, precision))
+        # Exact zeros on the diagonal (the matmul identity can leave
+        # ~1e-4 noise).
+        return zero_diagonal(D)

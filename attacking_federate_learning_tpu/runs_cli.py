@@ -723,6 +723,15 @@ def _walls_data(events):
                     for e in evs]
             if any(v > 0 for v in vals):
                 agg["stages"][s] = _median(vals)
+        # The finer views of the same captures: device self time per
+        # sub-stage (part of its parent stage's row above) and the
+        # device's idle time by the host span that overlaps it.
+        for key in ("substages", "host_gaps"):
+            names = sorted({n for e in evs for n in (e.get(key) or {})})
+            if names:
+                agg[key] = {n: _median([float((e.get(key) or {})
+                                              .get(n, 0.0)) for e in evs])
+                            for n in names}
         covs = [(e.get("coverage") or {}).get("op_time_fraction")
                 for e in evs]
         covs = [c for c in covs if isinstance(c, (int, float))]
@@ -773,6 +782,16 @@ def _print_walls(w):
                      else f"{'-':>9}")
                   + (f"{ratio:>8.2f}" if ratio is not None
                      else f"{'-':>8}"))
+        total = sum(rows.values())
+        for key, title in (("substages", "sub-stages"),
+                           ("host_gaps", "device idle, by host span")):
+            if agg.get(key):
+                print(f"    {title}: " + ", ".join(
+                    f"{n} {us / 1e3:.3f} ms"
+                    + (f" ({us / total:.1%})"
+                       if key == "substages" and total else "")
+                    for n, us in sorted(agg[key].items(),
+                                        key=lambda kv: -kv[1])))
 
 
 def cmd_walls(reg, args):

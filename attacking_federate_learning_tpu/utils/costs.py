@@ -40,7 +40,10 @@ Stage & wire ledger (ISSUE 15).  The whole-program numbers above answer
 - **Stage attribution**: the engines annotate their round programs with
   :func:`stage_scope` — ``jax.named_scope`` under the canonical stage
   set :data:`STAGES` (``deliver → quarantine → protect →
-  tier1_aggregate → tier2_aggregate → apply``).  The scopes are
+  tier1_aggregate → tier2_aggregate → apply``), and with the
+  sub-stages :data:`SUBSTAGES` under ``deliver`` and
+  ``tier1_aggregate`` that only the measured booking (utils/walls.py)
+  reads.  The scopes are
   metadata-only: the optimized HLO stays computation-identical
   (:func:`canonical_hlo` strips op metadata and canonicalizes value
   names, so :func:`hlo_fingerprint` hashes the same program with scopes
@@ -79,6 +82,19 @@ from typing import Optional
 STAGES = ("deliver", "quarantine", "protect",
           "tier1_aggregate", "tier2_aggregate", "apply")
 _STAGE_SET = frozenset(STAGES)
+# Sub-stages, each under its parent stage's scope: what ``deliver`` and
+# ``tier1_aggregate`` lump together.  ``gather`` is the participation
+# draw + batch gather + style/augment + the reshape into local steps,
+# ``client_step`` the vmapped client update, ``craft`` the attacker's
+# rewrite of rows [0, f); ``gram`` the pairwise squared distances
+# (ops/distances.py), ``select`` Krum's scoring, sort / top-k and argmin.
+# Only the measured booking (utils/walls.py) reads them:
+# :func:`stage_attribution` and ``hlo_stage_map`` filter on
+# :data:`STAGES`, so an op under ``deliver/gather`` still books to
+# ``deliver`` there.
+SUBSTAGES = {"gather": "deliver", "client_step": "deliver",
+             "craft": "deliver",
+             "gram": "tier1_aggregate", "select": "tier1_aggregate"}
 
 _STAGE_ENV = "FL_STAGE_SCOPES"
 _stage_scopes_on = True
@@ -102,11 +118,14 @@ def set_stage_scopes(enabled: bool) -> bool:
 
 
 def stage_scope(name: str):
-    """``jax.named_scope(name)`` for a canonical stage — metadata-only
+    """``jax.named_scope(name)`` for a canonical stage or sub-stage
+    (:data:`SUBSTAGES`, entered under its parent's scope) — metadata-only
     annotation (op_name path component) on every op traced under it,
     or a no-op context when scopes are disabled.  Importable without
     jax; jax loads on first enabled use."""
-    assert name in STAGES, f"unknown stage {name!r} (stages: {STAGES})"
+    assert name in _STAGE_SET or name in SUBSTAGES, (
+        f"unknown stage {name!r} (stages: {STAGES}, "
+        f"sub-stages: {tuple(SUBSTAGES)})")
     if not stage_scopes_enabled():
         import contextlib
 
@@ -205,10 +224,17 @@ class _CacheCounters:
     misses = 0
     installed = False
     compiles: list = []     # one record per backend compile, in order
+    trace_lower: list = []  # one record per jaxpr trace / MLIR lowering
     booked = (0, 0)         # (hits, misses) already attributed
 
 
 _BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+# The compile pipeline's two stages before the backend: tracing the
+# Python function to a jaxpr and lowering the jaxpr to an MLIR module.
+_TRACE_LOWER_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jaxpr_trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jaxpr_to_mlir",
+}
 
 
 def install_cache_counters() -> None:
@@ -231,6 +257,11 @@ def install_cache_counters() -> None:
         # The hit/miss events carry no module name, but they fire inside
         # the compile they belong to — before its duration event — so
         # the counter delta since the last compile attributes them.
+        if event in _TRACE_LOWER_EVENTS:
+            _CacheCounters.trace_lower.append(
+                {"stage": _TRACE_LOWER_EVENTS[event], "name": fun_name,
+                 "secs": secs, "t": time.perf_counter()})
+            return
         if event != _BACKEND_COMPILE_EVENT:
             return
         hits0, misses0 = _CacheCounters.booked
@@ -257,6 +288,18 @@ def compile_log() -> list:
     persistent cache), 'miss' (compiled and written) or 'uncached'
     (compiled, under the persistence threshold or cache disabled)."""
     return list(_CacheCounters.compiles)
+
+
+def trace_lower_log() -> list:
+    """Every jaxpr trace and jaxpr-to-MLIR lowering since
+    install_cache_counters, in order: ``{"stage": "jaxpr_trace" |
+    "jaxpr_to_mlir", "name", "secs", "t"}`` — the part of a first call
+    that ``backend_compile_duration`` (:func:`compile_log`) leaves out.
+    ``t`` is ``time.perf_counter()`` when the stage ended.  A nested jit
+    traces inside its caller's trace, so the ``jaxpr_trace`` entries
+    overlap: take the union of the intervals ``[t - secs, t]``, not the
+    sum of ``secs``."""
+    return list(_CacheCounters.trace_lower)
 
 
 def compilation_cache_dir() -> Optional[str]:
@@ -335,23 +378,32 @@ def collective_hlo_bytes(text: str) -> dict:
 # steps over — so op_name paths may contain anything but a quote.
 _METADATA_RE = None
 _VALUE_NAME_RE = None
+# The module header's stack-frame tables (jax >= 0.9 prints them): a
+# line "FileNames" / "FunctionNames" / "FileLocations" / "StackFrames"
+# and numbered rows up to a blank line.  They index source positions,
+# which a named_scope's ``with`` line shifts: metadata, like op_name.
+_FRAME_TABLE_RE = None
 
 
 def canonical_hlo(text: str) -> str:
     """The computation-identity view of an HLO module text: op metadata
-    stripped and every %value/%computation name rewritten to its
+    and the header's stack-frame tables stripped and every
+    %value/%computation name rewritten to its
     first-appearance ordinal.  Two programs are computation-identical
     iff their canonical texts match — op_name scopes, source lines and
     instruction-id drift are all erased, while opcodes, shapes, operand
     wiring and attributes all still compare."""
     import re
 
-    global _METADATA_RE, _VALUE_NAME_RE
+    global _METADATA_RE, _VALUE_NAME_RE, _FRAME_TABLE_RE
     if _METADATA_RE is None:
         _METADATA_RE = re.compile(
             r",?\s*metadata=\{(?:[^{}\"]|\"[^\"]*\")*\}")
         _VALUE_NAME_RE = re.compile(r"%[\w.\-]+")
-    stripped = _METADATA_RE.sub("", text)
+        _FRAME_TABLE_RE = re.compile(
+            r"^(?:FileNames|FunctionNames|FileLocations|StackFrames)\n"
+            r"(?:\d+ .*\n)*\n?", re.MULTILINE)
+    stripped = _FRAME_TABLE_RE.sub("", _METADATA_RE.sub("", text))
     names: dict = {}
 
     def rename(m):

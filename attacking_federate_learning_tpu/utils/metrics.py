@@ -125,7 +125,8 @@ EVENT_KINDS = {
     "eval": {"round", "test_loss", "accuracy", "correct", "test_size"},
     # backdoor attack-success rate at eval cadence
     "asr": {"round", "attack_success_rate"},
-    # PhaseTimer summary written once at run end (--profile)
+    # recorder totals written once at run end: the loop's host phases
+    # (interval.*), plus --profile's device-synced round / eval
     "profile": {"phases"},
     # host-stream stall accounting (data/stream.py stall_stats)
     "stream": {"stream_stall_s", "stream_gets"},

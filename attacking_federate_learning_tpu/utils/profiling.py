@@ -1,10 +1,15 @@
 """Tracing / profiling hooks.
 
 The reference's only timing artifact is a wall-clock timestamp printed at run
-end (reference main.py:97; SURVEY.md §5 "tracing: absent").  Here every round
-phase (grads / attack / aggregate / eval) can be timed with a context-manager
-stopwatch that blocks on device completion, and a full XLA trace can be
-captured with ``jax.profiler`` around any region for TensorBoard/Perfetto.
+end (reference main.py:97; SURVEY.md §5 "tracing: absent").  Here the host
+side of a run is a vocabulary of named spans kept by one recorder
+(:class:`PhaseTimer`, :data:`RECORDER`): the phases of building an
+experiment (``setup.*``) and of every eval interval of the round loop
+(``interval.*``, core/engine.py ``_run_body``), each a clock pair and a
+``jax.profiler.TraceAnnotation`` of the same name, so a profiler capture
+shows them on the device trace's time base.  The device side of the
+vocabulary is utils/costs.py ``stage_scope``; utils/walls.py books a
+capture onto both.
 
 ``xla_trace`` is the one capture wrapper — the measured-walls layer
 (utils/walls.py, ``--profile-every``) and ``--trace-dir`` both run
@@ -15,10 +20,10 @@ carry per-op events at all.
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import os
 import time
-from collections import defaultdict
 from typing import Optional
 
 import jax
@@ -46,38 +51,80 @@ def ensure_op_profiling() -> bool:
 
 
 class PhaseTimer:
-    """Accumulates per-phase wall-clock, device-synchronized."""
+    """The one host-side span recorder.
 
-    def __init__(self):
-        self.totals = defaultdict(float)
-        self.counts = defaultdict(int)
+    ``span(name)`` is a ``jax.profiler.TraceAnnotation`` -- so inside any
+    profiler capture the name sits on the host plane of the same trace as
+    the device's operations -- and a ``time.perf_counter()`` pair, added
+    to the per-name totals/counts and appended to a bounded ring of
+    ``(name, start, end)``.  Nothing is synchronized unless ``sync_on``
+    is given.  With no profiler running a span costs two clock reads and
+    a no-op annotation.
+
+    :data:`RECORDER` is the process-wide instance the program's own
+    spans go to (``interval.*`` in core/engine.py ``_run_body`` /
+    ``run_span``, ``setup.*`` where an experiment is built).  A caller
+    may still hand ``run(timer=PhaseTimer())`` an instance of its own
+    (``--profile``): that forces the per-round path and device-synced
+    ``round`` / ``eval`` phases, as before."""
+
+    def __init__(self, ring: int = 16384):
+        self.totals = collections.defaultdict(float)
+        self.counts = collections.defaultdict(int)
+        self.ring = collections.deque(maxlen=ring)
 
     @contextlib.contextmanager
-    def phase(self, name: str, sync_on=None):
+    def span(self, name: str, sync_on=None):
         """``sync_on``: array (or zero-arg callable returning one, evaluated
         after the block so it can reference freshly produced state) to
-        block on before stopping the clock.  The phase is accounted even
+        block on before stopping the clock.  The span is accounted even
         when the block or the sync target raises — the wall-clock was
         spent either way."""
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
+        with jax.profiler.TraceAnnotation(name):
+            t0 = time.perf_counter()
             try:
-                if sync_on is not None:
-                    jax.block_until_ready(sync_on() if callable(sync_on)
-                                          else sync_on)
+                yield
             finally:
-                dt = time.perf_counter() - t0
-                self.totals[name] += dt
-                self.counts[name] += 1
+                try:
+                    if sync_on is not None:
+                        jax.block_until_ready(sync_on() if callable(sync_on)
+                                              else sync_on)
+                finally:
+                    t1 = time.perf_counter()
+                    self.totals[name] += t1 - t0
+                    self.counts[name] += 1
+                    self.ring.append((name, t0, t1))
 
-    def summary(self) -> dict:
-        return {name: {"total_s": round(self.totals[name], 4),
-                       "count": self.counts[name],
-                       "mean_ms": round(1e3 * self.totals[name]
-                                        / max(self.counts[name], 1), 3)}
-                for name in self.totals}
+    phase = span    # the name --profile's callers know it by
+
+    def snapshot(self) -> dict:
+        """``{"spans": [(name, start, end), ...] by start time (a parent
+        before its children), "totals": {name: seconds}, "counts"}``.
+        ``spans`` holds the newest ``ring`` spans; the totals cover every
+        span since the recorder was made."""
+        return {"spans": sorted(self.ring, key=lambda s: (s[1], -s[2])),
+                "totals": dict(self.totals), "counts": dict(self.counts)}
+
+    def summary(self, since: Optional[dict] = None) -> dict:
+        """Per-name totals, or only what was added after the
+        :meth:`snapshot` passed as ``since``."""
+        base_t = since["totals"] if since else {}
+        base_c = since["counts"] if since else {}
+        out = {}
+        for name in self.totals:
+            count = self.counts[name] - base_c.get(name, 0)
+            if count <= 0:
+                continue
+            total = self.totals[name] - base_t.get(name, 0.0)
+            out[name] = {"total_s": round(total, 4), "count": count,
+                         "mean_ms": round(1e3 * total / count, 3)}
+        return out
+
+
+RECORDER = PhaseTimer()
+# A span on the process-wide recorder: ``with span(name):``, or
+# ``@span(name)`` over a function (a contextmanager's object decorates).
+span = RECORDER.span
 
 
 @contextlib.contextmanager

@@ -2,6 +2,7 @@
 pjit path runs in CI without a TPU (SURVEY.md §4(e)).  Tests are
 CPU-only; the checks that need the chip live in chip_smoke.py."""
 
+import contextlib
 import os
 
 # Environment setup must precede backend initialization (XLA_FLAGS is
@@ -41,6 +42,21 @@ def pytest_configure(config):
         "slow: outside the tier-1 budget (tier-1 runs -m 'not slow'); "
         "e.g. the measured campaign cache-ordering proof, which spawns "
         "a child process per cell")
+
+
+@contextlib.contextmanager
+def metadata_in_cache_key():
+    """The persistent cache's key leaves op metadata out, so of two
+    programs that differ in scopes alone the second loads the first's
+    executable, metadata and all.  A test that compares their compiled
+    texts compiles both under this."""
+    name = "jax_compilation_cache_include_metadata_in_key"
+    prev = getattr(jax.config, name)
+    jax.config.update(name, True)
+    try:
+        yield
+    finally:
+        jax.config.update(name, prev)
 
 
 @pytest.fixture(scope="session")

@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import metadata_in_cache_key
 
 from attacking_federate_learning_tpu import config as C
 from attacking_federate_learning_tpu import report
@@ -446,7 +447,8 @@ def test_pallas_cell_attributes_to_tier1(tmp_path):
 def test_stage_scopes_are_metadata_only(tmp_path):
     """Scopes off must leave the compiled program identical up to
     metadata: the canonicalized fingerprint matches, while the
-    annotated text itself differs (the scopes ARE there)."""
+    annotated text itself differs (the scopes ARE there) -- the stages
+    and the sub-stages under ``deliver`` and ``tier1_aggregate``."""
     ds = load_dataset(C.SYNTH_MNIST, seed=0, synth_train=256,
                       synth_test=64)
 
@@ -460,9 +462,18 @@ def test_stage_scopes_are_metadata_only(tmp_path):
         finally:
             costs.set_stage_scopes(prev)
 
-    on, off = compiled_text(True), compiled_text(False)
+    with metadata_in_cache_key():
+        on, off = compiled_text(True), compiled_text(False)
     assert costs.hlo_fingerprint(on) == costs.hlo_fingerprint(off)
-    assert "tier1_aggregate" in on and "tier1_aggregate" not in off
+    assert costs.canonical_hlo(on) == costs.canonical_hlo(off)
+    scopes = costs.STAGES[:1] + costs.STAGES[3:4] + tuple(costs.SUBSTAGES)
+    assert set(scopes) >= {"deliver", "tier1_aggregate", "gather",
+                           "client_step", "craft", "gram", "select"}
+    for token in scopes:
+        assert f"/{token}/" in on, token
+        assert f"/{token}/" not in off, token
+    assert "StackFrames" in on and "StackFrames" not in (
+        costs.canonical_hlo(on))
 
 
 def test_wire_ledger_seam_math():

@@ -137,6 +137,8 @@ def run_cell(tag: str, overrides: dict, rounds: int,
                 "stages": {s: round(v, 3)
                            for s, v in wrec.stages.items()},
                 "unattributed_us": round(wrec.unattributed_us, 3),
+                "substages": {s: round(v, 3)
+                              for s, v in wrec.substages.items()},
                 "op_time_fraction":
                     wrec.coverage.get("op_time_fraction"),
             }
