@@ -309,7 +309,12 @@ class FederatedExperiment:
                     self.state = shardings.place_state(self.state)
             else:
                 self.shards = jnp.asarray(shards)
-                self.train_x = jnp.asarray(self.dataset.train_x)
+                # Row-contiguous storage: one sample = one (F,) row, the
+                # feature axis minor, so the batch gather moves whole
+                # rows (_gather_batches restores the sample shape).  A
+                # zero-copy view of the host array.
+                self.train_x = jnp.asarray(self.dataset.train_x.reshape(
+                    len(self.dataset.train_x), -1))
                 self.train_y = jnp.asarray(self.dataset.train_y)
                 if shardings is not None:
                     self.shards, self.train_x, self.train_y, self.state = (
@@ -721,15 +726,35 @@ class FederatedExperiment:
             return np.asarray(self._participants(t))
 
     def _gather_batches(self, t, participants=None):
-        """Round-t minibatches for the round cohort: one (m, k*B) gather
+        """Round-t minibatches for the round cohort (or the megabatch
+        whose client ids are ``participants``): one (m, k*B) row gather
         from the device-resident dataset (replaces the reference's N
         host-side DataLoaders, user.py:52-55); k = local_steps (1 in the
-        reference's FedSGD regime)."""
+        reference's FedSGD regime).
+
+        The set is stored (N, F), one sample a row, and the sample shape
+        comes back only AFTER the gather: gathered as (N, C, H, W) the
+        TPU compiler pushes the first matmul's / convolution's operand
+        layout — sample axis minor — back through the gather, which then
+        moves the batch element by element (228 ms a round at n=10,240
+        against 8.6 ms of whole rows; PERF.md section 6, PR 26)."""
         shards = (self.shards if participants is None
                   else self.shards[participants])
         idx = round_batch_indices(
             shards, t, self.cfg.batch_size * self.cfg.local_steps)
-        return self.train_x[idx], self.train_y[idx]
+        xs = self.train_x[idx].reshape(
+            idx.shape + self.dataset.train_x.shape[1:])
+        return xs, self.train_y[idx]
+
+    def _split_local_steps(self, xs, ys, participants, t):
+        """Style, augmentation, then the flat (m, k*B) batch split into
+        k local-step minibatches — the tail of the ``gather`` sub-stage,
+        shared by the flat cohort and the hierarchical megabatch."""
+        xs = self._apply_style(xs, participants)
+        xs = self._maybe_augment(xs, t)
+        k, B = self.cfg.local_steps, self.cfg.batch_size
+        xs = xs.reshape((xs.shape[0], k, B) + xs.shape[2:])
+        return xs, ys.reshape((ys.shape[0], k, B))
 
     def _compute_grads_impl(self, state: ServerState, t, batches=None,
                             part=None):
@@ -760,13 +785,7 @@ class FederatedExperiment:
                     # style rows aligned with the streamed batch.
                     part = (self._participants(t)
                             if self._style is not None else None)
-                xs = self._apply_style(xs, part)
-                xs = self._maybe_augment(xs, t)
-                # Split the flat (m, k*B) gather into k local-step
-                # minibatches.
-                k, B = cfg.local_steps, cfg.batch_size
-                xs = xs.reshape((self.m, k, B) + xs.shape[2:])
-                ys = ys.reshape((self.m, k, B))
+                xs, ys = self._split_local_steps(xs, ys, part, t)
             # Clients train at the faded lr the server dispatches
             # (reference server.py:50-52; inert at k=1, user.py:80); the
             # pseudo-gradient divides by the lr the server will multiply
@@ -1463,15 +1482,8 @@ class FederatedExperiment:
                                      self.f, self.n)
             with stage_scope("deliver"):
                 with stage_scope("gather"):
-                    shard_rows = self.shards[ids]
-                    idx = round_batch_indices(
-                        shard_rows, t, cfg.batch_size * cfg.local_steps)
-                    xs, ys = self.train_x[idx], self.train_y[idx]
-                    xs = self._apply_style(xs, ids)
-                    xs = self._maybe_augment(xs, t)
-                    k, B = cfg.local_steps, cfg.batch_size
-                    xs = xs.reshape((m, k, B) + xs.shape[2:])
-                    ys = ys.reshape((m, k, B))
+                    xs, ys = self._gather_batches(t, ids)
+                    xs, ys = self._split_local_steps(xs, ys, ids, t)
                 lr_train = faded_learning_rate(cfg.learning_rate,
                                                cfg.fading_rate, t)
                 lr_report = (lr_train if cfg.server_uses_faded_lr

@@ -11,10 +11,17 @@ The partition is materialized as an int32 index matrix ``shards`` of shape
 (n_clients, shard_len); a round's batch for all clients at once is
 
     idx = shards[:, (t*B + arange(B)) % shard_len]          # (n, B)
-    batch_x, batch_y = X[idx], Y[idx]                       # one gather
+    batch_x = X2d[idx].reshape((n, B) + sample_shape)       # one row gather
+    batch_y = Y[idx]
 
 which keeps shapes static under jit (the reference's DataLoader yields a
 short final batch instead; wrap-around is the jit-friendly equivalent).
+``X2d`` is the training set stored (N, F), one sample a contiguous row:
+the gather then moves whole rows, and the sample shape comes back after
+it (core/engine.py ``_gather_batches``).  Indexing the set in its sample
+shape, ``X[idx]`` with X (N, C, H, W), is the same tensor but lets the TPU
+compiler give the gather its consumer's layout -- sample axis minor --
+and move the batch one element at a time (PERF.md section 6, PR 26).
 
 Also provides a Dirichlet label-skew partitioner for non-IID experiments
 (no reference analog — the reference is IID-only).

@@ -1,0 +1,307 @@
+"""The batch gather reads whole sample rows (PERF.md section 6, PR 26).
+
+The device-resident training set is stored (N, F), one sample a row, and
+``FederatedExperiment._gather_batches`` restores the sample shape after
+the gather.  That is a storage change only: for every round t, cohort
+row i and position j the batch must still be
+``train_x[shards[i, (t*kB + j) % L]]`` bit for bit — whatever the
+partition, the participation, the number of local steps, the shard
+length against kB, and the engine (flat cohort or hierarchical
+megabatch).  One parametrised test per property; the reference is host
+NumPy on the dataset as loaded, never the engine's own arrays.
+"""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from attacking_federate_learning_tpu import config as C
+from attacking_federate_learning_tpu.attacks import make_attacker
+from attacking_federate_learning_tpu.config import ExperimentConfig
+from attacking_federate_learning_tpu.core.engine import FederatedExperiment
+from attacking_federate_learning_tpu.data.datasets import load_dataset
+from attacking_federate_learning_tpu.data.partition import (
+    make_shards, round_batch_indices
+)
+
+_DS = {}
+
+
+def _dataset(name, n_train):
+    if (name, n_train) not in _DS:
+        _DS[name, n_train] = load_dataset(name, seed=0, synth_train=n_train,
+                                          synth_test=32)
+    return _DS[name, n_train]
+
+
+def _experiment(n_train=96, dataset=C.SYNTH_MNIST, **kw):
+    kw.setdefault("users_count", 16)
+    kw.setdefault("mal_prop", 0.25)
+    kw.setdefault("batch_size", 8)
+    kw.setdefault("epochs", 4)
+    kw.setdefault("defense", "Krum")
+    cfg = ExperimentConfig(dataset=dataset, synth_train=n_train,
+                           synth_test=32, **kw)
+    ds = _dataset(dataset, n_train)
+    exp = FederatedExperiment(cfg, attacker=make_attacker(cfg, dataset=ds),
+                              dataset=ds)
+    return exp, ds
+
+
+def _spy(exp):
+    """Record, from inside the traced round program, what every call of
+    the gather was asked for and returned, and what the client step was
+    then fed.  Both are looked up on the instance at trace time, so the
+    flat engine and the hierarchical builder's closure both see these."""
+    seen = {"gather": [], "client": []}
+    gather, update = exp._gather_batches, exp._client_update
+
+    def keep(kind):
+        return lambda *a: seen[kind].append([np.asarray(v) for v in a])
+
+    def spy_gather(t, participants=None):
+        xs, ys = gather(t, participants)
+        ids = (jnp.arange(exp.n) if participants is None else participants)
+        jax.debug.callback(keep("gather"), t, ids, xs, ys)
+        return xs, ys
+
+    def spy_update(w, xs, ys, lr_train, lr_report):
+        jax.debug.callback(keep("client"), xs, ys)
+        return update(w, xs, ys, lr_train, lr_report)
+
+    exp._gather_batches, exp._client_update = spy_gather, spy_update
+    return seen
+
+
+def _reference(exp, ds, t, ids):
+    """Host NumPy: the rows of the set as loaded, (len(ids), kB, ...)."""
+    cfg = exp.cfg
+    shards = make_shards(cfg.partition, ds.train_y, cfg.users_count,
+                         cfg.seed, cfg.dirichlet_alpha)
+    kB = cfg.batch_size * cfg.local_steps
+    idx = np.asarray(round_batch_indices(shards[ids], int(t), kB))
+    offs = (int(t) * kB + np.arange(kB)) % shards.shape[1]
+    np.testing.assert_array_equal(idx, shards[ids][:, offs])
+    return ds.train_x[idx], ds.train_y[idx]
+
+
+def _assert_styled(exp, got, rows, ids):
+    """What the client step was fed: the rows themselves, or under
+    'femnist_style' row i through client ids[i]'s a*x + b (to an ulp:
+    the compiled program may fuse the multiply-add; a row under another
+    client's style is off by far more)."""
+    if exp._style is None:
+        np.testing.assert_array_equal(got, rows)
+    else:
+        want = np.asarray(exp._apply_style(jnp.asarray(rows),
+                                           jnp.asarray(ids)))
+        np.testing.assert_allclose(got, want, rtol=0, atol=1e-6)
+
+
+# (n_train, n, B): L = ceil(n_train / n) against kB = local_steps * B.
+SHAPES = {
+    "L6_lt_kB": (96, 16, 8),          # the MLP cell's regime: L=6 < kB
+    "L7_no_divisor": (100, 16, 8),    # L=7 divides neither 8 nor 24
+    "L64_gt_kB": (1024, 16, 8),       # the CNN cell's regime: L > kB
+}
+
+
+@pytest.mark.parametrize("engine", ["flat", "hierarchical"])
+@pytest.mark.parametrize("local_steps", [1, 3])
+@pytest.mark.parametrize("shape", sorted(SHAPES))
+@pytest.mark.parametrize("partition", ["iid", "dirichlet", "femnist_style"])
+def test_batch_is_the_sets_rows_bit_for_bit(partition, shape, local_steps,
+                                            engine):
+    n_train, n, B = SHAPES[shape]
+    kw = dict(users_count=n, batch_size=B, partition=partition,
+              local_steps=local_steps)
+    if engine == "hierarchical":
+        kw.update(aggregation="hierarchical", megabatch=4)
+    exp, ds = _experiment(n_train, **kw)
+    seen = _spy(exp)
+    rounds = [0, 1, 5]          # 5*kB >= 40 wraps every shard length here
+    for t in rounds:
+        exp.run_round(t)
+    jax.effects_barrier()
+    per_round = n // 4 if engine == "hierarchical" else 1
+    assert len(seen["gather"]) == len(seen["client"]) == (
+        len(rounds) * per_round)
+    rows_seen = {t: [] for t in rounds}
+    for (t, ids, xs, ys), (cx, cy) in zip(seen["gather"], seen["client"]):
+        ref_x, ref_y = _reference(exp, ds, t, ids)
+        assert xs.dtype == np.float32 and xs.shape == ref_x.shape
+        np.testing.assert_array_equal(xs, ref_x)
+        np.testing.assert_array_equal(ys, ref_y)
+        # ... and style + the local-step split keep every row in place.
+        m = len(ids)
+        _assert_styled(exp, cx, ref_x.reshape(
+            (m, local_steps, B) + ref_x.shape[2:]), ids)
+        np.testing.assert_array_equal(
+            cy, ref_y.reshape(m, local_steps, B))
+        rows_seen[int(t)].extend(ids.tolist())
+    for t in rounds:            # every client delivered once a round
+        assert sorted(rows_seen[t]) == list(range(n))
+
+
+@pytest.mark.parametrize("partition", ["iid", "dirichlet", "femnist_style"])
+@pytest.mark.parametrize("shape", sorted(SHAPES))
+def test_partial_participation_gathers_the_cohorts_rows(shape, partition):
+    n_train, n, B = SHAPES[shape]
+    exp, ds = _experiment(n_train, users_count=n, batch_size=B,
+                          partition=partition, participation=0.5)
+    seen = _spy(exp)
+    for t in (0, 3, 7):
+        exp.run_round(t)
+    jax.effects_barrier()
+    assert len(seen["gather"]) == 3
+    for (t, ids, xs, ys), (cx, _) in zip(seen["gather"], seen["client"]):
+        np.testing.assert_array_equal(
+            ids, np.asarray(exp._participants(jnp.asarray(t))))
+        assert len(ids) == exp.m < n
+        ref_x, ref_y = _reference(exp, ds, t, ids)
+        np.testing.assert_array_equal(xs, ref_x)
+        np.testing.assert_array_equal(ys, ref_y)
+        _assert_styled(exp, cx[:, 0], ref_x, ids)
+
+
+def test_image_samples_get_their_shape_back_after_the_gather():
+    """(N, C, H, W) sets: the augmentation and the convolution need the
+    sample shape, which the (N, F) storage restores after the gather."""
+    exp, ds = _experiment(64, dataset=C.SYNTH_CIFAR10, users_count=4,
+                          batch_size=4, model="cifar10_cnn",
+                          data_augment=True)
+    assert ds.train_x.shape[1:] == (3, 32, 32)
+    xs, ys = exp._gather_batches(jnp.asarray(2, jnp.int32))
+    ref_x, ref_y = _reference(exp, ds, 2, np.arange(4))
+    assert xs.shape == (4, 4, 3, 32, 32)
+    np.testing.assert_array_equal(np.asarray(xs), ref_x)
+    np.testing.assert_array_equal(np.asarray(ys), ref_y)
+    exp.run_round(0)            # reflect_crop_flip accepts what it gets
+    assert np.isfinite(np.asarray(exp.state.weights)).all()
+
+
+def _parent_gather(exp, ds):
+    """The formulation before PR 26: the set placed (N, ...) as loaded,
+    gathered in its sample shape."""
+    x4d, y = jnp.asarray(ds.train_x), jnp.asarray(ds.train_y)
+
+    def gather(t, participants=None):
+        shards = (exp.shards if participants is None
+                  else exp.shards[participants])
+        idx = round_batch_indices(
+            shards, t, exp.cfg.batch_size * exp.cfg.local_steps)
+        return x4d[idx], y[idx]
+
+    return gather
+
+
+@pytest.mark.parametrize("kw", [
+    dict(),
+    dict(participation=0.5, partition="femnist_style"),
+    dict(aggregation="hierarchical", megabatch=4, local_steps=3),
+], ids=["flat", "flat_partial_styled", "hierarchical_k3"])
+def test_three_rounds_equal_the_parent_formulation(kw):
+    """Krum + ALIE, three rounds as one span: final weights equal those
+    of the same run gathering from the (N, ...) set."""
+    weights = []
+    for parent in (False, True):
+        exp, ds = _experiment(100, **kw)
+        if parent:
+            exp._gather_batches = _parent_gather(exp, ds)
+        exp.run_span(0, 3)
+        weights.append(np.asarray(exp.state.weights))
+    assert np.isfinite(weights[0]).all()
+    np.testing.assert_array_equal(weights[0], weights[1])
+
+
+@pytest.mark.parametrize("partition", ["iid", "dirichlet", "femnist_style"])
+def test_one_f32_row_store_on_the_device(partition):
+    """Every partition gets the same storage — f32 rows, (N, F), feature
+    axis minor — and it is the only copy of the set on the device."""
+    exp, ds = _experiment(96, partition=partition)
+    n_train, feat = len(ds.train_x), int(np.prod(ds.train_x.shape[1:]))
+    assert exp.train_x.shape == (n_train, feat)
+    assert exp.train_x.dtype == jnp.float32 == ds.train_x.dtype
+    np.testing.assert_array_equal(np.asarray(exp.train_x),
+                                  ds.train_x.reshape(n_train, feat))
+    held = [k for k, v in vars(exp).items()
+            if isinstance(v, jax.Array) and v.size == n_train * feat]
+    assert held == ["train_x"]
+
+
+# --- what the chip's compiler makes of it (no chip needed) -----------------
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # noqa: BLE001 — no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _gather_result_layouts(hlo_text):
+    """(shape, minor_to_major) of every fusion / instruction of the entry
+    computation that gathers floating-point rows."""
+    gathering = set(re.findall(
+        r"^%?([\w.\-]+) \([^\n]*\{\n(?:(?!^\}).*\n)*?"
+        r"[^\n]*= (?:f32|bf16)\[[\d,]*\]\S* gather\(", hlo_text, re.M))
+    entry = hlo_text[hlo_text.index("\nENTRY "):]
+    out = []
+    for m in re.finditer(
+            r"= (?:f32|bf16)\[([\d,]*)\]\{([\d,]*)[^ ]* "
+            r"(?:fusion\([^\n]*calls=%([\w.\-]+)|gather\()", entry):
+        if m.group(3) is None or m.group(3) in gathering:
+            out.append(([int(v) for v in m.group(1).split(",")],
+                        [int(v) for v in m.group(2).split(",")]))
+    return out
+
+
+def test_v5e_compiles_the_gather_to_whole_rows(one_chip):
+    """The v5e compiler, given the round's gather + client step at the
+    MLP cell's widths (n cut to 1,024), writes the gathered batch with
+    the FEATURE axis minor.  Gathered in sample shape it wrote
+    f32[n*B,28,28]{0,2,1} — the sample axis minor, one element at a
+    time (ledger, PR 25: fusion.71, 2.1 s of a 13.7 s window)."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    n, B, n_train = 1024, 32, 60000
+    exp, _ = _experiment(2 * n, users_count=n, batch_size=B, mal_prop=0.0,
+                         defense="NoDefense")
+    feat = exp.train_x.shape[1]
+    shard_len = -(-n_train // n)
+
+    def deliver(w, x, y, shards, t):
+        exp.train_x, exp.train_y, exp.shards = x, y, shards
+        return exp._compute_grads_impl(exp.state._replace(weights=w), t)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    placed = exp.train_x, exp.train_y, exp.shards
+    # An executable for a described chip cannot be read back from the
+    # persistent cache without one: keep it out.
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = jax.jit(deliver).lower(
+            arg((exp.flat.dim,), jnp.float32),
+            arg((n_train, feat), jnp.float32), arg((n_train,), jnp.int32),
+            arg((n, shard_len), jnp.int32), arg((), jnp.int32),
+        ).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+        compilation_cache.reset_cache()
+        exp.train_x, exp.train_y, exp.shards = placed
+    layouts = _gather_result_layouts(text)
+    assert layouts, "no floating-point gather in the compiled program"
+    for shape, minor_to_major in layouts:
+        assert shape[-1] == feat, (shape, minor_to_major)
+        assert minor_to_major[0] == len(shape) - 1, (shape, minor_to_major)
