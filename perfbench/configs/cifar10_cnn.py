@@ -8,6 +8,8 @@ in), each followed by its bias) -- d = 117,706."""
 import numpy as np
 from numpy.lib.stride_tricks import sliding_window_view
 
+from perfbench import modelcheck
+
 WIRE_DIM = 117_706
 SHAPES = [(16, 3, 3, 3), (16,), (64, 16, 4, 4), (64,), (384, 64), (384,),
           (192, 384), (192,), (10, 192), (10,)]
@@ -51,3 +53,17 @@ def logits(w, x):
         h = np.maximum(h @ f2w.T + f2b, 0.0)
         out.append(h @ f3w.T + f3b)
     return np.concatenate(out)
+
+
+def check(exp, weights, dataset, seed):
+    return modelcheck.count_check(exp, logits, weights, dataset)
+
+
+def train_flops_per_sample():
+    """Forward 2 FLOP a multiply-add, backward twice that (both gradients
+    at every layer, the usual 3 x forward); recomputation not counted.
+    Multiply-adds: conv1 30*30*16 outputs of 3*3*3, conv2 7*7*64 outputs of
+    4*4*16, then the three linear layers."""
+    macs = (30 * 30 * 16 * 27 + 7 * 7 * 64 * 256
+            + 64 * 384 + 384 * 192 + 192 * 10)
+    return 3 * 2 * macs

@@ -14,6 +14,11 @@ SAMPLED_ROWS = 128       # for every run; a seeded sample of rows is scored
 # score is off by ~1e-6 relative, so the device's winner may trail the f64
 # winner by that much.  A bf16 Gram (~4e-3) or a dropped term fails.
 TIE_RTOL = 1e-5
+# The host never holds the whole (n, d) matrix: it pulls blocks of whole
+# columns of about this many f32 bytes (a block's f64 copy is twice that),
+# whatever n and d are.  Every quantity below is a sum or a conjunction
+# over column blocks, so the block size changes no result.
+BLOCK_BYTES = 256 * 2**20
 
 
 def ops_bytes(n, d, f):
@@ -22,28 +27,45 @@ def ops_bytes(n, d, f):
     return 2.0 * n * n * d, 4.0 * (n * d + n * n)
 
 
+def column_blocks(G):
+    """(first column, the (n, width) block as a host array) over the
+    columns of ``G``, a device or a host array."""
+    n, d = G.shape
+    width = max(1, BLOCK_BYTES // (4 * n))
+    for lo in range(0, d, width):
+        yield lo, np.asarray(G[:, lo:lo + width])
+
+
 def scores(G, rows, k):
     """f64 Krum scores of ``rows`` against all rows of G (f32, (n, d))."""
     n = G.shape[0]
-    S = G[rows].astype(np.float64)
-    sq_s = np.einsum("nd,nd->n", S, S)
-    D2 = np.empty((len(rows), n))
-    for lo in range(0, n, 1024):
-        B = G[lo:lo + 1024].astype(np.float64)
-        sq_b = np.einsum("nd,nd->n", B, B)
-        D2[:, lo:lo + 1024] = sq_s[:, None] + sq_b[None, :] - 2.0 * (S @ B.T)
+    sq, cross = np.zeros(n), np.zeros((len(rows), n))
+    for _, block in column_blocks(G):
+        B = block.astype(np.float64)
+        sq += np.einsum("nd,nd->n", B, B)
+        cross += B[rows] @ B.T
+    D2 = sq[rows][:, None] + sq[None, :] - 2.0 * cross
     D = np.sqrt(np.maximum(D2, 0.0))
     D[np.arange(len(rows)), rows] = np.inf      # a row is not its own peer
     return np.partition(D, k - 1, axis=1)[:, :k].sum(axis=1)
 
 
+def rows_equal_to(G, agg):
+    """Indices of the rows of G that equal ``agg`` in every column."""
+    same = np.ones(G.shape[0], bool)
+    for lo, block in column_blocks(G):
+        same &= (block == agg[None, lo:lo + block.shape[1]]).all(axis=1)
+    return np.flatnonzero(same)
+
+
 def check(G, n, f, agg, seed=0):
     """Is the device's aggregate the reference's?  ``G`` the (n, d) wire
-    matrix and ``agg`` the defense's output, both as the device made them."""
-    winners = np.flatnonzero(G[:, 0] == agg[0])     # then the whole row
-    winners = winners[(G[winners] == agg[None, :]).all(axis=1)]
+    matrix as the device made it (still on the device: it is read in
+    column blocks) and ``agg`` the defense's output on the host."""
+    winners = rows_equal_to(G, agg)
     if winners.size == 0:
-        return {"ok": False, "why": "the aggregate is not an input row"}
+        return {"ok": False, "why": "the aggregate is not an input row",
+                "compared": {"aggregate_not_an_input_row": [1, 0]}}
     got, k = int(winners[0]), n - f
     if n <= FULL_CHECK_ROWS:
         rows, mode = np.arange(n), "all_rows"
@@ -62,4 +84,6 @@ def check(G, n, f, agg, seed=0):
     return {"ok": verdict != "wrong_row", "verdict": verdict, "mode": mode,
             "device_winner": got, "reference_winner": best,
             "identical_winner_rows": int(winners.size),
-            "relative_score_gap": gap}
+            "relative_score_gap": gap,
+            "compared": {"aggregate_not_an_input_row": [0, 0],
+                         "defense_score_gap": [gap, TIE_RTOL]}}

@@ -53,6 +53,22 @@ def _median(values):
     return statistics.median(values) if values else None
 
 
+def interval_medians(marks, spans=None):
+    """Median milliseconds of every ``interval.*`` phase recorded between
+    the first and the last mark, by name; {} without the recorder."""
+    if spans is None:
+        got = recorded()
+        if got is None or len(marks) < 2:
+            return {}
+        spans = got[0]
+    lo, hi = marks[0][1], marks[-1][1]
+    by_name = {}
+    for name, a, b in spans:
+        if a >= lo and b <= hi and name.startswith("interval."):
+            by_name.setdefault(name, []).append((b - a) * 1e3)
+    return {name: _median(v) for name, v in sorted(by_name.items())}
+
+
 def _setup_spans(spans, before):
     """The spans of the last experiment built before ``before``: its
     ``setup.experiment`` and children, and the last ``setup.dataset`` /
@@ -100,11 +116,7 @@ def read(obs, what):
                             if name == "interval.log"])
         # the whole split goes on an earlier line, as defense_roofline's
         # ops and bytes do: every interval.* phase the window recorded
-        by_name = {}
-        for name, a, b in inside:
-            by_name.setdefault(name, []).append((b - a) * 1e3)
-        say("interval_spans_median_ms",
-            {name: _median(v) for name, v in sorted(by_name.items())})
+        say("interval_spans_median_ms", interval_medians(marks, spans))
         seams, waited = [], None
         for name, a, b in inside:
             if name == "interval.wait_device":

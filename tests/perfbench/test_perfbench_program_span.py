@@ -26,16 +26,20 @@ def test_metric_file_loads_and_names_a_layer_the_benchmark_has(name):
                            else "program_span")
     with open(os.path.join(run.ROOT, "BENCHMARK.json")) as f:
         b = json.load(f)
-    accepted = [e for e in b["per_layer"] if e["name"] not in METRICS]
+    names = [e["name"] for e in b["per_layer"]]
+    # what PR 24 accepted comes first, in the order it had; PR 25's five
+    # were appended to it, and later PRs append after them
+    accepted = b["per_layer"][:names.index("host_seam_ms")]
+    assert not {e["name"] for e in accepted} & set(METRICS)
     assert m["layer"] == METRICS[name]
     assert m["layer"] in {e["layer"] for e in accepted}
     entry = [e for e in b["per_layer"] if e["name"] == name]
     assert len(entry) == 1
     assert entry[0]["moves"] == m["moves"] == (
         "rounds_per_s" if name.startswith("host_") else "setup_s")
-    # appended: the accepted entries come first, in the order they had
-    names = [e["name"] for e in b["per_layer"]]
-    assert names[:len(accepted)] == [e["name"] for e in accepted]
+    assert names[len(accepted):len(accepted) + len(METRICS)] == [
+        "host_seam_ms", "host_log_ms", "setup_data_s", "setup_build_s",
+        "trace_lower_s"]
 
 
 def _interval(dispatch, eval_, wait, log, poll):
