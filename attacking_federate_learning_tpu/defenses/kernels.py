@@ -6,7 +6,8 @@ registry (reference defences.py:73-75) — but vectorized over the client axis
 instead of Python loops:
 
 - Krum's O(n^2 * d) pairwise-distance dict (reference defences.py:16-21)
-  becomes one Gram matmul (ops/distances.py) + a top_k reduction.
+  becomes a Gram matmul (ops/distances.py; for a large cohort its upper
+  block triangle, each pair once) + a top_k reduction.
 - TrimmedMean's per-coordinate Python loop (reference defences.py:44-52)
   becomes a stable argsort along the client axis + masked mean.
 - Bulyan's destructive dict-popping selection loop (reference

@@ -3,7 +3,8 @@
 Headline kernel: Krum robust aggregation — the reference's #1 hotspot, an
 O(n^2 d) Python dict of pairwise norms plus a per-user sort
 (reference defences.py:16-42).  Here it is the framework's dispatching
-kernel (defenses/kernels.py): one Gram matmul + top-k on the TPU MXU.
+kernel (defenses/kernels.py): a Gram matmul (the upper block triangle
+at this size, ops/distances.py) + top-k on the TPU MXU.
 The baseline is a NumPy/BLAS implementation of the same exact semantics
 (defenses/oracle.py math, vectorized Gram form — already far faster than
 the reference's Python double loop, so the reported speedup is a *lower*

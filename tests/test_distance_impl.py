@@ -507,7 +507,9 @@ def test_zero_diagonal_costs_no_more_than_eye():
     """The eye spelling pays an extra n^2-shaped construct+multiply on
     the hot path (~420 MB f32 materialized at n=10,240 before fusion
     gets a say); the iota select must be strictly cheaper in FLOPs and
-    never worse in bytes/temp on the same shape."""
+    never worse in bytes/temp on the same shape.  n = 512 is under the
+    Gram's two-block threshold (2 * GRAM_BLOCK_ROWS = 2,048), so both
+    spellings sit on the same single dot."""
     from attacking_federate_learning_tpu.ops.distances import (
         pairwise_distances, pairwise_sq_distances
     )

@@ -11,6 +11,7 @@ megabatch).  One parametrised test per property; the reference is host
 NumPy on the dataset as loaded, never the engine's own arrays.
 """
 
+import contextlib
 import re
 
 import jax
@@ -246,6 +247,22 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+@contextlib.contextmanager
+def _no_persistent_cache():
+    """An executable for a described chip cannot be read back from the
+    persistent cache without one: keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+        compilation_cache.reset_cache()
+
+
 def _gather_result_layouts(hlo_text):
     """(shape, minor_to_major) of every fusion / instruction of the entry
     computation that gathers floating-point rows."""
@@ -269,8 +286,6 @@ def test_v5e_compiles_the_gather_to_whole_rows(one_chip):
     the FEATURE axis minor.  Gathered in sample shape it wrote
     f32[n*B,28,28]{0,2,1} — the sample axis minor, one element at a
     time (ledger, PR 25: fusion.71, 2.1 s of a 13.7 s window)."""
-    from jax.experimental.compilation_cache import compilation_cache
-
     n, B, n_train = 1024, 32, 60000
     exp, _ = _experiment(2 * n, users_count=n, batch_size=B, mal_prop=0.0,
                          defense="NoDefense")
@@ -285,23 +300,45 @@ def test_v5e_compiles_the_gather_to_whole_rows(one_chip):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     placed = exp.train_x, exp.train_y, exp.shards
-    # An executable for a described chip cannot be read back from the
-    # persistent cache without one: keep it out.
-    cache = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
     try:
-        text = jax.jit(deliver).lower(
-            arg((exp.flat.dim,), jnp.float32),
-            arg((n_train, feat), jnp.float32), arg((n_train,), jnp.int32),
-            arg((n, shard_len), jnp.int32), arg((), jnp.int32),
-        ).compile().as_text()
+        with _no_persistent_cache():
+            text = jax.jit(deliver).lower(
+                arg((exp.flat.dim,), jnp.float32),
+                arg((n_train, feat), jnp.float32),
+                arg((n_train,), jnp.int32),
+                arg((n, shard_len), jnp.int32), arg((), jnp.int32),
+            ).compile().as_text()
     finally:
-        jax.config.update("jax_enable_compilation_cache", cache)
-        compilation_cache.reset_cache()
         exp.train_x, exp.train_y, exp.shards = placed
     layouts = _gather_result_layouts(text)
     assert layouts, "no floating-point gather in the compiled program"
     for shape, minor_to_major in layouts:
         assert shape[-1] == feat, (shape, minor_to_major)
         assert minor_to_major[0] == len(shape) - 1, (shape, minor_to_major)
+
+
+def test_v5e_compiles_the_gram_panels_without_copying_the_wire_matrix(
+        one_chip):
+    """Krum's Gram at the MLP cell's shape (n = 10,240, d = 79,510, f32):
+    the v5e compiler feeds each panel's convolution from row slices of G
+    in place.  A materialised ``G[i*b:]`` would be up to 3.26 GB beside a
+    3.26 GB matrix; what the block triangle may add is one (n, n) f32
+    buffer, 0.42 GB (PERF.md section 6, PR 31).  The compiler's own FLOP
+    count is the work witness at the real size: 55 of 100 blocks."""
+    from attacking_federate_learning_tpu.ops import distances
+
+    n, d = 10240, 79510
+    G = jax.ShapeDtypeStruct((n, d), jnp.float32, sharding=one_chip)
+    with _no_persistent_cache():
+        compiled = jax.jit(distances.pairwise_distances).lower(G).compile()
+    text = compiled.as_text()
+    entry = text[text.index("\nENTRY "):]
+    copies = [line.strip()[:160] for line in entry.splitlines()
+              if re.search(r"= (?:f32|bf16)\[\d+,%d\]" % d, line)
+              and " parameter(" not in line]
+    assert not copies, copies
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5 * 4 * n * n
+    blocks = n // distances.GRAM_BLOCK_ROWS
+    assert blocks >= 2, "the MLP cell's cohort no longer takes the panels"
+    share = (blocks + 1) / (2 * blocks)
+    assert compiled.cost_analysis()["flops"] < (share + 0.02) * 2 * n * n * d

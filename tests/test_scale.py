@@ -42,7 +42,10 @@ def test_1024_client_sharded_round_with_krum():
 @needs_8
 def test_2048_client_krum_topk_sharded_matches_sort():
     """At n=2048 the distance matrix is 4M entries; the sharded top-k
-    scoring must agree with the sort path."""
+    scoring must agree with the sort path.  n is two blocks of
+    ops/distances.GRAM_BLOCK_ROWS, where one device would take the Gram's
+    block triangle; G lives on a mesh here, so both calls keep the single
+    dot (tests/test_gram_blocks.py pins that rule)."""
     rng = np.random.default_rng(0)
     G = jnp.asarray(rng.standard_normal((2048, 64)).astype(np.float32))
     from jax.sharding import NamedSharding, PartitionSpec as P
