@@ -154,7 +154,7 @@ def validate_composition(cfg: ExperimentConfig,
     reject at init (the pure checks only — nothing here touches a jax
     op or builds a model)."""
     from attacking_federate_learning_tpu.defenses.kernels import (
-        TIER2_DEFENSES, check_defense_args, check_tier2_args
+        check_defense_args, check_tier2_args
     )
 
     m, m_mal = _cohort(cfg)
@@ -171,44 +171,10 @@ def validate_composition(cfg: ExperimentConfig,
             "is no arrival time to game")
     if cfg.aggregation == "hierarchical":
         from attacking_federate_learning_tpu.ops.federated import (
-            tier1_assumed, tier2_assumed
+            check_hier_support, tier1_assumed, tier2_assumed
         )
 
-        if cfg.participation < 1.0:
-            raise ValueError(
-                "hierarchical aggregation requires full participation "
-                "(placement assigns every client to a megabatch)")
-        if cfg.data_placement != "device":
-            raise ValueError(
-                "hierarchical aggregation requires "
-                "data_placement='device' (the scanned round gathers "
-                "each megabatch's batch on device)")
-        if cfg.backdoor and not cfg.backdoor_fused:
-            raise ValueError(
-                "hierarchical aggregation needs the fused backdoor "
-                "path (drop --backdoor-staged)")
-        if cfg.defense not in TIER2_DEFENSES:
-            raise ValueError(
-                f"hierarchical tier-1 defense must be one of "
-                f"{sorted(TIER2_DEFENSES)} (the mask-aware kernel "
-                f"set), got {cfg.defense!r}")
-        if cfg.distance_impl in ("ring", "allgather", "host"):
-            raise ValueError(
-                f"hierarchical aggregation supports distance_impl in "
-                f"auto/xla/pallas (got {cfg.distance_impl!r}): the "
-                f"per-megabatch distance pass must stay inside the "
-                f"scanned program")
-        for knob in ("trimmed_mean_impl", "median_impl",
-                     "bulyan_selection_impl", "bulyan_trim_impl"):
-            if getattr(cfg, knob) == "host":
-                # Mirrors engine._init_hierarchical: the pallas values
-                # stay inside the scanned program and compose; only
-                # the host kernels would pay a per-megabatch callback.
-                raise ValueError(
-                    f"hierarchical aggregation requires a device-"
-                    f"resident {knob} ('xla' or 'pallas'; got 'host' — "
-                    f"a host kernel would pure_callback once per "
-                    f"megabatch per scan step)")
+        check_hier_support(cfg)
         S = cfg.users_count // cfg.megabatch
         f = cfg.corrupted_count
         t1 = (cfg.tier1_corrupted if cfg.tier1_corrupted is not None
@@ -296,12 +262,12 @@ class Cell:
                "priority": self.priority, "group": self.group,
                "index": self.index}
         # The impl knobs ride along so `runs campaign` can render
-        # impl-comparison tables (xla vs pallas vs host sweeps,
-        # ISSUE 11) straight from the journal rows; the mesh/topology
+        # impl-comparison tables (xla vs host sweeps) straight from
+        # the journal rows; the mesh/topology
         # knobs (ISSUE 12) let the same tables split SPMD vs scan
         # hierarchical cells.
         for k in ("dataset", "defense", "seed", "epochs", "aggregation",
-                  "secagg", "aggregation_impl", "distance_impl",
+                  "secagg", "distance_impl",
                   "bulyan_selection_impl", "mesh_shape", "megabatch",
                   "mal_placement"):
             if self.cfg is not None:
@@ -452,7 +418,6 @@ _VALUE_FLAGS = (
     ("bulyan_selection_impl", "--bulyan-selection-impl"),
     ("bulyan_trim_impl", "--bulyan-trim-impl"),
     ("aggregation", "--aggregation"),
-    ("aggregation_impl", "--aggregation-impl"),
     ("async_buffer", "--async-buffer"),
     ("async_max_staleness", "--async-max-staleness"),
     ("staleness_weight", "--staleness-weight"),

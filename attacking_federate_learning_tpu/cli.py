@@ -189,23 +189,11 @@ def build_parser() -> argparse.ArgumentParser:
                         "reference-exact")
     p.add_argument("--bulyan-selection-impl",
                    default=ExperimentConfig.bulyan_selection_impl,
-                   choices=["xla", "host", "pallas"],
+                   choices=["xla", "host"],
                    help="Bulyan selection engine: traced XLA loop "
-                        "(default), the hybrid exact path — device "
+                        "(default) or the hybrid exact path — device "
                         "distances, one (n, n) host marshal, native "
-                        "incremental selection, device trim-mean — or "
-                        "'pallas': the same exact loop over the fused "
-                        "pallas distance kernel's on-device D (no "
-                        "marshal at all; ops/pallas_defense.py)")
-    p.add_argument("--aggregation-impl",
-                   default=ExperimentConfig.aggregation_impl,
-                   choices=["xla", "pallas"],
-                   help="Defense-kernel suite (ops/pallas_defense.py): "
-                        "'pallas' runs the tier-1 pipeline on-device — "
-                        "fused distance->Krum-score kernel, tiled "
-                        "trimmed-mean/median, all-on-device Bulyan — "
-                        "with interpret-mode fallback off-TPU; 'xla' "
-                        "(default) leaves every path unchanged")
+                        "incremental selection, device trim-mean")
     p.add_argument("--bulyan-trim-impl",
                    default=ExperimentConfig.bulyan_trim_impl,
                    choices=["xla", "host"],
@@ -282,11 +270,10 @@ def build_parser() -> argparse.ArgumentParser:
                         "kernels run over group sums via "
                         "--tier2-defense)")
     p.add_argument("--distance-impl", default="auto",
-                   choices=["auto", "xla", "pallas", "host", "ring",
-                            "allgather"],
+                   choices=["auto", "xla", "host", "ring", "allgather"],
                    help="Krum/Bulyan distance engine (defenses/kernels.py): "
-                        "XLA Gram matmul, fused pallas TPU kernel, host "
-                        "BLAS (CPU backend), or the blockwise shard_map "
+                        "XLA Gram matmul, host BLAS (CPU backend), or "
+                        "the blockwise shard_map "
                         "schedules over the clients mesh axis "
                         "(ring/allgather need --mesh-shape)")
     p.add_argument("--distance-dtype", default="float32",
@@ -569,7 +556,6 @@ def config_from_args(args) -> ExperimentConfig:
         bulyan_batch_select=args.bulyan_batch_select,
         bulyan_selection_impl=args.bulyan_selection_impl,
         bulyan_trim_impl=args.bulyan_trim_impl,
-        aggregation_impl=args.aggregation_impl,
         server_uses_faded_lr=args.server_uses_faded_lr,
         log_round_stats=args.round_stats,
         telemetry=args.telemetry,

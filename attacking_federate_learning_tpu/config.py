@@ -410,7 +410,6 @@ class ExperimentConfig:
     #               core/engine.py:_wire_distance_defense); host BLAS for
     #               eager CPU-backend kernel calls (the bench fallback)
     #   'xla'       Gram matmul + epilogue (ops/distances.py)
-    #   'pallas'    fused-epilogue TPU kernel (ops/pallas_distances.py)
     #   'host'      NumPy/BLAS (defenses/host.py; pure_callback in-jit)
     #   'ring'      blockwise ppermute schedule over the clients mesh axis
     #   'allgather' one all_gather + per-device tiles
@@ -436,34 +435,12 @@ class ExperimentConfig:
     # round program), 'host' — the HYBRID exact path for the
     # accelerator at large n: distances stay on the MXU, the (n, n) D
     # ships to the host once for the native O(n^2) incremental
-    # selection, and the gather + trimmed mean run back on the device —
-    # or 'pallas': the ALL-ON-DEVICE exact route (ISSUE 11) — the
-    # (n, n) D from the fused-epilogue pallas kernel feeds the same
-    # traced selection loop as 'xla', so exact q=1 semantics survive
-    # with NO pure_callback marshal at all.
+    # selection, and the gather + trimmed mean run back on the device.
     # Opt-in (not auto): host ties resolve by the native comparator
-    # (ulp-band only — tests/test_native.py; pallas distances carry the
-    # same ulp-band vs the XLA Gram), and the pure_callback
+    # (ulp-band only — tests/test_native.py), and the pure_callback
     # marshal is only worth it when set_size sequential XLA trips cost
     # more than one D transfer (the 10k north-star regime).
     bulyan_selection_impl: str = "xla"
-    # Defense-kernel implementation suite (ops/pallas_defense.py):
-    # 'xla' (the default — every path unchanged) or 'pallas', the
-    # on-device tier-1 pipeline: Krum scores via the fused
-    # distance->score kernel (no (n, n) matrix, one HBM sweep),
-    # TrimmedMean/Median via the tiled per-d-block selection kernels
-    # (masked/weighted seams included, so fault/async/hierarchical
-    # rounds compose), Bulyan via pallas distances + the traced
-    # selection loop + the pallas trim tail.  Off-TPU the kernels run
-    # interpret=True (the CPU test mode); on TPU jax 0.9.0's Pallas
-    # lowering has no sort, so every sort-based kernel of the suite
-    # raises a NotImplementedError naming itself (never interpret, never
-    # the XLA twin) — only the distance kernel compiles through Mosaic.
-    # Composition matrix (rejected loudly below): covers the mask-aware
-    # kernel family only, excludes the host kernels and the staged
-    # (host-eager) backdoor seam, and needs an in-program distance
-    # engine (auto/pallas).
-    aggregation_impl: str = "xla"
     # Bulyan's final trimmed-mean tail: 'xla' (default, bit-stable with
     # the traced path) or 'host' (native column-blocked kernel — the
     # CPU-backend 10k opt-in; at full scale the XLA:CPU stable argsort
@@ -616,10 +593,10 @@ class ExperimentConfig:
             raise ValueError(
                 f"krum_scoring_method must be 'sort', 'topk' or 'auto', "
                 f"got {self.krum_scoring_method!r}")
-        if self.distance_impl not in ("auto", "xla", "pallas", "host",
-                                      "ring", "allgather"):
+        if self.distance_impl not in ("auto", "xla", "host", "ring",
+                                      "allgather"):
             raise ValueError(
-                f"distance_impl must be one of auto/xla/pallas/host/ring/"
+                f"distance_impl must be one of auto/xla/host/ring/"
                 f"allgather, got {self.distance_impl!r}")
         if self.distance_dtype not in ("float32", "bfloat16"):
             raise ValueError(
@@ -648,68 +625,10 @@ class ExperimentConfig:
             raise ValueError(
                 f"bulyan_batch_select must be >= 1, got "
                 f"{self.bulyan_batch_select}")
-        if self.bulyan_selection_impl not in ("xla", "host", "pallas"):
+        if self.bulyan_selection_impl not in ("xla", "host"):
             raise ValueError(
-                f"bulyan_selection_impl must be 'xla', 'host' or "
-                f"'pallas', got {self.bulyan_selection_impl!r}")
-        if self.aggregation_impl not in ("xla", "pallas"):
-            raise ValueError(
-                f"aggregation_impl must be 'xla' or 'pallas', "
-                f"got {self.aggregation_impl!r}")
-        _PALLAS_KERNELS = ("Krum", "TrimmedMean", "Bulyan", "Median")
-        if self.aggregation_impl == "pallas":
-            # The pallas suite covers the mask-aware kernel family;
-            # everything that would mix it with a host engine or pull
-            # the aggregation out of the device program is rejected
-            # here, loudly, with the offending flag named (the same
-            # standard as the secagg/hierarchical matrices; campaign
-            # cells pre-validate through this via construction).
-            if self.defense not in _PALLAS_KERNELS:
-                raise ValueError(
-                    f"aggregation_impl='pallas' covers the Pallas "
-                    f"defense-kernel suite {_PALLAS_KERNELS} "
-                    f"(ops/pallas_defense.py); defense "
-                    f"{self.defense!r} has no pallas kernel — drop "
-                    f"--aggregation-impl pallas")
-            for knob in ("trimmed_mean_impl", "median_impl",
-                         "bulyan_trim_impl"):
-                if getattr(self, knob) != "xla":
-                    raise ValueError(
-                        f"aggregation_impl='pallas' already routes the "
-                        f"coordinate-wise kernels on-device; mixing it "
-                        f"with {knob}={getattr(self, knob)!r} would "
-                        f"dispatch two engines for one estimator "
-                        f"(leave {knob}='xla')")
-            if self.bulyan_selection_impl == "host":
-                raise ValueError(
-                    "aggregation_impl='pallas' is the no-marshal "
-                    "on-device route; bulyan_selection_impl='host' "
-                    "reintroduces the (n, n) pure_callback marshal — "
-                    "pick one (the hybrid OR the pallas suite)")
-            if self.distance_impl not in ("auto", "pallas"):
-                raise ValueError(
-                    f"aggregation_impl='pallas' computes distances "
-                    f"inside its fused kernels; "
-                    f"distance_impl={self.distance_impl!r} would "
-                    f"silently not run — set distance_impl to "
-                    f"'auto' or 'pallas'")
-        if "pallas" in (self.aggregation_impl,
-                        self.bulyan_selection_impl):
-            if self.backdoor and not self.backdoor_fused:
-                raise ValueError(
-                    "--backdoor-staged aggregates eagerly on the host "
-                    "between compute and craft; the Pallas defense "
-                    "suite is a device-kernel route (and the "
-                    "staged==fused bit-identity pin needs both modes "
-                    "on one kernel) — drop --backdoor-staged")
-            if self.bulyan_selection_impl == "pallas" and (
-                    self.distance_impl in ("host", "ring", "allgather")):
-                raise ValueError(
-                    f"bulyan_selection_impl='pallas' selects over the "
-                    f"pallas distance kernel's on-device D; "
-                    f"distance_impl={self.distance_impl!r} computes D "
-                    f"elsewhere — set distance_impl to 'auto', 'xla' "
-                    f"or 'pallas'")
+                f"bulyan_selection_impl must be 'xla' or 'host', "
+                f"got {self.bulyan_selection_impl!r}")
         if self.bulyan_trim_impl not in ("xla", "host"):
             raise ValueError(
                 f"bulyan_trim_impl must be 'xla' or 'host', "

@@ -213,13 +213,9 @@ class FederatedExperiment:
             self.defense_fn = self._wire_distance_defense(self.defense_fn)
         elif cfg.defense in ("TrimmedMean", "Median"):
             # Opt-in kernel routing (defenses/kernels.py:trimmed_mean
-            # explains why the host kernel is not auto-dispatched; the
-            # pallas suite is the same opt-in standard, ISSUE 11 —
-            # config validation keeps the two exclusive).
+            # explains why the host kernel is not auto-dispatched).
             impl = (cfg.trimmed_mean_impl if cfg.defense == "TrimmedMean"
                     else cfg.median_impl)
-            if cfg.aggregation_impl == "pallas":
-                impl = "pallas"
             if impl != "xla":
                 self.defense_fn = functools.partial(
                     self.defense_fn, impl=impl)
@@ -404,44 +400,11 @@ class FederatedExperiment:
             TIER2_DEFENSES, check_tier2_args
         )
         from attacking_federate_learning_tpu.ops.federated import (
-            make_placement, tier1_assumed, tier2_assumed
+            check_hier_support, make_placement, tier1_assumed,
+            tier2_assumed
         )
 
-        if cfg.participation < 1.0:
-            raise ValueError(
-                "hierarchical aggregation requires full participation "
-                "(placement assigns every client to a megabatch)")
-        if cfg.data_placement != "device":
-            raise ValueError(
-                "hierarchical aggregation requires "
-                "data_placement='device' (the scanned round gathers "
-                "each megabatch's batch on device)")
-        if cfg.backdoor and not cfg.backdoor_fused:
-            raise ValueError(
-                "hierarchical aggregation needs the fused backdoor "
-                "path (drop --backdoor-staged)")
-        if cfg.defense not in TIER2_DEFENSES:
-            raise ValueError(
-                f"hierarchical tier-1 defense must be one of "
-                f"{sorted(TIER2_DEFENSES)} (the mask-aware kernel "
-                f"set), got {cfg.defense!r}")
-        if cfg.distance_impl in ("ring", "allgather", "host"):
-            raise ValueError(
-                f"hierarchical aggregation supports distance_impl in "
-                f"auto/xla/pallas (got {cfg.distance_impl!r}): the "
-                f"per-megabatch distance pass must stay inside the "
-                f"scanned program")
-        for knob in ("trimmed_mean_impl", "median_impl",
-                     "bulyan_selection_impl", "bulyan_trim_impl"):
-            if getattr(cfg, knob) == "host":
-                # The pallas values stay INSIDE the scanned program
-                # (ISSUE 11) and compose; only the host kernels would
-                # pure_callback once per megabatch per scan step.
-                raise ValueError(
-                    f"hierarchical aggregation requires a device-"
-                    f"resident {knob} ('xla' or 'pallas'; got 'host' — "
-                    f"a host kernel would pure_callback once per "
-                    f"megabatch per scan step)")
+        check_hier_support(cfg)
 
         self._placement = make_placement(self.n, self.f, cfg.megabatch,
                                          cfg.mal_placement)
@@ -557,32 +520,21 @@ class FederatedExperiment:
         )
 
         cfg = self.cfg
-        pallas_suite = cfg.aggregation_impl == "pallas"
         kw = {"method": cfg.krum_scoring_method}
         if cfg.krum_paper_scoring:
             kw["paper_scoring"] = True
         if cfg.distance_dtype != "float32":
             kw["distance_dtype"] = cfg.distance_dtype
-        if cfg.defense == "Krum" and pallas_suite:
-            # The fused distance->score kernel (ops/pallas_defense.py):
-            # scores in one sweep, no (n, n) matrix, the topk-class
-            # cancellation guard applied inside the dispatch.
-            kw["scores_impl"] = "pallas"
         if cfg.defense == "Bulyan":
             if cfg.bulyan_batch_select != 1:
                 kw["batch_select"] = cfg.bulyan_batch_select
-            sel = cfg.bulyan_selection_impl
-            if pallas_suite and sel == "xla":
-                sel = "pallas"
-            if sel != "xla":
+            if cfg.bulyan_selection_impl != "xla":
                 # 'host': hybrid exact selection — device distances, one
                 # (n, n) D marshal, native host selection, device
-                # trim-mean.  'pallas': the all-on-device exact route —
-                # pallas D, traced selection loop, no marshal.
-                kw["selection_impl"] = sel
-            trim = "pallas" if pallas_suite else cfg.bulyan_trim_impl
-            if trim != "xla":
-                kw["trim_impl"] = trim
+                # trim-mean.
+                kw["selection_impl"] = cfg.bulyan_selection_impl
+            if cfg.bulyan_trim_impl != "xla":
+                kw["trim_impl"] = cfg.bulyan_trim_impl
         impl = cfg.distance_impl
         if impl in ("ring", "allgather"):
             if self.shardings is None:
@@ -1296,20 +1248,10 @@ class FederatedExperiment:
             if self.traffic is not None:
                 # Config already rejects --backdoor-staged + traffic;
                 # this catches a non-fusable attacker handed in
-                # programmatically (same seam as the pallas check below).
+                # programmatically (same seam as the secagg check).
                 raise ValueError(
                     "the traffic engine requires a fusable attack (the "
                     "staged host-eager path has no arrival seam)")
-            if (cfg.aggregation_impl == "pallas"
-                    or cfg.bulyan_selection_impl == "pallas"):
-                # Config already rejects --backdoor-staged ⊕ pallas;
-                # this catches a non-fusable attacker handed in
-                # programmatically (same seam as the secagg check).
-                raise ValueError(
-                    "the staged (host-eager) aggregation path does not "
-                    "run the Pallas defense suite "
-                    "(aggregation_impl/bulyan_selection_impl='pallas' "
-                    "need a fusable attack)")
             self._compute_grads = jax.jit(self._compute_grads_impl)
             # Staged rounds already cross the host boundary every round,
             # so on the CPU backend a Krum/Bulyan aggregation runs EAGERLY:

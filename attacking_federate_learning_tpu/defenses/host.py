@@ -1,7 +1,7 @@
 """Host-BLAS defense kernels for the CPU backend.
 
 Backend-aware kernel dispatch: on TPU the Krum/Bulyan distance engine is an
-MXU Gram matmul (ops/distances.py, ops/pallas_distances.py), but XLA:CPU's
+MXU Gram matmul (ops/distances.py), but XLA:CPU's
 single-threaded gemm and sort are ~2x slower than the host's native BLAS on
 this class of machine (measured: 433 ms XLA:CPU vs 226 ms OpenBLAS for the
 (512, 79510) Gram).  So when the active backend is CPU the defense kernels

@@ -137,8 +137,8 @@ def resolve_distance_impl(distance_impl, users_count=None, users_grads=None):
     return "host" if jax.default_backend() == "cpu" else "xla"
 
 
-def _distances_for(users_grads, impl, distance_dtype=None):
-    """Distance matrix (zero diagonal) via the selected engine.
+def _distances_for(users_grads, distance_dtype=None):
+    """Distance matrix (zero diagonal) via the XLA Gram engine.
 
     ``distance_dtype='bfloat16'``: cast the operand for the distance
     computation ONLY — the Gram rides the MXU at native bf16 throughput
@@ -148,17 +148,6 @@ def _distances_for(users_grads, impl, distance_dtype=None):
     by default; the 'host' engine ignores it — host BLAS is f32)."""
     if distance_dtype is not None:
         users_grads = users_grads.astype(jnp.dtype(distance_dtype))
-    if impl == "pallas":
-        from attacking_federate_learning_tpu.ops.pallas_distances import (
-            pallas_pairwise_distances
-        )
-        if distance_dtype is None:
-            # Preserve pre-flag semantics: without an explicit
-            # distance_dtype the pallas path always computed f32, even
-            # for a bf16 wire matrix (the xla path, by documented
-            # contract, rides the wire dtype — ops/distances.py).
-            users_grads = users_grads.astype(jnp.float32)
-        return pallas_pairwise_distances(users_grads)
     return pairwise_distances(users_grads)
 
 
@@ -395,41 +384,6 @@ def _krum_scores(D, users_count, corrupted_count, alive=None,
     return scores
 
 
-def _pallas_krum_scores_guarded(users_grads, users_count, corrupted_count,
-                                paper_scoring, distance_dtype):
-    """Fused distance->score kernel (ops/pallas_defense.py) under the
-    same cancellation guard as :func:`_krum_scores`'s 'topk' method:
-    the fused evaluation is the complement identity (rowsum minus the
-    c largest), so whenever any row's kept mass falls below the
-    subtraction's noise floor the scores re-evaluate via the exact
-    sort path over the pallas distance matrix (``lax.cond`` — one
-    branch executes at runtime).  c == 0 degenerates to the pure
-    rowsum: no subtraction, no guard."""
-    from attacking_federate_learning_tpu.ops.pallas_defense import (
-        pallas_krum_scores
-    )
-
-    op = users_grads
-    if distance_dtype is not None:
-        op = op.astype(jnp.dtype(distance_dtype))
-    scores, rowsum = pallas_krum_scores(op, users_count, corrupted_count,
-                                        paper_scoring=paper_scoring)
-    comp = corrupted_count - 1 + (2 if paper_scoring else 0)
-    if comp == 0:
-        return scores
-    n = users_grads.shape[0]
-    eps = jnp.finfo(jnp.float32).eps
-    floor = (_TOPK_GUARD * eps * max(np.log2(max(n, 2)), 1.0) * rowsum)
-
-    def exact_sort():
-        D = _distances_for(users_grads, "pallas", distance_dtype)
-        return _krum_scores(D, users_count, corrupted_count,
-                            paper_scoring=paper_scoring, method="sort")
-
-    reliable = jnp.all((scores >= floor) & jnp.isfinite(rowsum))
-    return lax.cond(reliable, lambda: scores, exact_sort)
-
-
 def _host_krum_index(users_grads, users_count, corrupted_count,
                      paper_scoring):
     """Host-BLAS Krum index; pure_callback (scalar int out) under trace,
@@ -455,18 +409,11 @@ def _host_krum_index(users_grads, users_count, corrupted_count,
 
 def _krum_scores_and_index(users_grads, users_count, corrupted_count,
                            paper_scoring, method, distance_impl, D,
-                           distance_dtype, mask=None, scores_impl="xla"):
+                           distance_dtype, mask=None):
     """(scores-or-None, winner index) behind both :func:`krum_select`
     and the telemetry path.  Scores are ``None`` on the host engine —
     it returns only the scalar index (defenses/host.py), so telemetry
     fills that slot with NaN instead of paying a second (n,) marshal.
-
-    ``scores_impl='pallas'`` (config ``aggregation_impl='pallas'``):
-    the fused distance->score kernel — scores in one sweep, no (n, n)
-    matrix (ops/pallas_defense.py), guarded like the 'topk' method
-    (:func:`_pallas_krum_scores_guarded`).  An explicit opt-in that
-    outranks ``distance_impl`` resolution; the masked path keeps the
-    exact sort evaluator, fed by the pallas distance kernel.
 
     ``mask`` (the quarantine seam, core/faults.py): dead rows are
     excluded from every score (their distance entries mask to +inf, the
@@ -474,16 +421,6 @@ def _krum_scores_and_index(users_grads, users_count, corrupted_count,
     and can never win — fixed shapes, scoring forced onto the exact
     'sort' evaluator (the topk complement identity assumes the static
     pool)."""
-    if D is None and scores_impl == "pallas":
-        if mask is None:
-            with stage_scope("select"):
-                scores = _pallas_krum_scores_guarded(
-                    users_grads, users_count, corrupted_count,
-                    paper_scoring, distance_dtype)
-                return scores, jnp.argmin(scores)
-        # Masked pool: exact sort scoring over the pallas-computed
-        # distance matrix (the fused kernel assumes the static pool).
-        D = _distances_for(users_grads, "pallas", distance_dtype)
     if D is None:
         impl = resolve_distance_impl(distance_impl, users_count,
                                      users_grads)
@@ -495,7 +432,7 @@ def _krum_scores_and_index(users_grads, users_count, corrupted_count,
                     "(defenses/host.py)")
             return None, _host_krum_index(users_grads, users_count,
                                           corrupted_count, paper_scoring)
-        D = _distances_for(users_grads, impl, distance_dtype)
+        D = _distances_for(users_grads, distance_dtype)
     if mask is not None:
         scores = _krum_scores(D, jnp.sum(mask), corrupted_count,
                               alive=mask, paper_scoring=paper_scoring,
@@ -509,28 +446,25 @@ def _krum_scores_and_index(users_grads, users_count, corrupted_count,
 
 def krum_select(users_grads, users_count, corrupted_count,
                 paper_scoring=False, method="sort", distance_impl="xla",
-                D=None, distance_dtype=None, mask=None,
-                scores_impl="xla"):
+                D=None, distance_dtype=None, mask=None):
     """Index of the Krum winner (reference ``krum(..., return_index=True)``,
     defences.py:39-40).  :func:`krum` is defined through this, so the
     selection the engine's round diagnostics report is — by construction —
     the client the defense aggregated, for every distance engine."""
     return _krum_scores_and_index(users_grads, users_count, corrupted_count,
                                   paper_scoring, method, distance_impl, D,
-                                  distance_dtype, mask=mask,
-                                  scores_impl=scores_impl)[1]
+                                  distance_dtype, mask=mask)[1]
 
 
 @DEFENSES.register("Krum")
 def krum(users_grads, users_count, corrupted_count, paper_scoring=False,
          method="sort", distance_impl="xla", D=None, distance_dtype=None,
-         telemetry=False, mask=None, weights=None, scores_impl="xla",
-         margins=False, numerics=False):
+         telemetry=False, mask=None, weights=None, margins=False,
+         numerics=False):
     """Krum selection (reference defences.py:23-42): the single gradient
     whose summed distance to its k nearest peers is minimal.
 
-    ``distance_impl``: 'xla' (Gram matmul, ops/distances.py), 'pallas'
-    (fused-epilogue TPU kernel, ops/pallas_distances.py), 'host' (NumPy/BLAS
+    ``distance_impl``: 'xla' (Gram matmul, ops/distances.py), 'host' (NumPy/BLAS
     via pure_callback — the CPU-backend path, defenses/host.py), or 'auto'
     (host on CPU, xla elsewhere).  ``D``: precomputed (n, n) distance matrix
     with zero diagonal — the engine passes one from the blockwise shard_map
@@ -550,13 +484,6 @@ def krum(users_grads, users_count, corrupted_count, paper_scoring=False,
     ``mask``): selection stays unweighted (distances don't age), but
     the winning row's contribution is scaled by ITS weight — a stale
     Krum winner moves the server proportionally less.
-
-    ``scores_impl='pallas'`` (config ``aggregation_impl='pallas'``):
-    the fused distance->score route — see
-    :func:`_krum_scores_and_index`.  The winner is an input row, so
-    the aggregate is bit-exact whenever the (ulp-class) score
-    difference between evaluations doesn't flip a near-tie — the
-    measured-band contract (tests/test_pallas.py).
 
     ``margins=True`` (requires ``telemetry=True``; ISSUE 18)
     additionally returns ``margin_selection`` (n,) — each row's signed
@@ -582,15 +509,13 @@ def krum(users_grads, users_count, corrupted_count, paper_scoring=False,
         idx = krum_select(users_grads, users_count, corrupted_count,
                           paper_scoring=paper_scoring, method=method,
                           distance_impl=distance_impl, D=D,
-                          distance_dtype=distance_dtype, mask=mask,
-                          scores_impl=scores_impl)
+                          distance_dtype=distance_dtype, mask=mask)
         if weights is not None:
             return users_grads[idx] * weights[idx]
         return users_grads[idx]
     scores, idx = _krum_scores_and_index(
         users_grads, users_count, corrupted_count, paper_scoring, method,
-        distance_impl, D, distance_dtype, mask=mask,
-        scores_impl=scores_impl)
+        distance_impl, D, distance_dtype, mask=mask)
     n = users_grads.shape[0]
     scores_out = (jnp.full((n,), jnp.nan, jnp.float32) if scores is None
                   else scores.astype(jnp.float32))
@@ -632,18 +557,14 @@ def trimmed_mean_of(users_grads, number_to_consider, impl="xla",
 
     ``impl='host'`` is the single dispatch site for the native
     column-blocked kernel — shared by :func:`trimmed_mean` and Bulyan's
-    ``trim_impl`` tail so the two can never diverge.  ``impl='pallas'``
-    (config ``aggregation_impl='pallas'``) is the on-device equivalent:
-    the tiled per-d-block selection kernel
-    (ops/pallas_defense.py:pallas_trimmed_mean_of) — same summation-
-    order-ulps contract as the host kernel, and like it the kernel
-    returns only the aggregate, so telemetry fills the NaN slots.
+    ``trim_impl`` tail so the two can never diverge.  It returns only
+    the aggregate, so telemetry fills the NaN slots.
 
     ``telemetry=True`` additionally returns ``{'kept_fraction': (n,) —
     per client, the fraction of coordinates where its value survived the
-    trim (NaN on the host/pallas kernels, which return only the
-    aggregate) — 'trim_fraction': () — the per-round fraction of
-    clients trimmed per coordinate}``.
+    trim (NaN on the host kernel, which returns only the aggregate) —
+    'trim_fraction': () — the per-round fraction of clients trimmed per
+    coordinate}``.
 
     ``margins=True`` (requires ``telemetry=True``; ISSUE 18)
     additionally returns ``margin_kept_frac``/``margin_boundary_dist``
@@ -651,10 +572,7 @@ def trimmed_mean_of(users_grads, number_to_consider, impl="xla",
     membership (bit-equal to the scatter-based ``kept_fraction``) and
     the inside-positive mean distance to the trim boundary.  The
     reductions are pure-XLA rank ops over the same key the estimator
-    sorts by, so the pallas impl gets REAL margins (its aggregate
-    kernel still reports NaN ``kept_fraction``) and the two impls'
-    margins are bit-identical by construction; the host kernel runs
-    off-device and raises.
+    sorts by; the host kernel runs off-device and raises.
 
     ``numerics=True`` (requires ``margins=True``; ISSUE 20)
     additionally returns ``num_tie_rows`` () int32 — per-coordinate
@@ -667,27 +585,6 @@ def trimmed_mean_of(users_grads, number_to_consider, impl="xla",
     n = users_grads.shape[0]
     trim_frac = jnp.float32(1.0 - number_to_consider / n)
 
-    def margin_fields():
-        med = jnp.median(users_grads, axis=0)
-        key = jnp.abs(users_grads - med[None, :])
-        mf = rank_keep_margins(key, number_to_consider)
-        if numerics:
-            mf["num_tie_rows"] = tie_proximity(
-                mf["margin_boundary_dist"], max_finite_abs(key))
-        return mf
-
-    if impl == "pallas":
-        from attacking_federate_learning_tpu.ops.pallas_defense import (
-            pallas_trimmed_mean_of
-        )
-        agg = pallas_trimmed_mean_of(users_grads, int(number_to_consider))
-        if not telemetry:
-            return agg
-        diag = {"kept_fraction": jnp.full((n,), jnp.nan, jnp.float32),
-                "trim_fraction": trim_frac}
-        if margins:
-            diag.update(margin_fields())
-        return agg, diag
     if impl == "host":
         if margins:
             raise ValueError(
@@ -768,21 +665,9 @@ def trimmed_mean(users_grads, users_count, corrupted_count, impl="xla",
                 "(defenses/host.py is maskless); use impl='xla'")
         n = users_grads.shape[0]
         e = jnp.sum(mask)
-        if impl == "pallas":
-            # Mask/weights seam on the pallas route: the tiled kernel
-            # replicates masked_trimmed_mean_of op for op (pinned
-            # bit-exact, tests/test_pallas.py); k = e - f - 1 derives
-            # from the mask inside the kernel.
-            from attacking_federate_learning_tpu.ops.pallas_defense import (
-                pallas_masked_trimmed_mean
-            )
-            agg = pallas_masked_trimmed_mean(
-                users_grads, mask, corrupted_count + 1, weights=weights,
-                weighted=weights is not None)
-        else:
-            agg = masked_trimmed_mean_of(users_grads, mask,
-                                         e - corrupted_count - 1,
-                                         weights=weights)
+        agg = masked_trimmed_mean_of(users_grads, mask,
+                                     e - corrupted_count - 1,
+                                     weights=weights)
         if not telemetry:
             return agg
         diag = {"kept_fraction": jnp.full((n,), jnp.nan, jnp.float32),
@@ -790,9 +675,7 @@ def trimmed_mean(users_grads, users_count, corrupted_count, impl="xla",
                 (1.0 - (e - corrupted_count - 1) / jnp.maximum(e, 1)
                  ).astype(jnp.float32)}
         if margins:
-            # Same alive-anchored key masked_trimmed_mean_of ranks by
-            # (and the pallas tiles replicate op for op), so the
-            # margins are impl-independent pure-XLA rank ops.
+            # Same alive-anchored key masked_trimmed_mean_of ranks by.
             med = masked_median(users_grads, mask)
             key = jnp.where(mask[:, None],
                             jnp.abs(users_grads - med[None, :]), _INF)
@@ -928,16 +811,6 @@ def bulyan(users_grads, users_count, corrupted_count, paper_scoring=False,
     the traced loop uses f32 throughout — identical outside ulp-band
     ties (tests/test_defenses.py pins hybrid==xla on plain inputs).
 
-    ``selection_impl='pallas'`` / ``trim_impl='pallas'`` (config
-    ``bulyan_selection_impl='pallas'`` / ``aggregation_impl='pallas'``)
-    is the ALL-ON-DEVICE exact route (ISSUE 11): the (n, n) D comes
-    from the fused-epilogue pallas kernel (one HBM write, no Gram
-    round-trip), the selection is the same oracle-verified traced loop
-    as 'xla', and the trim tail runs the tiled pallas kernel — exact
-    q=1 reference semantics with NO pure_callback marshal, the
-    accelerator-resident alternative to the host hybrid above.  Same
-    ulp-band caveat as every cross-engine distance comparison.
-
     ``trim_impl='host'`` routes the final trimmed-mean tail through the
     native column-blocked kernel (same opt-in standard — and the same
     ulps-not-bits caveat — as ``trimmed_mean_impl``): at the 10k north
@@ -992,11 +865,11 @@ def bulyan(users_grads, users_count, corrupted_count, paper_scoring=False,
     q = int(batch_select)
     if not (1 <= q):
         raise ValueError(f"batch_select must be >= 1, got {batch_select}")
-    if selection_impl not in ("xla", "host", "pallas"):
-        raise ValueError(f"selection_impl must be 'xla', 'host' or "
-                         f"'pallas', got {selection_impl!r}")
-    if trim_impl not in ("xla", "host", "pallas"):
-        raise ValueError(f"trim_impl must be 'xla', 'host' or 'pallas', "
+    if selection_impl not in ("xla", "host"):
+        raise ValueError(f"selection_impl must be 'xla' or 'host', "
+                         f"got {selection_impl!r}")
+    if trim_impl not in ("xla", "host"):
+        raise ValueError(f"trim_impl must be 'xla' or 'host', "
                          f"got {trim_impl!r}")
 
     def trim_tail(selection, number_to_consider):
@@ -1011,15 +884,6 @@ def bulyan(users_grads, users_count, corrupted_count, paper_scoring=False,
     if D is None:
         impl = resolve_distance_impl(distance_impl, users_count,
                                      users_grads)
-        if selection_impl == "pallas":
-            # The all-on-device exact route (ISSUE 11): distances from
-            # the fused-epilogue pallas kernel (no Gram round-trip),
-            # then the SAME oracle-verified traced selection loop as
-            # 'xla' below — the (n, n) matrix exists once, on device,
-            # and no pure_callback marshal ever runs.  Identical
-            # selection math on a ulp-different D: flips only inside
-            # the measured tie band (tests/test_pallas.py).
-            impl = "pallas"
         if impl == "host":
             if mask is not None:
                 raise ValueError(
@@ -1045,7 +909,7 @@ def bulyan(users_grads, users_count, corrupted_count, paper_scoring=False,
             # pytree shape fixed and say "not measured" explicitly.
             nan = jnp.full((n,), jnp.nan, jnp.float32)
             return agg, {"selection_mask": nan, "scores": nan}
-        D = _distances_for(users_grads, impl, distance_dtype)
+        D = _distances_for(users_grads, distance_dtype)
 
     # +inf diagonal reproduces the reference's no-self-distance dict
     # (defences.py:16-21).
@@ -1160,17 +1024,9 @@ def bulyan(users_grads, users_count, corrupted_count, paper_scoring=False,
         e_set = jnp.sum(mask) - 2 * f
         sel_mask = sel_alive & (jnp.cumsum(sel_alive) <= e_set)
         w_sel = None if weights is None else weights[selected]
-        if trim_impl == "pallas":
-            from attacking_federate_learning_tpu.ops.pallas_defense import (
-                pallas_masked_trimmed_mean
-            )
-            agg = pallas_masked_trimmed_mean(
-                selection, sel_mask, 2 * f + 1, weights=w_sel,
-                weighted=w_sel is not None)
-        else:
-            agg = masked_trimmed_mean_of(
-                selection, sel_mask, jnp.sum(sel_mask) - 2 * f - 1,
-                weights=w_sel)
+        agg = masked_trimmed_mean_of(
+            selection, sel_mask, jnp.sum(sel_mask) - 2 * f - 1,
+            weights=w_sel)
         if not telemetry:
             return agg
         dm = jnp.zeros((n,), jnp.float32).at[selected].set(

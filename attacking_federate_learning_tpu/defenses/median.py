@@ -20,10 +20,7 @@ def median(users_grads, users_count, corrupted_count, impl="xla",
     """``impl='host'`` (opt-in, config ``median_impl``) routes to the
     native column-blocked kernel (native/bulyan_select.cpp:fl_median) —
     same rationale and same non-auto-dispatch rule as
-    kernels.py:trimmed_mean.  ``impl='pallas'`` (config
-    ``aggregation_impl='pallas'``) is the on-device tiled kernel
-    (ops/pallas_defense.py) — the masked/weighted variants replicate
-    kernels.masked_median bit for bit (pinned, tests/test_pallas.py).
+    kernels.py:trimmed_mean.
 
     ``telemetry=True`` additionally returns ``{'dist_to_agg': (n,)}`` —
     each client's L2 distance to the aggregated median vector, the
@@ -43,16 +40,16 @@ def median(users_grads, users_count, corrupted_count, impl="xla",
     (utils/margins.py:median_pick_margins) — each row's pick mass
     from the exact rank membership of the median (so the picked values
     reconstruct the aggregate) and its inside-positive proximity to
-    the rank-derived median.  Pure-XLA rank ops independent of
-    ``impl``, so the pallas route gets bit-identical margins; the
-    off-device host kernel raises.
+    the rank-derived median.  Pure-XLA rank ops; the off-device host
+    kernel raises.
 
     ``numerics=True`` (requires ``margins=True``; ISSUE 20)
     additionally returns ``num_tie_rows`` () int32 — boundary
     distances within TIE_BAND_ULPS ulp of the median pick, banded at
     the input's largest finite magnitude (utils/numerics.py)."""
     from attacking_federate_learning_tpu.defenses.kernels import (
-        check_margin_seam, check_numerics_seam, check_weight_seam
+        check_margin_seam, check_numerics_seam, check_weight_seam,
+        masked_median
     )
     check_weight_seam(mask, weights)
     check_margin_seam(margins, telemetry)
@@ -82,17 +79,7 @@ def median(users_grads, users_count, corrupted_count, impl="xla",
             raise ValueError(
                 "mask-aware Median has no host kernel "
                 "(defenses/host.py is maskless); use impl='xla'")
-        if impl == "pallas":
-            from attacking_federate_learning_tpu.ops.pallas_defense import (
-                pallas_masked_median
-            )
-            agg = pallas_masked_median(users_grads, mask, weights=weights,
-                                       weighted=weights is not None)
-        else:
-            from attacking_federate_learning_tpu.defenses.kernels import (
-                masked_median
-            )
-            agg = masked_median(users_grads, mask, weights=weights)
+        agg = masked_median(users_grads, mask, weights=weights)
         if not telemetry:
             return agg
         G = users_grads.astype(jnp.float32)
@@ -110,11 +97,6 @@ def median(users_grads, users_count, corrupted_count, impl="xla",
             host_coordwise
         )
         agg = host_coordwise(host_median, users_grads)
-    elif impl == "pallas":
-        from attacking_federate_learning_tpu.ops.pallas_defense import (
-            pallas_median_of
-        )
-        agg = pallas_median_of(users_grads)
     else:
         agg = jnp.median(users_grads, axis=0)
     if not telemetry:

@@ -22,23 +22,30 @@ def np_no_defense(G, users_count, corrupted_count):
     return np.mean(G, axis=0)
 
 
-def np_krum_select(G, users_count, corrupted_count, alive=None, D=None):
-    """Index of the Krum winner among alive users."""
+def np_krum_scores(G, users_count, corrupted_count, alive=None, D=None,
+                   paper_scoring=False):
+    """Per-user Krum score among alive users (+inf for the dead): the sum
+    of the k = n - f nearest distances (k = n - f - 2 under the NIPS'17
+    paper's scoring, SURVEY.md §2.4 #4)."""
     n = G.shape[0]
     if D is None:
         D = np_pairwise_distances(G)
     if alive is None:
         alive = np.ones(n, bool)
-    k = users_count - corrupted_count
-    best_idx, best_err = -1, np.inf
+    k = users_count - corrupted_count - (2 if paper_scoring else 0)
+    scores = np.full(n, np.inf)
     for i in range(n):
         if not alive[i]:
             continue
         others = [D[i, j] for j in range(n) if j != i and alive[j]]
-        err = float(np.sum(np.sort(others)[:k]))
-        if err < best_err:
-            best_err, best_idx = err, i
-    return best_idx
+        scores[i] = float(np.sum(np.sort(others)[:k]))
+    return scores
+
+
+def np_krum_select(G, users_count, corrupted_count, alive=None, D=None):
+    """Index of the Krum winner among alive users (the first of equals)."""
+    return int(np.argmin(np_krum_scores(G, users_count, corrupted_count,
+                                        alive=alive, D=D)))
 
 
 def np_krum(G, users_count, corrupted_count):

@@ -1,6 +1,6 @@
 """Native (C++) host-runtime kernels, built on demand.
 
-The TPU compute path is JAX/XLA/pallas; this package holds the *host*
+The TPU compute path is JAX/XLA; this package holds the *host*
 runtime's native kernels — currently the incremental exact Bulyan
 selection (bulyan_select.cpp), which turns the reference's O(n^3)
 sequential selection (reference defences.py:55-70) into O(n^2) total so

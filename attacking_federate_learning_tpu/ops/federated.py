@@ -191,6 +191,49 @@ def spmd_schedule(placement: Placement, parts: int) -> SpmdSchedule:
                         sids=tuple(sid_rows))
 
 
+def check_hier_support(cfg):
+    """Fail fast on configs the hierarchical topology cannot honor
+    (engine init AND campaigns/spec.py pre-validation — both call this
+    exact function, so the pre-check message and the construction
+    message cannot drift).  Pure: no jax op, no model."""
+    from attacking_federate_learning_tpu.defenses.kernels import (
+        TIER2_DEFENSES
+    )
+
+    if cfg.participation < 1.0:
+        raise ValueError(
+            "hierarchical aggregation requires full participation "
+            "(placement assigns every client to a megabatch)")
+    if cfg.data_placement != "device":
+        raise ValueError(
+            "hierarchical aggregation requires "
+            "data_placement='device' (the scanned round gathers "
+            "each megabatch's batch on device)")
+    if cfg.backdoor and not cfg.backdoor_fused:
+        raise ValueError(
+            "hierarchical aggregation needs the fused backdoor "
+            "path (drop --backdoor-staged)")
+    if cfg.defense not in TIER2_DEFENSES:
+        raise ValueError(
+            f"hierarchical tier-1 defense must be one of "
+            f"{sorted(TIER2_DEFENSES)} (the mask-aware kernel "
+            f"set), got {cfg.defense!r}")
+    if cfg.distance_impl in ("ring", "allgather", "host"):
+        raise ValueError(
+            f"hierarchical aggregation supports distance_impl in "
+            f"auto/xla (got {cfg.distance_impl!r}): the "
+            f"per-megabatch distance pass must stay inside the "
+            f"scanned program")
+    for knob in ("trimmed_mean_impl", "median_impl",
+                 "bulyan_selection_impl", "bulyan_trim_impl"):
+        if getattr(cfg, knob) == "host":
+            raise ValueError(
+                f"hierarchical aggregation requires a device-"
+                f"resident {knob} ('xla'; got 'host' — "
+                f"a host kernel would pure_callback once per "
+                f"megabatch per scan step)")
+
+
 def _client_map_spmd(shard_fn, placement: Placement, plan, *args,
                      with_sid=False):
     """One true SPMD program for the megabatch axis: a ``shard_map``

@@ -109,9 +109,7 @@ def rank_keep_margins(key, number_to_consider, order=None):
     - ``margin_kept_frac`` (n,): per row, the fraction of coordinates
       where it survived the trim — computed from rank membership, so
       it is bit-equal to the scatter-based telemetry ``kept_fraction``
-      (same stable sort, same keep set, same sum/d) and holds for
-      every impl that shares the key (the pallas tiles replicate the
-      XLA ranks op for op);
+      (same stable sort, same keep set, same sum/d);
     - ``margin_boundary_dist`` (n,): per row, the mean over
       coordinates of (trim boundary - key) — inside-positive distance
       to the envelope edge, where the boundary is the midpoint of the

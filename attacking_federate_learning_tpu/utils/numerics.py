@@ -44,8 +44,8 @@ except Exception:  # pragma: no cover - jax is baked into this image
 # Default tie band: a decision whose margin sits within this many ulp
 # (at the boundary's own magnitude) of zero is one a legal 1-ulp
 # evaluation-order difference could plausibly flip — 8 ulp covers the
-# measured cross-engine envelopes (tests/test_native.py's <=1-ulp tie
-# swaps, tests/test_pallas.py's reduction-order bands) with headroom.
+# measured cross-engine envelope (tests/test_native.py's <=1-ulp tie
+# swaps) with headroom.
 TIE_BAND_ULPS = 8
 
 _EPS32 = 2.0 ** -23           # f32 machine epsilon (ulp at 1.0)

@@ -14,9 +14,9 @@ CPU.
 Output: {"metric": "krum_agg_<n>c_wall_ms", "value": <ms>,
          "unit": "ms", "vs_baseline": <cpu_ms / our_ms>, "env": <device>}
 
-Diagnostics (per-impl table incl. the Mosaic-compiled pallas distance
-kernel, MFU, the 10k-client north-star suite from BASELINE.md, FL round
-throughput) go to stderr, with a recap block at the very end.
+Diagnostics (per-impl table, MFU, the 10k-client north-star suite from
+BASELINE.md, FL round throughput) go to stderr, with a recap block at
+the very end.
 
 This is a device measurement: it runs on the TPU or not at all.  A
 backend that is not a TPU is a non-zero exit with one line saying so
@@ -263,10 +263,10 @@ def gate_f32_disagreement(G, f, group, n):
 
 
 def bench_impl_table(G, f, iters=4):
-    """Per-impl diagnostic: the in-program distance engines at this n —
-    XLA Gram and the Mosaic-compiled pallas kernel, each in f32 and in
-    the bf16-Gram MXU mode (distance_dtype='bfloat16') — with cross-impl
-    Krum selection-index agreement (the on-chip pallas parity check)."""
+    """Per-impl diagnostic: the in-program distance engine at this n —
+    the XLA Gram in f32 and in the bf16-Gram MXU mode
+    (distance_dtype='bfloat16') — with cross-impl Krum selection-index
+    agreement among rows of one dtype."""
     import functools
 
     import jax
@@ -276,8 +276,7 @@ def bench_impl_table(G, f, iters=4):
     n = G.shape[0]
     rows = {}
     idxs = {}
-    for impl, ddt in [("xla", None), ("pallas", None),
-                      ("xla", "bfloat16"), ("pallas", "bfloat16")]:
+    for impl, ddt in [("xla", None), ("xla", "bfloat16")]:
         label = impl + ("[bf16]" if ddt else "")
         sel_fn = jax.jit(
             functools.partial(krum_select, distance_impl=impl,
@@ -293,7 +292,7 @@ def bench_impl_table(G, f, iters=4):
     # Cross-impl agreement is checked WITHIN a dtype: on iid gaussian
     # data near-tied Krum scores make an f32-vs-bf16 selection flip
     # legitimate (tests/test_distance_impl.py), so mixing dtypes into
-    # one set would false-alarm the xla-vs-pallas parity signal.
+    # one set would false-alarm the parity signal.
     for tag, group in (("f32", {k: v for k, v in idxs.items()
                                 if "bf16" not in k}),
                        ("bf16", {k: v for k, v in idxs.items()

@@ -24,14 +24,7 @@ started):
 3. oracle — Krum's winner on the device-produced (n, d) matrix of one
    round against ``defenses/oracle.py`` on the host: exact index, or an
    ``adjudicate()`` verdict that the two selected rows are the same
-   (colluders send bit-identical rows);
-4. kernels — the Pallas kernel Mosaic builds is lowered in its
-   production configuration, its lowered text holds the Mosaic custom
-   call, it executes and matches its XLA twin on an aligned and on the
-   unaligned (n = 1,000, d = 79,510) shape; every kernel Mosaic cannot
-   build raises the error naming it (never interpret mode, never the
-   XLA twin), and its raw body, lowered past that guard, is still
-   refused by Mosaic's own message — so the leg fails the day it is not.
+   (colluders send bit-identical rows).
 
 Any exception is a non-zero exit.  Without a TPU the first leg exits
 non-zero with one line naming the backend it found, and nothing below is
@@ -57,9 +50,6 @@ CLIENTS = 1024
 MAL_PROP = 0.24
 WIRE_DIM = 79_510
 TARGET_ACCURACY = 90.0
-UNALIGNED = (1000, WIRE_DIM)            # neither a tile nor a lane multiple
-ALIGNED = (1024, 8192)                  # bm = bn = 128, bk = 512 multiples
-MOSAIC_CALL = "tpu_custom_call"
 
 
 def cli_argv(rounds, log_dir):
@@ -170,100 +160,6 @@ def oracle_leg(argv):
             "malicious_rows": f}
 
 
-def compiled_kernels():
-    """Leg 4a: the Mosaic-compiled kernel against its XLA twin."""
-    import jax
-    import jax.numpy as jnp
-
-    from attacking_federate_learning_tpu.ops.distances import (
-        pairwise_distances
-    )
-    from attacking_federate_learning_tpu.ops.pallas_distances import (
-        pallas_pairwise_distances
-    )
-
-    report = {}
-    for shape in (ALIGNED, UNALIGNED):
-        G = jax.random.normal(jax.random.PRNGKey(sum(shape)), shape,
-                              jnp.float32)
-        # default interpret resolution: what aggregation_impl='pallas' runs
-        lowered = jax.jit(pallas_pairwise_distances).lower(G)
-        assert MOSAIC_CALL in lowered.as_text(), (
-            "pairwise_distances: no Mosaic custom call in the lowered "
-            "text — the TPU route is not the compiled one")
-        t0 = time.perf_counter()
-        compiled = lowered.compile()
-        compile_s = time.perf_counter() - t0
-        got = np.asarray(compiled(G))
-        want = np.asarray(jax.jit(pairwise_distances)(G))
-        assert np.isfinite(got).all()
-        # the tolerance tests/test_pallas.py held the Mosaic build to
-        np.testing.assert_allclose(got, want, rtol=1e-3, atol=5e-3)
-        report[f"pairwise_distances@{shape[0]}x{shape[1]}"] = {
-            "mosaic": True, "compile_s": round(compile_s, 2),
-            "max_abs_err": float(np.max(np.abs(got - want)))}
-    return report
-
-
-def refused_kernels():
-    """Leg 4b: a kernel Mosaic cannot build raises the error naming it —
-    never interpret mode, never the XLA twin — and Mosaic still refuses
-    its body when lowered past that guard at the production shape."""
-    import jax
-    import jax.numpy as jnp
-
-    from attacking_federate_learning_tpu.defenses.kernels import trimmed_mean
-    from attacking_federate_learning_tpu.defenses.median import median
-    from attacking_federate_learning_tpu.ops.pallas_defense import (
-        pallas_krum_scores, pallas_masked_median,
-        pallas_masked_trimmed_mean, raw_sort_kernels
-    )
-
-    n = UNALIGNED[0]
-    f = int(MAL_PROP * n)
-    G = jax.ShapeDtypeStruct(UNALIGNED, jnp.float32)
-    alive = jax.ShapeDtypeStruct((n,), bool)
-    routes = {
-        "krum_score_fusion":
-            lambda: jax.eval_shape(lambda g: pallas_krum_scores(g, n, f), G),
-        "trimmed_mean_tile":
-            lambda: jax.eval_shape(
-                lambda g: trimmed_mean(g, n, f, impl="pallas"), G),
-        "median_tile":
-            lambda: jax.eval_shape(
-                lambda g: median(g, n, f, impl="pallas"), G),
-        "masked_trimmed_mean_tile":
-            lambda: jax.eval_shape(
-                lambda g, m: pallas_masked_trimmed_mean(g, m, f + 1),
-                G, alive),
-        "masked_median_tile":
-            lambda: jax.eval_shape(pallas_masked_median, G, alive),
-    }
-    raw = raw_sort_kernels(n, f)
-    assert routes.keys() == raw.keys()
-    report = {}
-    for name, route in routes.items():
-        try:
-            route()
-        except NotImplementedError as e:
-            assert name in str(e), (name, str(e))
-        else:
-            raise AssertionError(
-                f"{name}: traced on the TPU backend without a Mosaic "
-                f"build (interpret mode or an XLA stand-in)")
-        try:
-            jax.jit(raw[name]).lower(G)
-        except NotImplementedError as e:
-            assert "sort" in str(e) and name not in str(e), (name, str(e))
-            report[name] = {"mosaic": False,
-                            "refusal": str(e).split(". ")[0]}
-        else:
-            raise AssertionError(
-                f"{name}: Mosaic now lowers this kernel — drop its guard "
-                f"(ops/pallas_defense.py) and move it to the compiled leg")
-    return report
-
-
 def main():
     import jax
 
@@ -279,13 +175,11 @@ def main():
               f"({device['device_kind']} x{device['count']})", flush=True)
         trail = winner_trail(os.path.join(log_dir, "trail"))
     oracle = oracle_leg(argv)
-    kernels = {**compiled_kernels(), **refused_kernels()}
 
     compiles = compile_log()
     span = [c for c in compiles if "span" in (c["name"] or "")]
     print("[smoke] readings (one run; not benchmark metrics): " + json.dumps({
         "main_path": run, "winner_trail": trail, "oracle": oracle,
-        "kernels": kernels,
         "compile_s_total": round(sum(c["compile_s"] for c in compiles), 2),
         "compile_span": span,
         "compile_slowest": sorted(compiles, key=lambda c: -c["compile_s"])[:5],

@@ -115,11 +115,11 @@ class TestF32FlipAdjudication:
         rng = np.random.default_rng(5)
         G = rng.standard_normal((12, 16)).astype(np.float32)
         G[1] = G[7]
-        bench.gate_f32_disagreement(G, 2, {"xla": 1, "pallas": 7}, 12)
+        bench.gate_f32_disagreement(G, 2, {"xla": 1, "other": 7}, 12)
         assert "valid" not in bench.RESULT       # tie: warning only
         assert any("legal tie" in r for r in bench.RECAP)
         G[2] *= 40.0                             # decisive outlier
-        bench.gate_f32_disagreement(G, 2, {"xla": 0, "pallas": 2}, 12)
+        bench.gate_f32_disagreement(G, 2, {"xla": 0, "other": 2}, 12)
         assert bench.RESULT["valid"] is False
         assert any("disagree" in r
                    for r in bench.RESULT["invalid_reasons"])

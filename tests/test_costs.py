@@ -432,18 +432,6 @@ def test_stage_attribution_partitions_round(tmp_path, defense, topology):
         assert att["stages"]["tier2_aggregate"]["flops"] == 0
 
 
-def test_pallas_cell_attributes_to_tier1(tmp_path):
-    """The pallas defense-kernel dispatch is scoped: its (interpret-
-    mode, on CPU) compute books under tier1_aggregate, not
-    unattributed."""
-    exp = _exp(_cfg(tmp_path, defense="Krum", aggregation_impl="pallas"))
-    compiled = _round_compiled(exp)
-    att = costs.stage_attribution(compiled.as_text(),
-                                  costs.compiled_cost_facts(compiled))
-    assert att["stages"]["tier1_aggregate"]["flops"] > 0
-    assert att["stages"]["tier1_aggregate"]["bytes_accessed"] > 0
-
-
 def test_stage_scopes_are_metadata_only(tmp_path):
     """Scopes off must leave the compiled program identical up to
     metadata: the canonicalized fingerprint matches, while the

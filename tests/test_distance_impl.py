@@ -1,8 +1,7 @@
 """The distance-engine dispatch layer (VERDICT round-1 items #3/#4).
 
 Every selectable ``distance_impl`` — xla, host (CPU BLAS, defenses/host.py),
-pallas (interpret off-TPU), ring / allgather (blockwise shard_map kernels,
-parallel/distances.py) — must produce the same aggregate as the oracle, both
+ring / allgather (blockwise shard_map kernels, parallel/distances.py) — must produce the same aggregate as the oracle, both
 through the kernel API and wired through the engine's config knob.
 """
 
@@ -69,7 +68,7 @@ def test_host_krum_adversarial_magnitudes_and_ties():
 # --------------------------------------------------------------------------
 # kernel API dispatch
 # --------------------------------------------------------------------------
-@pytest.mark.parametrize("impl", ["xla", "host", "auto", "pallas"])
+@pytest.mark.parametrize("impl", ["xla", "host", "auto"])
 def test_krum_kernel_dispatch(impl):
     n, d, f = 24, 104, 5
     G = grads_for(n, d, seed=1)
@@ -399,19 +398,16 @@ def test_bf16_distances_close_to_f32():
     np.testing.assert_allclose(got, want, atol=0.05, rtol=2e-2)
 
 
-@pytest.mark.parametrize("impl", ["xla", "pallas"])
-def test_krum_select_bf16_agrees(impl):
+def test_krum_select_bf16_agrees():
     """On generic (non-tie) data the bf16-Gram selection matches f32 —
-    eager and jitted — for both the XLA and pallas engines."""
+    eager and jitted."""
     G = jnp.asarray(grads_for(24, 300, seed=9))
     want = int(K.krum_select(G, 24, 5))
-    got = int(K.krum_select(G, 24, 5, distance_impl=impl,
-                            distance_dtype="bfloat16"))
+    got = int(K.krum_select(G, 24, 5, distance_dtype="bfloat16"))
     assert got == want
     jit_sel = jax.jit(K.krum_select, static_argnums=(1, 2),
-                      static_argnames=("distance_impl", "distance_dtype"))
-    assert int(jit_sel(G, 24, 5, distance_impl=impl,
-                       distance_dtype="bfloat16")) == want
+                      static_argnames=("distance_dtype",))
+    assert int(jit_sel(G, 24, 5, distance_dtype="bfloat16")) == want
 
 
 def test_bulyan_bf16_close_to_f32():
@@ -453,21 +449,6 @@ def test_engine_distance_dtype_bf16_blockwise(impl):
     np.testing.assert_allclose(got, ref, atol=1e-3, rtol=1e-3)
 
 
-def test_pallas_default_distance_dtype_stays_f32_for_bf16_wire():
-    """grad_dtype=bfloat16 + distance_impl=pallas WITHOUT the flag must
-    keep the pre-flag f32 distance math (change behavior only behind
-    flags): pallas distances from a bf16 wire matrix equal those of its
-    f32 upcast exactly."""
-    from attacking_federate_learning_tpu.defenses.kernels import (
-        _distances_for
-    )
-
-    G16 = jnp.asarray(grads_for(16, 128, seed=21), jnp.bfloat16)
-    want = np.asarray(_distances_for(G16.astype(jnp.float32), "pallas"))
-    got = np.asarray(_distances_for(G16, "pallas"))
-    np.testing.assert_array_equal(got, want)
-
-
 def test_distance_dtype_validation():
     from attacking_federate_learning_tpu.config import ExperimentConfig
 
@@ -477,7 +458,7 @@ def test_distance_dtype_validation():
 
 
 # --------------------------------------------------------------------------
-# ISSUE 6 satellites: diagonal zeroing + pallas norm hoist, pinned via
+# ISSUE 6 satellite: diagonal zeroing, pinned via
 # static cost facts (utils/costs.py — deterministic per (HLO, XLA,
 # platform), no stopwatch)
 # --------------------------------------------------------------------------
@@ -526,31 +507,3 @@ def test_zero_diagonal_costs_no_more_than_eye():
     assert new["flops"] < old["flops"]
     assert new["bytes_accessed"] <= old["bytes_accessed"]
     assert new["temp_bytes"] <= old["temp_bytes"]
-
-
-def test_pallas_single_f32_materialization_of_padded_matrix():
-    """pallas_pairwise_distances hoists ONE f32 view of the padded
-    matrix for the squared norms; the matmul operand stays the wire
-    dtype.  A second materialization of Gp.astype(f32) would cost
-    ~np*dp*4 extra temp bytes — pin the bf16 path under that
-    threshold (shape-exact facts; the perf-gate env guard covers
-    toolchain bumps, and this box's tests always run on one env)."""
-    from attacking_federate_learning_tpu.ops.pallas_distances import (
-        pallas_pairwise_distances
-    )
-
-    n, d = 300, 700
-    np_, dp = 384, 1024          # padded to lcm(128,128) x 512-multiple
-    extra_cast = np_ * dp * 4    # a second f32 copy of Gp
-    sds16 = jax.ShapeDtypeStruct((n, d), jnp.bfloat16)
-    sds32 = jax.ShapeDtypeStruct((n, d), jnp.float32)
-    f16 = _facts(jax.jit(lambda g: pallas_pairwise_distances(g))
-                 .lower(sds16))
-    f32 = _facts(jax.jit(lambda g: pallas_pairwise_distances(g))
-                 .lower(sds32))
-    # Measured 4.18 MB on this env; one duplicated cast would add
-    # +1.57 MB.  The bound sits between the two.
-    assert f16["temp_bytes"] < 4.18e6 + 0.5 * extra_cast
-    # And the bf16 path must stay cheaper than the all-f32 path (whose
-    # padded matrix alone is twice the bytes).
-    assert f16["temp_bytes"] < f32["temp_bytes"]

@@ -6,7 +6,7 @@ of the boundary's own scale, Gram cancellation depth); the host ulp
 machinery is the shared f32 lattice (ordinals, NaN conventions, the
 f64-adjudicated verdict classes the divergence ledger persists into
 NUMERICS_BASELINE.json); numerics-off programs stay HLO byte-identical
-(the kernel seam here, all 62 perf_gate entry points plus the
+(the kernel seam here, all 41 perf_gate entry points plus the
 bit-identity behavioral twin in CI via --numproof); numerics without
 margins is rejected at the kernel and host impls at config time; every
 engine (flat, hierarchical, async) emits one schema-v14 ``numerics``
@@ -540,22 +540,22 @@ def test_numerics_gate_banding_rules():
                                       "verdict": verdict,
                                       "band_ulps": 8}}}
 
-    base = {"Krum/topk": cell(), "Median/pallas": cell(0, "exact")}
+    base = {"Krum/topk": cell(), "Median/native_host": cell(0, "exact")}
     ok = ng.diff(base, {"Krum/topk": cell(),
-                        "Median/pallas": cell(0, "exact")})
+                        "Median/native_host": cell(0, "exact")})
     assert not ok
     # Envelope growth fails; shrink passes.
     assert ng.diff(base, {"Krum/topk": cell(5),
-                          "Median/pallas": cell(0, "exact")})
+                          "Median/native_host": cell(0, "exact")})
     assert not ng.diff(base, {"Krum/topk": cell(1),
-                              "Median/pallas": cell(0, "exact")})
+                              "Median/native_host": cell(0, "exact")})
     # Verdict flip fails even inside the band.
     assert ng.diff(base, {"Krum/topk": cell(),
-                          "Median/pallas": cell(0, "b_closer")})
+                          "Median/native_host": cell(0, "b_closer")})
     # Availability flip (a cell vanishing or erroring) fails.
     assert ng.diff(base, {"Krum/topk": cell()})
     assert ng.diff(base, {"Krum/topk": cell(),
-                          "Median/pallas": {"cohorts": {
+                          "Median/native_host": {"cohorts": {
                               "drift": {"skipped": "impl unavailable"}}}})
 
 
@@ -568,7 +568,7 @@ def test_numerics_baseline_is_fresh():
         base = json.load(f)
     assert base["tie_band_ulps"] == N.TIE_BAND_ULPS
     cells = base["cells"]
-    assert len(cells) >= 15
+    assert len(cells) >= 13
     for name, cell in cells.items():
         assert "/" in name
         for rec in cell["cohorts"].values():

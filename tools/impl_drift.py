@@ -2,15 +2,14 @@
 """Cross-implementation divergence ledger (ISSUE 20).
 
 Every defense in this repo ships several implementations that are
-supposed to agree — the XLA kernels, the pallas (Mosaic/interpret)
-tiles, the native C++ selection engine, the host BLAS routes, the
+supposed to agree — the XLA kernels, the native C++ selection
+engine, the host BLAS routes, the
 masked/weighted fault- and staleness-seam variants, and two shipped
 traversal orders for the hierarchical tier-1 sweep (vmap'd shards vs a
 lax.scan over shards).  History says "supposed to agree" needs a
 measured envelope, not faith: the PR 4 bulyan-blockwise cascade was a
-1-ulp Gram cancellation, tests/test_native.py pins a 3/1000 <=1-ulp
-tie-swap band, and tests/test_pallas.py documents reduction-order
-bands for the fused distance kernels.
+1-ulp Gram cancellation and tests/test_native.py pins a 3/1000
+<=1-ulp tie-swap band.
 
 This tool runs every available impl pair over identical seeded
 attack-shaped cohorts (a DriftAttack-shaped cohort plus a near-tie one
@@ -137,10 +136,6 @@ def _variants() -> dict:
                 "topk": arr(lambda G: krum(G, N, F, method="topk")),
                 "dist_host": arr(
                     lambda G: krum(G, N, F, distance_impl="host")),
-                "dist_pallas": arr(
-                    lambda G: krum(G, N, F, distance_impl="pallas")),
-                "scores_pallas": arr(
-                    lambda G: krum(G, N, F, scores_impl="pallas")),
                 "masked": arr(lambda G: krum(G, N, F, mask=ones(N))),
             }),
         "TrimmedMean": (
@@ -149,8 +144,6 @@ def _variants() -> dict:
             {
                 "native_host": arr(
                     lambda G: trimmed_mean(G, N, F, impl="host")),
-                "pallas": arr(
-                    lambda G: trimmed_mean(G, N, F, impl="pallas")),
                 "masked": arr(
                     lambda G: trimmed_mean(G, N, F, mask=ones(N))),
                 "weighted": arr(
@@ -163,7 +156,6 @@ def _variants() -> dict:
             {
                 "native_host": arr(
                     lambda G: median(G, N, F, impl="host")),
-                "pallas": arr(lambda G: median(G, N, F, impl="pallas")),
                 "masked": arr(lambda G: median(G, N, F, mask=ones(N))),
                 "weighted": arr(
                     lambda G: median(G, N, F, mask=ones(N),

@@ -2,7 +2,7 @@
 """Numeric-drift gate over the cross-implementation divergence ledger.
 
 tools/impl_drift.py measures, for every shipped impl pair of every
-defense (xla / pallas-interpret / native / host, masked / weighted
+defense (xla / native / host, masked / weighted
 variants, the scan-vs-sharded hier traversal), the f32 ulp envelope
 between the pair on identical seeded cohorts plus an f64-adjudicated
 verdict (defenses/oracle.py in double as referee).  This gate persists

@@ -75,18 +75,6 @@ CELLS = {
     "hier_krum_tele": dict(defense="Krum", aggregation="hierarchical",
                            users_count=12, mal_prop=0.25, megabatch=4,
                            telemetry=True),
-    # ISSUE 11: the Pallas defense-kernel suite (interpret-mode HLO on
-    # CPU — the facts pin the emulation program's drift; the
-    # fused-vs-XLA fusion WIN is pinned by --pallasproof below, which
-    # compares accounting-compatible models, not emulation bytes).
-    "krum_pallas": dict(defense="Krum", aggregation_impl="pallas"),
-    "trimmed_mean_pallas": dict(defense="TrimmedMean",
-                                aggregation_impl="pallas"),
-    "median_pallas": dict(defense="Median", aggregation_impl="pallas"),
-    "bulyan_pallas": dict(defense="Bulyan", aggregation_impl="pallas"),
-    "hier_krum_pallas": dict(defense="Krum", aggregation="hierarchical",
-                             users_count=12, mal_prop=0.25, megabatch=4,
-                             aggregation_impl="pallas"),
 }
 
 EXACT = ("flops", "bytes_accessed", "argument_bytes", "output_bytes",
@@ -290,83 +278,6 @@ def wireproof() -> int:
     print(f"ok   perf_gate wireproof: secagg-vanilla round @ n={n}: "
           f"u32 wire present, unmask feeds only the cohort-sum "
           f"reduce, no (n, n) distance matrix")
-    return pallasproof()
-
-
-# --- pallas fusion proof (ISSUE 11 acceptance) -------------------------
-# Baseline-free like the memproof: at the 10k north star the fused
-# distance->Krum-score kernel must beat the XLA Gram+epilogue path on
-# HBO bytes in the SAME accounting convention — XLA's cost_analysis
-# counts each logical operand/output once, so the kernel's comparison
-# number is its exact operands-once model
-# (ops/pallas_defense.py:krum_scores_cost; the interpret emulation's
-# own cost_analysis counts the grid loop body once and is not
-# comparable in either direction).  Two structural witnesses ride
-# along: the compiled fused program contains NO f32[n,n] tensor while
-# the compiled XLA path does — the (n, n) matrix, its second HBM pass
-# and the hybrid's pure_callback marshal are all gone on the pallas
-# route.
-
-PALLASPROOF = dict(n=10_240, d=79_510, f_frac=0.24)
-
-
-def pallasproof() -> int:
-    import jax
-    import jax.numpy as jnp
-
-    from attacking_federate_learning_tpu.defenses.kernels import (
-        _krum_scores
-    )
-    from attacking_federate_learning_tpu.ops.distances import (
-        pairwise_distances
-    )
-    from attacking_federate_learning_tpu.ops.pallas_defense import (
-        krum_scores_cost, pallas_krum_scores
-    )
-    from attacking_federate_learning_tpu.utils.costs import (
-        compiled_cost_facts
-    )
-
-    n, d = PALLASPROOF["n"], PALLASPROOF["d"]
-    f = int(PALLASPROOF["f_frac"] * n)
-    sds = jax.ShapeDtypeStruct((n, d), jnp.float32)
-    fused_c = jax.jit(
-        lambda g: pallas_krum_scores(g, n, f)[0]).lower(sds).compile()
-    xla_c = jax.jit(
-        lambda g: _krum_scores(pairwise_distances(g), n, f,
-                               method="sort")).lower(sds).compile()
-    xla_facts = compiled_cost_facts(xla_c)
-    model = krum_scores_cost(n, d, f)
-    nn = f"f32[{n},{n}]"
-    problems = []
-    if nn in fused_c.as_text():
-        problems.append(
-            f"pallasproof: {nn} tensor present in the fused "
-            f"distance->score program — the (n, n) matrix is back")
-    if nn not in xla_c.as_text():
-        problems.append(
-            f"pallasproof: comparison baseline degenerate — the XLA "
-            f"Gram+epilogue path no longer materializes {nn}")
-    if not model["bytes_accessed"] < xla_facts["bytes_accessed"]:
-        problems.append(
-            f"pallasproof: fused-kernel operands-once bytes "
-            f"{model['bytes_accessed']:.3e} not below the XLA "
-            f"Gram+epilogue path's measured "
-            f"{xla_facts['bytes_accessed']:.3e}")
-    if problems:
-        print(f"FAIL perf_gate --pallasproof: {len(problems)} "
-              f"violation(s)")
-        for prob in problems:
-            print(f"  {prob}")
-        return 1
-    ratio = model["bytes_accessed"] / xla_facts["bytes_accessed"]
-    print(f"ok   perf_gate pallasproof: fused krum-score kernel @ "
-          f"n={n}, d={d}: {model['bytes_accessed'] / 1e9:.1f} GB "
-          f"(operands-once) vs XLA path "
-          f"{xla_facts['bytes_accessed'] / 1e9:.1f} GB "
-          f"({100 * ratio:.0f}%); no {nn} tensor on the pallas route "
-          f"(tile traffic {model['hbm_tile_bytes'] / 1e9:.0f} GB at "
-          f"CI blocks)")
     return shardproof()
 
 
@@ -545,18 +456,7 @@ def shardproof() -> int:
 # ledger (ledger <= measured <= 1.25x ledger).
 
 STAGEPROOF = dict(flops_floor=0.95, bytes_floor=0.85, coll_slack=1.25,
-                  # The pallas cells compile the CPU interpret-mode
-                  # EMULATION (the same stand-in --pallasproof declares
-                  # non-comparable): its grid-loop marshaling copies
-                  # and rewritten prefix-sum reduce-windows carry no op
-                  # metadata at all, so their mass is unattributable by
-                  # construction — on the TPU route the kernel is one
-                  # custom-call traced inside the dispatch scope.  The
-                  # relaxed floors still pin the emulation cells'
-                  # attribution from drifting further.
-                  emu_floors=(0.75, 0.50),
-                  fingerprint_cells=("krum", "hier_krum",
-                                     "trimmed_mean_pallas"))
+                  fingerprint_cells=("krum", "hier_krum"))
 
 
 def _round_compiled(exp):
@@ -595,22 +495,17 @@ def stageproof(cells=None) -> int:
         att = stage_attribution(compiled.as_text(), facts)
         cov_f = att["coverage"]["flops"]
         cov_b = att["coverage"]["bytes_accessed"]
-        emu = CELLS[name].get("aggregation_impl") == "pallas"
-        f_floor, b_floor = (STAGEPROOF["emu_floors"] if emu else
-                            (STAGEPROOF["flops_floor"],
-                             STAGEPROOF["bytes_floor"]))
-        if not emu:
-            covs.append(cov_f)
+        f_floor = STAGEPROOF["flops_floor"]
+        b_floor = STAGEPROOF["bytes_floor"]
+        covs.append(cov_f)
         if cov_f < f_floor:
             problems.append(
                 f"stageproof[{name}]: named-stage FLOP coverage "
-                f"{cov_f:.1%} below the {f_floor:.0%} floor"
-                + (" (interpret-emulation floor)" if emu else ""))
+                f"{cov_f:.1%} below the {f_floor:.0%} floor")
         if cov_b < b_floor:
             problems.append(
                 f"stageproof[{name}]: named-stage byte coverage "
-                f"{cov_b:.1%} below the {b_floor:.0%} floor"
-                + (" (interpret-emulation floor)" if emu else ""))
+                f"{cov_b:.1%} below the {b_floor:.0%} floor")
         for metric, total in (("flops", facts.get("flops")),
                               ("bytes_accessed",
                                facts.get("bytes_accessed")),
@@ -701,10 +596,7 @@ def stageproof(cells=None) -> int:
             if coll is not None else "")
     print(f"ok   perf_gate stageproof: {len(names)} cells partition "
           f">= {STAGEPROOF['flops_floor']:.0%} of FLOPs into named "
-          f"stages (min {min(covs):.1%} over the faithful programs)"
-          if covs else
-          f"ok   perf_gate stageproof: {len(names)} emulation cells "
-          f"hold the interpret floors", end="")
+          f"stages (min {min(covs, default=1.0):.1%})", end="")
     print(f", stage sums exact, "
           f"{len([c for c in names if c in STAGEPROOF['fingerprint_cells']])} "
           f"scopes-off twins fingerprint-identical, hier "
@@ -721,7 +613,7 @@ def stageproof(cells=None) -> int:
 #     observatory kwargs lowers to HLO text byte-identical to the
 #     explicit margins=False, numerics=False spelling — the kwargs
 #     leave zero residue when off (the off-path COST identity across
-#     all 62 baseline entry points is pinned by the main gate, which
+#     all 41 baseline entry points is pinned by the main gate, which
 #     chains into this proof);
 # (b) behavioral twin: a numerics-ON pinned experiment reaches
 #     bit-identical weights to its numerics-OFF twin — the counters
@@ -871,18 +763,10 @@ def main(argv=None) -> int:
     p.add_argument("--memproof", action="store_true",
                    help="additionally run the hierarchical O(m*d) "
                         "memory proof at the 10k north star, the "
-                        "secagg-vanilla wire proof, the pallas "
-                        "fusion proof, the hierarchical SPMD shard "
-                        "proof and the stage/wire-ledger proof "
-                        "(absolute structural facts, no baseline; "
-                        "tools/smoke.sh leg 4 runs all five)")
-    p.add_argument("--pallasproof", action="store_true",
-                   help="run ONLY the pallas fusion proof (+ the "
-                        "chained shard proof): the fused "
-                        "distance->Krum-score kernel's operands-once "
-                        "bytes must beat the XLA Gram+epilogue path "
-                        "at the 10k north star and no (n, n) tensor "
-                        "may exist on the pallas route (ISSUE 11)")
+                        "secagg-vanilla wire proof, the hierarchical "
+                        "SPMD shard proof and the stage/wire-ledger "
+                        "proof (absolute structural facts, no "
+                        "baseline; tools/smoke.sh leg 4 runs all four)")
     p.add_argument("--shardproof", action="store_true",
                    help="run ONLY the hierarchical SPMD shard proof "
                         "(ISSUE 12): every pinned hier cell on a "
@@ -922,8 +806,6 @@ def main(argv=None) -> int:
 
     if args.shardproof and not args.memproof:
         return shardproof()
-    if args.pallasproof and not args.memproof:
-        return pallasproof()
     if args.numproof and not args.memproof:
         return numproof()
 

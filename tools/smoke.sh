@@ -1,7 +1,7 @@
 #!/usr/bin/env bash
 # One-liner CI smoke: event-schema validation + fault matrix + crash
 # matrix + perf gate (incl. hierarchical memproof + secagg wireproof +
-# pallas fusion proof + stage/wire-ledger stageproof) +
+# stage/wire-ledger stageproof) +
 # science gate + registry selfcheck + hierarchical-aggregation smoke +
 # secure-aggregation smoke + hierarchical-telemetry/forensics smoke +
 # asynchronous-rounds smoke + campaign-engine kill/resume smoke +
@@ -122,8 +122,8 @@ else
     echo "== smoke 3/16: crash_matrix — skipped (--fast) =="
 fi
 
-echo "== smoke 4/16: perf_gate (+ memproof + wireproof + pallasproof"
-echo "   + shardproof + stageproof) =="
+echo "== smoke 4/16: perf_gate (+ memproof + wireproof + shardproof"
+echo "   + stageproof) =="
 python tools/perf_gate.py --memproof || fail=1
 
 echo "== smoke 5/16: science_gate (behavioral drift) =="
