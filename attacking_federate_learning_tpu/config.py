@@ -395,10 +395,13 @@ class ExperimentConfig:
     # Krum scores sum the n-f smallest distances (reference defences.py:26,
     # 33-34) rather than the paper's n-f-2.
     krum_paper_scoring: bool = False
-    # Score evaluation strategy: 'sort' (default — oracle-verified and
-    # cancellation-free under arbitrary attacker magnitudes), 'topk'
+    # Score evaluation strategy: 'sort' (default — the exact evaluator,
+    # oracle-verified and cancellation-free under arbitrary attacker
+    # magnitudes; from kernels.KRUM_SELECT_MIN_ROWS rows up it selects
+    # each row's k-th smallest distance instead of ordering the row,
+    # the same sum, chosen by the static n), 'topk'
     # (complement subtraction — cheaper at large n / small f; carries a
-    # runtime cancellation guard that re-evaluates via the sort path
+    # runtime cancellation guard that re-evaluates via 'sort'
     # whenever the subtraction would lose precision, so it is safe under
     # adversarial magnitudes too — kernels.py:_krum_scores), or 'auto'
     # (pick by shape).  The round-1 CPU bench regression attributed to

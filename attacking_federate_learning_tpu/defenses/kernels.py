@@ -7,7 +7,8 @@ instead of Python loops:
 
 - Krum's O(n^2 * d) pairwise-distance dict (reference defences.py:16-21)
   becomes a Gram matmul (ops/distances.py; for a large cohort its upper
-  block triangle, each pair once) + a top_k reduction.
+  block triangle, each pair once) + a per-row sum of the k smallest
+  (a sort below ``KRUM_SELECT_MIN_ROWS`` rows, a selection from there up).
 - TrimmedMean's per-coordinate Python loop (reference defences.py:44-52)
   becomes a stable argsort along the client axis + masked mean.
 - Bulyan's destructive dict-popping selection loop (reference
@@ -118,6 +119,19 @@ _INF = jnp.inf
 # 1e4 keeps the relative score error under ~1e-4 whenever topk is used;
 # below that the evaluation falls back to the exact sort path.
 _TOPK_GUARD = 1e4
+# Rows from which Krum's exact evaluator selects the k-th smallest
+# distance of a row instead of sorting the row (:func:`_select_scores`).
+# Below it the program is the sort's, instruction for instruction.  On
+# the v5e the two read the same at n = 512 and the selection wins from
+# 1,024 (0.20 against 0.29 ms; 7.3 against 106.1 at n = 10,240): PERF.md
+# §6 (PR 33) has the sweep.
+KRUM_SELECT_MIN_ROWS = 1024
+# Bits of the threshold's pattern one pass over D decides: 2**bits - 1
+# candidates counted a pass, ceil(31 / bits) passes.  A pass is bound by
+# its read of D, so wider is faster until the compares catch up: 18.1 /
+# 9.7 / 7.3 ms for 1 / 2 / 3 at n = 10,240 on the v5e (PERF.md §6, PR 33).
+_SELECT_BITS = 3
+_INF_KEY = 0x7F800000           # the int32 pattern of f32 +inf
 
 
 def resolve_distance_impl(distance_impl, users_count=None, users_grads=None):
@@ -296,6 +310,64 @@ def no_defense(users_grads, users_count, corrupted_count, telemetry=False,
     return agg, {}
 
 
+def _select_scores(D, k, alive):
+    """Sum of each row's ``k`` smallest off-diagonal (alive) distances by
+    selection: exactly what ``sum(sort(row)[:k])`` adds, without the order.
+
+    Every entry is >= +0 or +inf, and for such f32 values the int32 bit
+    pattern orders as the values do.  Per row the pattern ``t`` of the
+    k-th smallest entry is the largest ``t`` with ``#(key < t) < k``,
+    found from the high bit down, ``_SELECT_BITS`` bits a pass: a pass is
+    one fused compare + row count over D that writes (n,) vectors.  The
+    score is then ``sum(D[key < t]) + (k - #(key < t)) * value(t)``, the
+    ties at the threshold included.  A row with fewer than ``k`` finite
+    entries ends on a non-finite ``t`` and sums its finite entries only.
+
+    The +inf of the diagonal and of dead rows/columns, and the key, are
+    recomputed inside every pass from broadcast iotas so that they fuse
+    into the count: no (n, n) value exists beside D.  ``k`` may be
+    traced; the passes only compare against it.  The sign bit is cleared
+    in the key, so a -0.0 orders as +0.0 and any NaN above +inf, as
+    ``jnp.sort`` places them."""
+    n = D.shape[0]
+    hi = jnp.int32(_INF_KEY)
+
+    def keyed():
+        hole = (lax.broadcasted_iota(jnp.int32, (n, n), 0)
+                == lax.broadcasted_iota(jnp.int32, (n, n), 1))
+        if alive is not None:
+            hole = hole | ~(alive[None, :] & alive[:, None])
+        Dm = jnp.where(hole, jnp.asarray(_INF, D.dtype), D)
+        return Dm, lax.bitcast_convert_type(Dm, jnp.int32) & 0x7FFFFFFF
+
+    def decide(i, carry):
+        # The last pass may reach below bit 0: its shift stops at 0 and
+        # its candidates re-set decided bits.  Counts are monotone in the
+        # candidate, so the largest one with count < k is still the
+        # answer's prefix, and its count the largest such count.
+        t, below = carry
+        shift = jnp.maximum(31 - _SELECT_BITS * (i + 1), 0)
+        key = keyed()[1]
+        best, best_below = t, below
+        for j in range(1, 1 << _SELECT_BITS):
+            cand = t | jnp.left_shift(jnp.int32(j), shift)
+            cnt = jnp.sum(key < cand[:, None], axis=1, dtype=jnp.int32)
+            fits = cnt < k
+            best = jnp.where(fits, jnp.maximum(best, cand), best)
+            best_below = jnp.where(fits, jnp.maximum(best_below, cnt),
+                                   best_below)
+        return best, best_below
+
+    zeros = jnp.zeros((n,), jnp.int32)
+    t, below = lax.fori_loop(0, -(-31 // _SELECT_BITS), decide,
+                             (zeros, zeros))
+    Dm, key = keyed()
+    total = jnp.sum(jnp.where(key < jnp.minimum(t, hi)[:, None], Dm, 0.0),
+                    axis=1)
+    tied = (k - below).astype(D.dtype) * lax.bitcast_convert_type(t, D.dtype)
+    return total + jnp.where(t < hi, tied, 0.0)
+
+
 @functools.partial(stage_wrapped, stage="select")
 def _krum_scores(D, users_count, corrupted_count, alive=None,
                  paper_scoring=False, method="sort"):
@@ -309,7 +381,15 @@ def _krum_scores(D, users_count, corrupted_count, alive=None,
     (SURVEY.md §2.4 #4).
 
     Two exact evaluation strategies:
-    - 'sort': full ascending sort per row + masked prefix sum.
+    - 'sort': the exact evaluator, no subtraction: the sum of each row's
+      k smallest participating entries.  Below ``KRUM_SELECT_MIN_ROWS``
+      rows that is a full ascending sort per row + masked prefix sum;
+      from there up (f32 D) it is :func:`_select_scores`, which finds the
+      k-th smallest entry by bisection on the bit pattern and adds what
+      lies below it — the same k values in row order instead of sorted
+      order (~1e-7 relative), never the order itself.  The rule reads the
+      static ``n`` only; there is no option.  Either form sums a row's
+      finite entries only when it has fewer than k of them.
     - 'topk': complement identity.  A row always has exactly k + c
       participating entries where c = f - 1 (+2 under paper scoring) is
       *independent of Bulyan's shrinking pool*, so
@@ -323,7 +403,7 @@ def _krum_scores(D, users_count, corrupted_count, alive=None,
     ~eps * log2(n) * rowsum, so whenever any row's kept mass falls below
     ``_TOPK_GUARD * eps * log2(n) * rowsum`` (relative score error no
     longer <= 1/_TOPK_GUARD-ish) the evaluation falls back to the
-    cancellation-free sort path via ``lax.cond`` — one branch executes at
+    cancellation-free 'sort' evaluator via ``lax.cond`` — one branch executes at
     runtime, so the benign large-n/small-f regime keeps topk's cost while
     adversarial magnitudes (reference malicious.py-scale rows, which
     concentrate the rowsum in the complement) get sort's exactness
@@ -338,12 +418,17 @@ def _krum_scores(D, users_count, corrupted_count, alive=None,
     if method == "auto":
         method = "topk" if (0 <= complement <= max(n // 4, 1)) else "sort"
 
+    def keep_count():
+        return users_count - corrupted_count - (2 if paper_scoring else 0)
+
     def sort_scores():
+        if n >= KRUM_SELECT_MIN_ROWS and D.dtype == jnp.float32:
+            return _select_scores(D, keep_count(), alive)
         Dm = D + jnp.diag(jnp.full((n,), _INF, D.dtype))
         if alive is not None:
             row_dead = jnp.where(alive, 0.0, _INF)
             Dm = Dm + row_dead[None, :] + row_dead[:, None]
-        k = users_count - corrupted_count - (2 if paper_scoring else 0)
+        k = keep_count()
         srt = jnp.sort(Dm, axis=1)  # ascending; masked entries land last
         prefix = (jnp.arange(n) < k) & jnp.isfinite(srt)
         return jnp.sum(jnp.where(prefix, srt, 0.0), axis=1)

@@ -342,3 +342,33 @@ def test_v5e_compiles_the_gram_panels_without_copying_the_wire_matrix(
     assert blocks >= 2, "the MLP cell's cohort no longer takes the panels"
     share = (blocks + 1) / (2 * blocks)
     assert compiled.cost_analysis()["flops"] < (share + 0.02) * 2 * n * n * d
+
+
+def test_v5e_compiles_krum_scores_without_a_sort(one_chip):
+    """Krum's score evaluator at the MLP cell's shape (n = 10,240,
+    f = 2,457): the v5e compiler's program has no ``sort`` (the parent's
+    stable sort of f32[10240,10240] carried an s32 payload of the same
+    shape, 100.9 ms a round, and the evaluator alone held 838,893,056
+    bytes of temporaries; PERF.md section 6, PR 33) and holds no (n, n)
+    value beside D: every pass rebuilds the key inside its reduce
+    fusion, so the temporaries stay under one such buffer (0 today)."""
+    from attacking_federate_learning_tpu.defenses import kernels
+
+    n, f = 10240, 2457
+    assert n >= kernels.KRUM_SELECT_MIN_ROWS, \
+        "the MLP cell's cohort no longer takes the selection"
+    D = jax.ShapeDtypeStruct((n, n), jnp.float32, sharding=one_chip)
+    with _no_persistent_cache():
+        compiled = jax.jit(
+            lambda D: kernels._krum_scores(D, n, f)).lower(D).compile()
+    text = compiled.as_text()
+    assert not re.search(r"\bsort\(", text)
+    assert " while(" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * n * n
+    # Outside the fused computations (whose instructions live in
+    # registers) nothing is (n, n) but D itself, as an entry parameter and
+    # as the loop's invariant operand.
+    written = [line.strip()[:160] for line in text.splitlines()
+               if re.search(r"= \w+\[%d,%d\]" % (n, n), line)
+               and " fusion(" in line]
+    assert not written, written
