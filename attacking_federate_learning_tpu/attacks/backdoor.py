@@ -102,26 +102,31 @@ class BackdoorAttack(Attack):
         self.poison_mask = jnp.asarray(bm)
         self.poison_count = float(n)
 
+    def operands(self):
+        """The poison set (batched x, y and validity mask): drawn from
+        the seed, so the jitted functions below take it as an argument
+        rather than closing over it."""
+        return self.poison_x, self.poison_y, self.poison_mask
+
     # ------------------------------------------------------------------
     def _build_fns(self):
         model, flat, cfg = self.model, self.flat, self.cfg
         alpha = self.alpha
-        px, py, pm = self.poison_x, self.poison_y, self.poison_mask
-        n_steps = cfg.mal_epochs * px.shape[0]
+        n_steps = cfg.mal_epochs * self.poison_x.shape[0]
         lr, wd = cfg.mal_learning_rate, cfg.mal_weight_decay
 
-        def poison_metrics(flat_w):
+        def poison_metrics(flat_w, poison):
             """(loss, correct) over the poisoned set (reference
             backdoor.py:67-102; test_loader is the train loader,
             backdoor.py:43; loss is the sum of per-batch mean NLLs divided
             by the set size, matching backdoor.py:89, :93)."""
             params = flat.unravel(flat_w)
             loss_sum, correct = masked_nll_metrics(model.apply, params,
-                                                   px, py, pm)
+                                                   *poison)
             return loss_sum / self.poison_count, correct
 
-        def poison_accuracy(flat_w):
-            _, correct = poison_metrics(flat_w)
+        def poison_accuracy(flat_w, poison):
+            _, correct = poison_metrics(flat_w, poison)
             return 100.0 * correct / self.poison_count
 
         def shadow_loss(params, anchor, x, y, m):
@@ -137,8 +142,9 @@ class BackdoorAttack(Attack):
 
         grad_fn = jax.grad(shadow_loss)
 
-        def train_shadow(start_flat):
+        def train_shadow(start_flat, poison):
             anchor = flat.unravel(start_flat)
+            px, py, pm = poison
 
             def do_train(w0):
                 def step(params, i):
@@ -157,10 +163,11 @@ class BackdoorAttack(Attack):
 
             # Early-out when the backdoor already fires at 100%
             # (reference backdoor.py:114-116).
-            return jax.lax.cond(poison_accuracy(start_flat) >= 100.0,
-                                lambda w: w, do_train, start_flat)
+            return jax.lax.cond(
+                poison_accuracy(start_flat, poison) >= 100.0,
+                lambda w: w, do_train, start_flat)
 
-        def craft(mal_grads, original_params, learning_rate,
+        def craft(mal_grads, original_params, learning_rate, poison,
                   delivered=None):
             # ``delivered`` (async rounds, core/async_rounds.py): the
             # clip envelope and the descent projection come from the
@@ -172,7 +179,7 @@ class BackdoorAttack(Attack):
             else:
                 mean, stdev = masked_cohort_stats(mal_grads, delivered)
             start = original_params - learning_rate * mean
-            mal_params = train_shadow(start)
+            mal_params = train_shadow(start, poison)
             new_params = mal_params + learning_rate * mean
             new_grads = (start - new_params) / learning_rate
             return jnp.clip(new_grads,
@@ -187,10 +194,11 @@ class BackdoorAttack(Attack):
         if ctx is not None and ctx.staleness is not None:
             f = mal_grads.shape[0]
             out = self._craft(mal_grads, ctx.original_params,
-                              ctx.learning_rate, ctx.staleness[:f] >= 0)
+                              ctx.learning_rate, self._operands_from(ctx),
+                              ctx.staleness[:f] >= 0)
         else:
             out = self._craft(mal_grads, ctx.original_params,
-                              ctx.learning_rate)
+                              ctx.learning_rate, self._operands_from(ctx))
         if not isinstance(out, jax.core.Tracer):
             # Staged/eager path: the reference's per-round host nan guard
             # (backdoor.py:145-152).  Inside a fused round program the
@@ -214,7 +222,8 @@ class BackdoorAttack(Attack):
                                            ctx.staleness[:f] >= 0)
         else:
             _, stdev = cohort_stats(users_grads[:f])
-        loss, correct = self._poison_metrics(ctx.original_params)
+        loss, correct = self._poison_metrics(ctx.original_params,
+                                             self._operands_from(ctx))
         return {"z": jnp.asarray(self.num_std, jnp.float32),
                 "clip_halfwidth_norm": jnp.asarray(
                     self.num_std, jnp.float32) * jnp.linalg.norm(stdev),
@@ -255,7 +264,8 @@ class BackdoorAttack(Attack):
         """Attack success rate of the *server* weights on the poisoned set
         (reference main.py:91-95 + backdoor.py:67-102); log line format
         matches reference backdoor.py:97-101."""
-        loss, correct = self._poison_metrics(jnp.asarray(flat_w))
+        loss, correct = self._poison_metrics(jnp.asarray(flat_w),
+                                             self.operands())
         acc = 100.0 * float(correct) / self.poison_count
         if logger is not None:
             logger.print(

@@ -19,7 +19,7 @@ reference main.py:28).  ``ctx`` carries what the reference stashes on user 0
 
 from __future__ import annotations
 
-from typing import NamedTuple, Optional
+from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -38,6 +38,12 @@ class AttackContext(NamedTuple):
     # (:func:`delivered_cohort_stats`) — the aggregation never sees the
     # rest.
     staleness: Optional[jax.Array] = None
+    # The attacker's own arrays (:meth:`Attack.operands`) as the round
+    # program received them: the engine passes them in as operands and
+    # hands them back here, so a jitted round does not close over a
+    # poison set or a seed's key.  None (a caller with no engine): the
+    # attack reads its own attributes.
+    operands: Any = None
 
 
 def cohort_stats(mal_grads):
@@ -83,6 +89,20 @@ class Attack:
 
     def __init__(self, num_std: float):
         self.num_std = num_std
+
+    def operands(self):
+        """The arrays this attack reads and never writes — a poison
+        set, a key derived from the seed — as one pytree, or None.  The
+        engine makes them operands of its round programs
+        (core/engine.py RoundData.attack) and returns them through
+        ``AttackContext.operands``."""
+        return None
+
+    def _operands_from(self, ctx):
+        """``ctx.operands`` where an engine bound them, else our own."""
+        if ctx is not None and ctx.operands is not None:
+            return ctx.operands
+        return self.operands()
 
     def craft(self, mal_grads, ctx: AttackContext):
         """(m, d) honest malicious-cohort grads -> (d,) crafted vector."""

@@ -34,11 +34,15 @@ class GaussianNoiseAttack(Attack):
         super().__init__(num_std)
         self._key = jax.random.key(seed)
 
+    def operands(self):
+        return self._key
+
     def craft(self, mal_grads, ctx=None):
         mean, stdev = cohort_stats(mal_grads)
         # Per-round key keeps the fused round a pure function of its
         # inputs while varying the noise each round.
         rnd = ctx.round if ctx is not None else 0
-        key = jax.random.fold_in(self._key, jnp.asarray(rnd, jnp.int32))
+        key = jax.random.fold_in(self._operands_from(ctx),
+                                 jnp.asarray(rnd, jnp.int32))
         noise = jax.random.normal(key, mean.shape, mean.dtype)
         return mean + self.num_std * stdev * noise

@@ -35,7 +35,7 @@ from __future__ import annotations
 import functools
 import os
 import time
-from typing import Optional
+from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -52,6 +52,9 @@ from attacking_federate_learning_tpu.core.evaluate import make_eval_fn
 from attacking_federate_learning_tpu.core.server import (
     ServerState, faded_learning_rate, init_server_state, momentum_update
 )
+from attacking_federate_learning_tpu.data.augment import (
+    augment_key, reflect_crop_flip
+)
 from attacking_federate_learning_tpu.data.datasets import load_dataset
 from attacking_federate_learning_tpu.data.partition import (
     make_shards, round_batch_indices
@@ -59,6 +62,7 @@ from attacking_federate_learning_tpu.data.partition import (
 from attacking_federate_learning_tpu.defenses import (
     DEFENSES, check_defense_args
 )
+from attacking_federate_learning_tpu.defenses.dnc import sketch_key
 from attacking_federate_learning_tpu.defenses.kernels import stage_wrapped
 from attacking_federate_learning_tpu.models.base import get_model
 from attacking_federate_learning_tpu.utils.costs import stage_scope
@@ -82,6 +86,32 @@ def _jsonable(v):
     if a.ndim == 1:
         return [float(x) for x in a]
     return a.astype(float).tolist()
+
+
+class RoundData(NamedTuple):
+    """What a round reads and never writes: the device-resident training
+    set, the client -> sample shards and every seed-derived array.  Built
+    once in ``FederatedExperiment.__init__`` (``self.data``, beside
+    ``self.state``) and passed as the first, never donated, operand of
+    every jitted round program — nothing here is closed over, so the
+    lowered program is the same text for every seed and every set of one
+    shape, and its compile-cache entry holds code only (PERF.md section
+    6, PR 34).  A field is None — an empty pytree, no operand — where
+    the configuration has no such array."""
+    train_x: Any = None      # (N, F) f32 rows; None under host_stream
+    train_y: Any = None      # (N,) int32
+    shards: Any = None       # (n, L) int32 client -> sample ids
+    part_key: Any = None     # the --participation cohort draw
+    style: Any = None        # femnist_style (a, b), each (n,)
+    augment_key: Any = None  # data/augment.py:augment_key
+    fault_key: Any = None    # core/faults.py:fault_key
+    secagg_key: Any = None   # protocols/secagg.py:secagg_key
+    traffic_key: Any = None  # core/population.py:traffic_key
+    async_key: Any = None    # core/async_rounds.py:async_key
+    latency: Any = None      # async traffic: (m,) latency scales
+    meta: Any = None         # FLTrust's trusted pool (meta_x, meta_y)
+    defense_key: Any = None  # defenses/dnc.py:sketch_key
+    attack: Any = None       # Attack.operands()
 
 
 class FederatedExperiment:
@@ -204,7 +234,6 @@ class FederatedExperiment:
         # None-ness is the run_span/run_round dispatch sentinel (hier
         # traffic is in-program slot resampling, no schedule operands).
         self._traffic_span = None
-        self._part_key = jax.random.key(cfg.seed ^ 0x9A47)
         self._krum_select_fn = None  # set for Krum (selection telemetry)
         self.last_round_telemetry = None   # cfg.telemetry, per-round modes
         self.last_span_telemetry = None    # cfg.telemetry, fused spans
@@ -223,7 +252,9 @@ class FederatedExperiment:
             # DnC's constants are config surface (the most constant-
             # sensitive defense), and its sketch keys flow from the
             # experiment seed so repeat runs with different seeds draw
-            # different coordinate subsets (defenses/dnc.py).
+            # different coordinate subsets (defenses/dnc.py); the round
+            # programs pass the same key as an operand
+            # (RoundData.defense_key, _aggregate_impl).
             self.defense_fn = functools.partial(
                 self.defense_fn, n_iters=cfg.dnc_iters,
                 sketch_dim=cfg.dnc_sketch_dim,
@@ -282,6 +313,8 @@ class FederatedExperiment:
             shards = make_shards(cfg.partition, self.dataset.train_y, self.n,
                                  cfg.seed, cfg.dirichlet_alpha)
         self._streaming = cfg.data_placement == "host_stream"
+        self._shards_host = shards    # collect_metadata reads the host's
+        train_x = train_y = dev_shards = None
         with host_span("setup.place_data"):
             if self._streaming:
                 # Beyond-HBM mode (SURVEY.md §7.3 #5): the training set stays
@@ -290,8 +323,6 @@ class FederatedExperiment:
                 from attacking_federate_learning_tpu.data.stream import (
                     HostStream
                 )
-                self.shards = shards                      # host numpy
-                self.train_x = self.train_y = None
                 self.stream = HostStream(self.dataset.train_x,
                                          self.dataset.train_y, shards,
                                          cfg.batch_size * cfg.local_steps,
@@ -304,18 +335,18 @@ class FederatedExperiment:
                 if shardings is not None:
                     self.state = shardings.place_state(self.state)
             else:
-                self.shards = jnp.asarray(shards)
+                dev_shards = jnp.asarray(shards)
                 # Row-contiguous storage: one sample = one (F,) row, the
                 # feature axis minor, so the batch gather moves whole
                 # rows (_gather_batches restores the sample shape).  A
                 # zero-copy view of the host array.
-                self.train_x = jnp.asarray(self.dataset.train_x.reshape(
+                train_x = jnp.asarray(self.dataset.train_x.reshape(
                     len(self.dataset.train_x), -1))
-                self.train_y = jnp.asarray(self.dataset.train_y)
+                train_y = jnp.asarray(self.dataset.train_y)
                 if shardings is not None:
-                    self.shards, self.train_x, self.train_y, self.state = (
-                        shardings.place(self.shards, self.train_x,
-                                        self.train_y, self.state,
+                    dev_shards, train_x, train_y, self.state = (
+                        shardings.place(dev_shards, train_x,
+                                        train_y, self.state,
                                         replicate_shards=self._hier_spmd))
 
         # FEMNIST-style feature shift (SURVEY §7.2 M4): each client sees
@@ -356,11 +387,45 @@ class FederatedExperiment:
         self.metadata = (self.collect_metadata()
                          if (cfg.collect_metadata
                              or self._needs_server_grad) else None)
-        if self._needs_server_grad:
+        # Everything a round program reads and never writes, as ONE
+        # operand pytree (RoundData): the set and the shards placed
+        # above, and each subsystem's seed-derived arrays.  The host
+        # planners (_fault_plan, the traffic registry) keep their own
+        # handles on the same keys.
+        latency = None
+        if self._async is not None and self.traffic is not None:
+            # Async traffic = latency-profile delivery: per-cohort-slot
+            # heavy-tail Pareto scales (materialized lazily from the
+            # population registry, never a (P,) tensor) replace the
+            # uniform 0..D arrival draw inside the ring
+            # (core/async_rounds.py:draw_delays).  The (m,) scales are
+            # the seed's, the tail is a config constant.
+            from attacking_federate_learning_tpu.core.population import (
+                async_latency_for_cfg
+            )
+            latency, self._latency_tail = async_latency_for_cfg(cfg, self.m)
+        self.data = RoundData(
+            train_x=train_x, train_y=train_y, shards=dev_shards,
+            part_key=(jax.random.key(cfg.seed ^ 0x9A47)
+                      if cfg.participation < 1.0 else None),
+            style=self._style,
+            augment_key=augment_key(cfg.seed) if self._augment else None,
+            fault_key=self._fault_key if self.faults is not None else None,
+            secagg_key=(self._secagg_key if self._secagg is not None
+                        else None),
+            traffic_key=(self._traffic_key if self.traffic is not None
+                         else None),
+            async_key=(self._async_key if self._async is not None
+                       else None),
+            latency=latency,
             # Validation-data defense (FLTrust): the server's own gradient
             # on the trusted metadata pool provides the trust anchor.
-            self._meta_x = jnp.asarray(self.metadata[0])
-            self._meta_y = jnp.asarray(self.metadata[1])
+            meta=((jnp.asarray(self.metadata[0]),
+                   jnp.asarray(self.metadata[1]))
+                  if self._needs_server_grad else None),
+            defense_key=(sketch_key(cfg.seed) if cfg.defense == "DnC"
+                         else None),
+            attack=self.attacker.operands())
         with host_span("setup.build_round_fns"):
             self._build_round_fns()
             self.evaluate = make_eval_fn(
@@ -588,7 +653,7 @@ class FederatedExperiment:
         concatenates them (server.py:62-77).  Returns (meta_x, meta_y) —
         the validation pool a FLTrust/Zeno-style defense can consume."""
         cfg = self.cfg
-        shards = np.asarray(self.shards)
+        shards = self._shards_host
         xs = np.asarray(self.dataset.train_x)
         ys = np.asarray(self.dataset.train_y)
         rng = np.random.default_rng(cfg.seed + 42)
@@ -624,30 +689,29 @@ class FederatedExperiment:
         return self.metadata
 
     # ------------------------------------------------------------------
-    def _maybe_augment(self, xs, t):
+    def _maybe_augment(self, data, xs, t):
         """In-program train-time augmentation where the reference pipeline
         has one (CIFAR100, data/augment.py)."""
         if self._augment:
-            from attacking_federate_learning_tpu.data.augment import (
-                reflect_crop_flip, round_augment_key
-            )
-            xs = reflect_crop_flip(xs, round_augment_key(self.cfg.seed, t))
+            xs = reflect_crop_flip(
+                xs, jax.random.fold_in(data.augment_key, t))
         return xs
 
-    def _apply_style(self, xs, participants):
+    @staticmethod
+    def _apply_style(data, xs, participants):
         """Per-client affine style transform ('femnist_style' partition):
         row i of the cohort batch becomes a_i*xs_i + b_i — one fused
         broadcast multiply-add inside the round program, so the feature
         shift costs nothing extra on device."""
-        if self._style is None:
+        if data.style is None:
             return xs
-        a, b = self._style
+        a, b = data.style
         if participants is not None:
             a, b = a[participants], b[participants]
         shape = (xs.shape[0],) + (1,) * (xs.ndim - 1)
         return a.reshape(shape) * xs + b.reshape(shape)
 
-    def _participants(self, t):
+    def _participants(self, data, t):
         """Round-t cohort ids, or None under full participation: the
         first m_mal entries are malicious ids (< f), the rest honest —
         random identities, static counts (config.participation).  The
@@ -660,7 +724,7 @@ class FederatedExperiment:
         from attacking_federate_learning_tpu.core.population import (
             legacy_cohort
         )
-        return legacy_cohort(self._part_key, t, self.n, self.f, self.m,
+        return legacy_cohort(data.part_key, t, self.n, self.f, self.m,
                              self.m_mal)
 
     def _participants_host(self, t):
@@ -673,11 +737,11 @@ class FederatedExperiment:
         try:
             cpu = jax.devices("cpu")[0]
         except RuntimeError:
-            return np.asarray(self._participants(t))
+            return np.asarray(self._participants(self.data, t))
         with jax.default_device(cpu):
-            return np.asarray(self._participants(t))
+            return np.asarray(self._participants(self.data, t))
 
-    def _gather_batches(self, t, participants=None):
+    def _gather_batches(self, data, t, participants=None):
         """Round-t minibatches for the round cohort (or the megabatch
         whose client ids are ``participants``): one (m, k*B) row gather
         from the device-resident dataset (replaces the reference's N
@@ -690,27 +754,31 @@ class FederatedExperiment:
         layout — sample axis minor — back through the gather, which then
         moves the batch element by element (228 ms a round at n=10,240
         against 8.6 ms of whole rows; PERF.md section 6, PR 26)."""
-        shards = (self.shards if participants is None
-                  else self.shards[participants])
+        shards = (data.shards if participants is None
+                  else data.shards[participants])
         idx = round_batch_indices(
             shards, t, self.cfg.batch_size * self.cfg.local_steps)
-        xs = self.train_x[idx].reshape(
+        xs = data.train_x[idx].reshape(
             idx.shape + self.dataset.train_x.shape[1:])
-        return xs, self.train_y[idx]
+        return xs, data.train_y[idx]
 
-    def _split_local_steps(self, xs, ys, participants, t):
+    def _split_local_steps(self, data, xs, ys, participants, t):
         """Style, augmentation, then the flat (m, k*B) batch split into
         k local-step minibatches — the tail of the ``gather`` sub-stage,
         shared by the flat cohort and the hierarchical megabatch."""
-        xs = self._apply_style(xs, participants)
-        xs = self._maybe_augment(xs, t)
+        xs = self._apply_style(data, xs, participants)
+        xs = self._maybe_augment(data, xs, t)
         k, B = self.cfg.local_steps, self.cfg.batch_size
         xs = xs.reshape((xs.shape[0], k, B) + xs.shape[2:])
         return xs, ys.reshape((ys.shape[0], k, B))
 
     def _compute_grads_impl(self, state: ServerState, t, batches=None,
-                            part=None):
-        """batches=None gathers from the device-resident dataset; the
+                            part=None, data=None):
+        """``data``: the round program's RoundData operand; None (a
+        caller outside the engine's own programs — the benchmark's
+        deliver span) binds ``self.data``.
+
+        batches=None gathers from the device-resident dataset; the
         host-streaming mode (cfg.data_placement='host_stream') passes the
         round's pre-transferred (xs, ys) instead.  ``part`` pre-empts
         the participation draw with explicit (m,) cohort ids — the
@@ -723,21 +791,23 @@ class FederatedExperiment:
         the cohort's gradients arriving at tier 1 (utils/costs.py:STAGES
         / SUBSTAGES; metadata-only annotation)."""
         cfg = self.cfg
+        if data is None:
+            data = self.data
         with stage_scope("deliver"):
             with stage_scope("gather"):
                 if batches is None:
                     if part is None:
-                        part = self._participants(t)
-                    xs, ys = self._gather_batches(t, part)
+                        part = self._participants(data, t)
+                    xs, ys = self._gather_batches(data, t, part)
                 else:
                     xs, ys = batches
                     # The streaming prefetcher derives the identical
                     # cohort ids (platform-invariant RNG,
                     # _participants_host), so re-deriving here keeps the
                     # style rows aligned with the streamed batch.
-                    part = (self._participants(t)
-                            if self._style is not None else None)
-                xs, ys = self._split_local_steps(xs, ys, part, t)
+                    part = (self._participants(data, t)
+                            if data.style is not None else None)
+                xs, ys = self._split_local_steps(data, xs, ys, part, t)
             # Clients train at the faded lr the server dispatches
             # (reference server.py:50-52; inert at k=1, user.py:80); the
             # pseudo-gradient divides by the lr the server will multiply
@@ -755,10 +825,13 @@ class FederatedExperiment:
                 grads = self.shardings.constrain_grads(grads)
         return grads
 
-    def _aggregate_impl(self, state: ServerState, grads, t, agg=None,
-                        telemetry=False, margins=False, numerics=False,
-                        mask=None, weights=None, action=None):
-        """``agg`` pre-empts the defense call — the Krum-telemetry round
+    def _aggregate_impl(self, data, state: ServerState, grads, t,
+                        agg=None, telemetry=False, margins=False,
+                        numerics=False, mask=None, weights=None,
+                        action=None):
+        """``data``: the program's RoundData operand (FLTrust's trusted
+        pool and DnC's sketch key live there).
+        ``agg`` pre-empts the defense call — the Krum-telemetry round
         computes the selection once and aggregates ``grads[sel]`` rather
         than running the O(n^2 d) distance engine twice.  ``telemetry``
         (static bool) asks the defense for its diagnostics pytree and
@@ -791,10 +864,12 @@ class FederatedExperiment:
                     # same attribute seam FLTrust uses for
                     # needs_server_grad.
                     kw["round"] = t
+                    if data.defense_key is not None:
+                        kw["key"] = data.defense_key
                 if self._needs_server_grad:
                     server_grad = jax.grad(
                         make_loss_fn(self.model, self.flat))(
-                        state.weights, self._meta_x, self._meta_y)
+                        state.weights, *data.meta)
                     kw["server_grad"] = server_grad
                 if telemetry:
                     if margins:
@@ -853,12 +928,15 @@ class FederatedExperiment:
         if cfg.aggregation == "async":
             return self._build_async_round_fns()
 
-        def ctx_for(state, t):
+        def ctx_for(state, t, data=None):
+            # data=None: a caller outside the round programs (the staged
+            # host seam, the benchmark's deliver span) gets self.data's.
             return AttackContext(
                 original_params=state.weights,
                 learning_rate=faded_learning_rate(
                     cfg.learning_rate, cfg.fading_rate, t),
-                round=t)
+                round=t,
+                operands=(self.data if data is None else data).attack)
 
         self._ctx_for = ctx_for  # single construction site for the seam
 
@@ -927,7 +1005,7 @@ class FederatedExperiment:
                           ("Krum", "TrimmedMean", "Median", "Bulyan"))
         self._kernel_numerics = kernel_num
 
-        def inject_and_quarantine(grads, t, fstate):
+        def inject_and_quarantine(data, grads, t, fstate):
             """Fault seam (core/faults.py): inject the round-t faults
             into the submitted matrix, then mask/zero what the server
             can detect.  Returns the aggregable matrix, the effective-
@@ -939,23 +1017,23 @@ class FederatedExperiment:
             )
             with stage_scope("quarantine"):
                 submitted, dropped, fstate2, fstats = apply_faults(
-                    grads, t, self._fault_key, fstate, self.faults,
+                    grads, t, data.fault_key, fstate, self.faults,
                     self.m_mal)
                 clean, mask, qstats = quarantine(submitted, dropped)
             return clean, mask, fstate2, {**fstats, **qstats}
 
         self._inject_and_quarantine = inject_and_quarantine
 
-        def attack_envelope(grads, state, t):
+        def attack_envelope(data, grads, state, t):
             """Pre-attack envelope stats (attacks/base.py seam), keyed
             ``attack_*`` into the telemetry pytree.  Stage ledger:
             observes the delivered/crafted matrix — ``deliver``."""
             with stage_scope("deliver"):
-                stats = self.attacker.envelope_stats(grads, self.m_mal,
-                                                     ctx_for(state, t))
+                stats = self.attacker.envelope_stats(
+                    grads, self.m_mal, ctx_for(state, t, data))
             return {"attack_" + k: v for k, v in stats.items()}
 
-        def attack_margins(pre, post, state, t):
+        def attack_margins(data, pre, post, state, t):
             """Attack-side envelope utilization (attacks/base.py
             margin_stats; cfg.margins): computed on the PRE-attack
             matrix with the POST-attack (crafted) matrix riding along,
@@ -963,7 +1041,8 @@ class FederatedExperiment:
             'margin' event.  Stage ledger: ``deliver``."""
             with stage_scope("deliver"):
                 stats = self.attacker.margin_stats(
-                    pre, self.m_mal, ctx_for(state, t), crafted=post)
+                    pre, self.m_mal, ctx_for(state, t, data),
+                    crafted=post)
             return {"margin_attack_" + k: v for k, v in stats.items()}
 
         def finish_telemetry(tele, grads, ddiag):
@@ -1001,7 +1080,7 @@ class FederatedExperiment:
                 secagg_cohort
             )
 
-            def secagg_step(agg_grads, mask, t):
+            def secagg_step(data, agg_grads, mask, t):
                 """Vanilla secure aggregation between the quarantine
                 and the (NoDefense-only) aggregation: mask every
                 submitted row in the uint32 bitcast domain, then
@@ -1011,27 +1090,28 @@ class FederatedExperiment:
                 aggregate — and the whole run — is byte-for-byte the
                 clear run's; the ``secagg_*`` stats ride the telemetry
                 plumbing into per-round 'secagg' events."""
-                return secagg_cohort(agg_grads, mask, self._secagg_key, t)
+                return secagg_cohort(agg_grads, mask, data.secagg_key, t)
 
             self._secagg_step = secagg_step
 
         if getattr(self.attacker, "fusable", True):
-            def fused_core(state, t, batches=None, fstate=None,
+            def fused_core(data, state, t, batches=None, fstate=None,
                            traffic=None):
                 part = traffic[0] if traffic is not None else None
                 grads = self._compute_grads_impl(state, t, batches,
-                                                 part=part)
-                tele = (attack_envelope(grads, state, t) if cfg.telemetry
-                        else {})
+                                                 part=part, data=data)
+                tele = (attack_envelope(data, grads, state, t)
+                        if cfg.telemetry else {})
                 pre_attack = grads if cfg.margins else None
                 with stage_scope("deliver"), stage_scope("craft"):
                     # Attack craft happens on the wire: what tier 1
                     # receives IS the crafted matrix.
                     grads = self.attacker.apply(grads, self.m_mal,
-                                                ctx_for(state, t))
+                                                ctx_for(state, t, data))
                 if cfg.margins:
                     tele = {**tele,
-                            **attack_margins(pre_attack, grads, state, t)}
+                            **attack_margins(data, pre_attack, grads,
+                                             state, t)}
                 if cfg.numerics:
                     # Numeric health at the delivery seam: the crafted
                     # wire matrix, before any quarantine can mask a
@@ -1061,12 +1141,12 @@ class FederatedExperiment:
                     mask = arrived
                 if self.faults is not None:
                     agg_grads, fmask, fstate, fstats = (
-                        inject_and_quarantine(agg_grads, t, fstate))
+                        inject_and_quarantine(data, agg_grads, t, fstate))
                     mask = fmask if mask is None else (mask & fmask)
                     tele = {**tele, **fstats}
                 if self._secagg is not None:
-                    agg_grads, sstats = self._secagg_step(agg_grads,
-                                                          mask, t)
+                    agg_grads, sstats = self._secagg_step(
+                        data, agg_grads, mask, t)
                     tele = {**tele, **sstats}
                 if cfg.numerics:
                     # Post-quarantine: what the defense actually
@@ -1078,7 +1158,7 @@ class FederatedExperiment:
                 act = traffic[2] if traffic is not None else None
                 if cfg.telemetry or cfg.margins or kernel_num:
                     new_state, ddiag = self._aggregate_impl(
-                        state, agg_grads, t, telemetry=True,
+                        data, state, agg_grads, t, telemetry=True,
                         margins=cfg.margins or kernel_num,
                         numerics=kernel_num, mask=mask, action=act)
                     tele = finish_telemetry(tele, agg_grads, ddiag)
@@ -1094,9 +1174,9 @@ class FederatedExperiment:
                         sel = diag_select(grads, self.m, self.m_mal)
                         aux["krum_selected"] = sel
                         agg = grads[sel]
-                    new_state = self._aggregate_impl(state, agg_grads, t,
-                                                     agg=agg, mask=mask,
-                                                     action=act)
+                    new_state = self._aggregate_impl(
+                        data, state, agg_grads, t, agg=agg, mask=mask,
+                        action=act)
                 if cfg.numerics:
                     # Post-apply: a nonfinite velocity is the server
                     # update already poisoned, whatever the cohort
@@ -1112,13 +1192,15 @@ class FederatedExperiment:
                         grads[: self.m_mal].astype(jnp.float32))).any()
 
             if self.traffic is not None:
-                def fused(state, t, sid, arrived, action, fstate=None):
+                def fused(data, state, t, sid, arrived, action,
+                          fstate=None):
                     """One traffic round: the host-sampled schedule row
                     (shard ids, arrival mask, ladder action) enters as
                     plain device operands — the compiled program never
                     sees the population, only the (m,) cohort."""
                     new_state, grads, aux, tele, fstate = fused_core(
-                        state, t, None, fstate, (sid, arrived, action))
+                        data, state, t, None, fstate,
+                        (sid, arrived, action))
                     diag = (round_diagnostics(grads, new_state, t, aux)
                             if cfg.log_round_stats else {})
                     bad = (crafted_nonfinite(grads)
@@ -1126,7 +1208,7 @@ class FederatedExperiment:
                            else jnp.asarray(False))
                     return new_state, diag, bad, tele, fstate
 
-                def traffic_span(state, t0, count, sids, arrs, acts,
+                def traffic_span(data, state, t0, count, sids, arrs, acts,
                                  fstate=None):
                     # Traffic span: like fault_span (scan, static count)
                     # but each round consumes its row of the host-
@@ -1138,7 +1220,7 @@ class FederatedExperiment:
                         s, bad, fs = carry
                         i, sid, arr, act = xs
                         s2, grads, _, tele, fs = fused_core(
-                            s, t0 + i, None, fs, (sid, arr, act))
+                            data, s, t0 + i, None, fs, (sid, arr, act))
                         if self._check_attack_nan:
                             bad = bad | crafted_nonfinite(grads)
                         return (s2, bad, fs), tele
@@ -1148,9 +1230,9 @@ class FederatedExperiment:
                         (jnp.arange(count), sids, arrs, acts))
                     return s, bad, fs, stacked
             elif self.faults is None:
-                def fused(state, t, batches=None):
-                    new_state, grads, aux, tele, _ = fused_core(state, t,
-                                                                batches)
+                def fused(data, state, t, batches=None):
+                    new_state, grads, aux, tele, _ = fused_core(
+                        data, state, t, batches)
                     diag = (round_diagnostics(grads, new_state, t, aux)
                             if cfg.log_round_stats else {})
                     bad = (crafted_nonfinite(grads)
@@ -1158,9 +1240,9 @@ class FederatedExperiment:
                            else jnp.asarray(False))
                     return new_state, diag, bad, tele
             else:
-                def fused(state, t, fstate, batches=None):
+                def fused(data, state, t, fstate, batches=None):
                     new_state, grads, aux, tele, fstate = fused_core(
-                        state, t, batches, fstate)
+                        data, state, t, batches, fstate)
                     diag = (round_diagnostics(grads, new_state, t, aux)
                             if cfg.log_round_stats else {})
                     bad = (crafted_nonfinite(grads)
@@ -1168,7 +1250,7 @@ class FederatedExperiment:
                            else jnp.asarray(False))
                     return new_state, diag, bad, tele, fstate
 
-            def fused_span(state, t0, count):
+            def fused_span(data, state, t0, count):
                 # One device program for `count` rounds: steady-state
                 # training between evals never returns to the host
                 # (the reference makes 3N+2 host->object calls per round,
@@ -1176,7 +1258,7 @@ class FederatedExperiment:
                 # so every span length shares one compilation.
                 def body(i, carry):
                     s, bad = carry
-                    s2, grads, _, _, _ = fused_core(s, t0 + i)
+                    s2, grads, _, _, _ = fused_core(data, s, t0 + i)
                     if self._check_attack_nan:
                         bad = bad | crafted_nonfinite(grads)
                     return s2, bad
@@ -1184,7 +1266,7 @@ class FederatedExperiment:
                 return jax.lax.fori_loop(0, count, body,
                                          (state, jnp.asarray(False)))
 
-            def tele_span(state, t0, count):
+            def tele_span(data, state, t0, count):
                 # Telemetry span: lax.scan stacks each round's telemetry
                 # pytree along a leading round axis, so `count` rounds
                 # still run as ONE device program and the host fetches
@@ -1194,7 +1276,7 @@ class FederatedExperiment:
                 # length; the eval cadence yields at most two).
                 def body(carry, i):
                     s, bad = carry
-                    s2, grads, _, tele, _ = fused_core(s, t0 + i)
+                    s2, grads, _, tele, _ = fused_core(data, s, t0 + i)
                     if self._check_attack_nan:
                         bad = bad | crafted_nonfinite(grads)
                     return (s2, bad), tele
@@ -1203,7 +1285,7 @@ class FederatedExperiment:
                     body, (state, jnp.asarray(False)), jnp.arange(count))
                 return s, bad, stacked
 
-            def fault_span(state, t0, count, fstate):
+            def fault_span(data, state, t0, count, fstate):
                 # Fault span: like tele_span (scan, static count, one
                 # program per eval/checkpoint interval) but the carry
                 # additionally threads the fault state (the straggler
@@ -1212,8 +1294,8 @@ class FederatedExperiment:
                 # are emitted per round whether or not cfg.telemetry.
                 def body(carry, i):
                     s, bad, fs = carry
-                    s2, grads, _, tele, fs = fused_core(s, t0 + i, None,
-                                                        fs)
+                    s2, grads, _, tele, fs = fused_core(data, s, t0 + i,
+                                                        None, fs)
                     if self._check_attack_nan:
                         bad = bad | crafted_nonfinite(grads)
                     return (s2, bad, fs), tele
@@ -1230,11 +1312,11 @@ class FederatedExperiment:
                 # surface the CPU donation distrust already covers).
                 self._fused_round = jax.jit(fused)
                 self._traffic_span = jax.jit(traffic_span,
-                                             static_argnums=2)
+                                             static_argnums=3)
             elif self.faults is None:
                 self._fused_round = jax.jit(fused, **donate)
                 self._fused_span = jax.jit(fused_span, **donate)
-                self._tele_span = jax.jit(tele_span, static_argnums=2,
+                self._tele_span = jax.jit(tele_span, static_argnums=3,
                                           **donate)
             else:
                 # The fault paths never donate (any backend): the fault
@@ -1242,7 +1324,7 @@ class FederatedExperiment:
                 # aliasing surface beyond what _donate_kw's CPU rationale
                 # already distrusts.
                 self._fused_round = jax.jit(fused)
-                self._fault_span = jax.jit(fault_span, static_argnums=2)
+                self._fault_span = jax.jit(fault_span, static_argnums=3)
             self._staged = False
         else:
             if self.traffic is not None:
@@ -1252,7 +1334,9 @@ class FederatedExperiment:
                 raise ValueError(
                     "the traffic engine requires a fusable attack (the "
                     "staged host-eager path has no arrival seam)")
-            self._compute_grads = jax.jit(self._compute_grads_impl)
+            self._compute_grads = jax.jit(
+                lambda data, state, t, batches=None:
+                self._compute_grads_impl(state, t, batches, data=data))
             # Staged rounds already cross the host boundary every round,
             # so on the CPU backend a Krum/Bulyan aggregation runs EAGERLY:
             # the kernel then sees concrete arrays and 'auto' resolves to
@@ -1343,12 +1427,13 @@ class FederatedExperiment:
         f1, f2, S = self._tier1_f, self._tier2_f, place.num_shards
         tier2_fn = self._tier2_fn
 
-        def ctx_for(state, t):
+        def ctx_for(state, t, data=None):
             return AttackContext(
                 original_params=state.weights,
                 learning_rate=faded_learning_rate(
                     cfg.learning_rate, cfg.fading_rate, t),
-                round=t)
+                round=t,
+                operands=(self.data if data is None else data).attack)
 
         self._ctx_for = ctx_for
         if not getattr(self.attacker, "fusable", True):
@@ -1404,7 +1489,7 @@ class FederatedExperiment:
                 return num_on
             return tele_on
 
-        def megabatch_grads(ids, c_mal, state, t):
+        def megabatch_grads(ids, c_mal, data, state, t):
             """Deliver + train + attack for one megabatch — the shared
             front half of the clear and faulted scan steps (a Python
             extraction, not a trace change: the fault seam only ever
@@ -1420,12 +1505,12 @@ class FederatedExperiment:
                 # (composition matrix, ARCHITECTURE.md).
                 from attacking_federate_learning_tpu.core.population \
                     import resample_slots
-                ids = resample_slots(self._traffic_key, t, ids, c_mal,
+                ids = resample_slots(data.traffic_key, t, ids, c_mal,
                                      self.f, self.n)
             with stage_scope("deliver"):
                 with stage_scope("gather"):
-                    xs, ys = self._gather_batches(t, ids)
-                    xs, ys = self._split_local_steps(xs, ys, ids, t)
+                    xs, ys = self._gather_batches(data, t, ids)
+                    xs, ys = self._split_local_steps(data, xs, ys, ids, t)
                 lr_train = faded_learning_rate(cfg.learning_rate,
                                                cfg.fading_rate, t)
                 lr_report = (lr_train if cfg.server_uses_faded_lr
@@ -1442,7 +1527,7 @@ class FederatedExperiment:
                     grads = self.shardings.constrain_grads(grads)
                 with stage_scope("craft"):
                     grads = self.attacker.apply(grads, c_mal,
-                                                ctx_for(state, t))
+                                                ctx_for(state, t, data))
             with stage_scope("quarantine"):   # the fused nan guard
                 bad = (
                     (~jnp.isfinite(
@@ -1451,7 +1536,7 @@ class FederatedExperiment:
                     else jnp.asarray(False))
             return grads, bad
 
-        def shard_fn(ids, c_mal, state, t):
+        def shard_fn(ids, c_mal, data, state, t):
             """One megabatch: ids (m,) client ids (malicious first —
             the per-megabatch mirror of the rows-[0, f) invariant),
             c_mal its STATIC malicious count.  Returns the (d,) f32
@@ -1462,7 +1547,7 @@ class FederatedExperiment:
             kernel's telemetry on THIS shard's sub-matrix, stacked by
             client_map into the (S, ...) shard_selection record) and,
             in the clear modes, the per-row gradient norms."""
-            grads, bad = megabatch_grads(ids, c_mal, state, t)
+            grads, bad = megabatch_grads(ids, c_mal, data, state, t)
             if groupwise:
                 # NET-SA composition: the group's rows are secure-
                 # aggregated (masks keyed on these GLOBAL client ids,
@@ -1472,7 +1557,7 @@ class FederatedExperiment:
                 # bit-identical to the clear tier-1 mean, so the
                 # tier-2 robust pass over group sums is byte-for-byte
                 # the plain hierarchical NoDefense tier's.
-                grads, sum_ok = secagg_group(grads, self._secagg_key,
+                grads, sum_ok = secagg_group(grads, data.secagg_key,
                                              t, ids)
                 if not extras:
                     est = self.defense_fn(grads, m, f1)
@@ -1525,14 +1610,15 @@ class FederatedExperiment:
         cm_plan = self.shardings if self._hier_spmd else None
         t2_plan = None if self._hier_spmd else self.shardings
 
-        def hier_core(state, t):
+        def hier_core(data, state, t):
             tele = {}
             # Outer scope: the megabatch scan's own plumbing (carry
             # writes, estimate stacking) books under tier1_aggregate;
             # the finer scopes inside shard_fn win for everything they
             # annotate (stage_attribution takes the innermost token).
             with stage_scope("tier1_aggregate"):
-                out = client_map(shard_fn, place, state, t, plan=cm_plan)
+                out = client_map(shard_fn, place, data, state, t,
+                                 plan=cm_plan)
             norms = diag1 = sum_oks = None
             if extras:
                 ests, bads = out["est"], out["bad"]
@@ -1602,7 +1688,7 @@ class FederatedExperiment:
             else:
                 agg = shard_reduce(tier2_fn, ests, S, f2,
                                    plan=t2_plan)
-            new_state = self._aggregate_impl(state, None, t, agg=agg)
+            new_state = self._aggregate_impl(data, state, None, t, agg=agg)
             if num_on:
                 with stage_scope("apply"):
                     tele["num_nonfinite_agg"] = nonfinite_count(
@@ -1636,19 +1722,19 @@ class FederatedExperiment:
                             group_sum_norm_min=jnp.min(gs))
             return new_state, diag, bad, tele
 
-        def fused(state, t, batches=None):
+        def fused(data, state, t, batches=None):
             # `batches` mirrors the flat signature (run_round always
             # passes it); hierarchical is device-resident-only, so it
             # is always None (validated at init).
-            new_state, diag, bad, tele = hier_core(state, t)
+            new_state, diag, bad, tele = hier_core(data, state, t)
             return new_state, diag, bad, tele
 
-        def fused_span(state, t0, count):
+        def fused_span(data, state, t0, count):
             # Same traced-count fori_loop as the flat span: one
             # compilation covers every span length.
             def body(i, carry):
                 s, bad = carry
-                s2, _, b, _ = hier_core(s, t0 + i)
+                s2, _, b, _ = hier_core(data, s, t0 + i)
                 if self._check_attack_nan:
                     bad = bad | b
                 return s2, bad
@@ -1656,14 +1742,14 @@ class FederatedExperiment:
             return jax.lax.fori_loop(0, count, body,
                                      (state, jnp.asarray(False)))
 
-        def tele_span(state, t0, count):
+        def tele_span(data, state, t0, count):
             # Per-round telemetry pytrees (and groupwise secagg's
             # protocol stats) come back stacked, exactly like the flat
             # engine's telemetry span (static count: one compilation
             # per distinct span length).
             def body(carry, i):
                 s, bad = carry
-                s2, _, b, tele = hier_core(s, t0 + i)
+                s2, _, b, tele = hier_core(data, s, t0 + i)
                 if self._check_attack_nan:
                     bad = bad | b
                 return (s2, bad), tele
@@ -1696,7 +1782,6 @@ class FederatedExperiment:
             )
 
             faults = self.faults
-            fkey = self._fault_key
             straggler = faults.straggler > 0
             # Ladder step: the masked shard-median fallback kernel
             # (core/faults.py TIER2_FALLBACK — the widest-validity
@@ -1704,7 +1789,7 @@ class FederatedExperiment:
             self._tier2_fallback_fn = stage_wrapped(
                 TIER2_DEFENSES[TIER2_FALLBACK], "tier2_aggregate")
 
-            def fault_shard_fn(sid, ids, c_mal, state, t, ring):
+            def fault_shard_fn(sid, ids, c_mal, data, state, t, ring):
                 """Faulted megabatch step: the clear front half
                 (megabatch_grads — byte-identical trace) plus the
                 fault seam.  ``sid`` is the shard id threaded by
@@ -1714,7 +1799,8 @@ class FederatedExperiment:
                 count exactly.  ``ring`` is the (delay, S, m, d) stale
                 slab (a unit f32 dummy when straggler is off).
                 Returns a dict pytree; client_map stacks it (S, ...)"""
-                grads, bad = megabatch_grads(ids, c_mal, state, t)
+                grads, bad = megabatch_grads(ids, c_mal, data, state, t)
+                fkey = data.fault_key
                 with stage_scope("quarantine"):
                     old = (ring[jnp.mod(t, faults.straggler_delay), sid]
                            if straggler else None)
@@ -1740,7 +1826,7 @@ class FederatedExperiment:
                     # quarantine semantics, behind the protocol.
                     qmask = ~drop
                     recovered, sstats = secagg_group(
-                        faulted, self._secagg_key, t, ids, alive=qmask)
+                        faulted, data.secagg_key, t, ids, alive=qmask)
                     out["secagg"] = sstats
                     with stage_scope("quarantine"):
                         out["f_quarantined"] = (
@@ -1785,11 +1871,11 @@ class FederatedExperiment:
                 out["est"] = est.astype(jnp.float32)
                 return out
 
-            def fault_hier_core(state, t, action, fstate):
+            def fault_hier_core(data, state, t, action, fstate):
                 ring = (fstate["stale"] if straggler
                         else jnp.ones((), jnp.float32))
                 with stage_scope("tier1_aggregate"):
-                    out = client_map(fault_shard_fn, place, state, t,
+                    out = client_map(fault_shard_fn, place, data, state, t,
                                      ring, plan=cm_plan, with_sid=True)
                 ests, bads, alive = out["est"], out["bad"], out["alive"]
                 fstate2 = fstate
@@ -1805,7 +1891,7 @@ class FederatedExperiment:
                                                faults.straggler_delay),
                                        0)}
                 with stage_scope("quarantine"):
-                    dom = domain_alive_row(fkey, t, S, faults)
+                    dom = domain_alive_row(data.fault_key, t, S, faults)
                     # NaN-safety: a shard with zero aggregable rows has
                     # an undefined tier-1 estimate (0/0 mean); zero it
                     # before tier-2 (whose mask already excludes it) so
@@ -1898,8 +1984,8 @@ class FederatedExperiment:
                 agg = jnp.where(action == TRAFFIC_FALLBACK, fb, agg)
                 # HOLD rides _aggregate_impl's action seam (state-level
                 # jnp.where after the momentum update).
-                new_state = self._aggregate_impl(state, None, t, agg=agg,
-                                                 action=action)
+                new_state = self._aggregate_impl(data, state, None, t,
+                                                 agg=agg, action=action)
                 if num_on:
                     with stage_scope("apply"):
                         tele["num_nonfinite_agg"] = nonfinite_count(
@@ -1929,13 +2015,13 @@ class FederatedExperiment:
                                 group_sum_norm_min=jnp.min(gs))
                 return new_state, diag, bad, tele, fstate2
 
-            def fault_fused(state, t, action, fstate, batches=None):
+            def fault_fused(data, state, t, action, fstate, batches=None):
                 # `batches` mirrors the flat faulted signature
                 # (run_round always passes it); hierarchical is
                 # device-resident-only, so it is always None.
-                return fault_hier_core(state, t, action, fstate)
+                return fault_hier_core(data, state, t, action, fstate)
 
-            def fault_span(state, t0, count, fstate, actions):
+            def fault_span(data, state, t0, count, fstate, actions):
                 # Hier fault span: the flat fault_span's shape (scan,
                 # static count, stacked 'fault_*' pytree, fault state
                 # in the carry) plus the host-planned (count,) ladder
@@ -1944,7 +2030,7 @@ class FederatedExperiment:
                     s, bad, fs = carry
                     i, act = xs
                     s2, _, b, tele, fs = fault_hier_core(
-                        s, t0 + i, act, fs)
+                        data, s, t0 + i, act, fs)
                     if self._check_attack_nan:
                         bad = bad | b
                     return (s2, bad, fs), tele
@@ -1958,7 +2044,7 @@ class FederatedExperiment:
             # state rides the carry and the stacked-scan outputs add
             # aliasing surface).
             self._fused_round = jax.jit(fault_fused)
-            self._fault_span = jax.jit(fault_span, static_argnums=2)
+            self._fault_span = jax.jit(fault_span, static_argnums=3)
             self._staged = False
             return
 
@@ -1966,7 +2052,7 @@ class FederatedExperiment:
         self._fused_round = jax.jit(fused, **donate)
         self._fused_span = jax.jit(fused_span, **donate)
         if groupwise or cfg.telemetry or cfg.margins or cfg.numerics:
-            self._tele_span = jax.jit(tele_span, static_argnums=2,
+            self._tele_span = jax.jit(tele_span, static_argnums=3,
                                       **donate)
         self._staged = False
 
@@ -2023,25 +2109,14 @@ class FederatedExperiment:
 
         spec = self._async
         D = spec.depth
-        if self.traffic is not None:
-            # Async traffic = latency-profile delivery: per-cohort-slot
-            # heavy-tail Pareto scales (materialized lazily from the
-            # population registry, never a (P,) tensor) replace the
-            # uniform 0..D arrival draw inside the ring
-            # (core/async_rounds.py:draw_delays).
-            from attacking_federate_learning_tpu.core.population import (
-                async_latency_for_cfg
-            )
-            self._traffic_latency = async_latency_for_cfg(cfg, self.m)
-        else:
-            self._traffic_latency = None
 
-        def ctx_for(state, t, staleness=None):
+        def ctx_for(state, t, staleness=None, data=None):
             return AttackContext(
                 original_params=state.weights,
                 learning_rate=faded_learning_rate(
                     cfg.learning_rate, cfg.fading_rate, t),
-                round=t, staleness=staleness)
+                round=t, staleness=staleness,
+                operands=(self.data if data is None else data).attack)
 
         self._ctx_for = ctx_for
         # Same predicate as the flat path (the in-program shadow-train
@@ -2055,19 +2130,18 @@ class FederatedExperiment:
             return (~jnp.isfinite(
                 grads[: self.m_mal].astype(jnp.float32))).any()
 
-        def async_core(state, t, astate):
-            grads = self._compute_grads_impl(state, t)
+        def async_core(data, state, t, astate):
+            grads = self._compute_grads_impl(state, t, data=data)
             # Stage ledger: the delivery ring (submit/merge/evict/
             # deliver) is how updates ARRIVE — ``deliver``.
             with stage_scope("deliver"):
                 (delivered_grads, delivered, staleness, astate,
                  stats) = async_step(
-                    grads, t, self._async_key, spec, astate, self.m_mal,
-                    faults=self.faults,
-                    fkey=self._fault_key if self.faults is not None
-                    else None,
-                    latency=self._traffic_latency)
-            ctx = ctx_for(state, t, staleness)
+                    grads, t, data.async_key, spec, astate, self.m_mal,
+                    faults=self.faults, fkey=data.fault_key,
+                    latency=(None if data.latency is None
+                             else (data.latency, self._latency_tail)))
+            ctx = ctx_for(state, t, staleness, data)
             tele = dict(stats)
             if cfg.telemetry:
                 with stage_scope("deliver"):
@@ -2117,7 +2191,7 @@ class FederatedExperiment:
                     bucket * w_eff[None, :], axis=1).astype(jnp.float32)
             if cfg.telemetry or cfg.margins or kernel_num:
                 upd, ddiag = self._aggregate_impl(
-                    state, agg_grads, t, telemetry=True,
+                    data, state, agg_grads, t, telemetry=True,
                     margins=cfg.margins or kernel_num,
                     numerics=kernel_num, mask=delivered,
                     weights=weights)
@@ -2137,7 +2211,7 @@ class FederatedExperiment:
                     if cfg.telemetry:
                         tele.update(population_telemetry(agg_grads))
             else:
-                upd = self._aggregate_impl(state, agg_grads, t,
+                upd = self._aggregate_impl(data, state, agg_grads, t,
                                            mask=delivered,
                                            weights=weights)
             with stage_scope("apply"):
@@ -2173,19 +2247,19 @@ class FederatedExperiment:
                     }
             return new_state, diag, bad, tele, astate
 
-        def fused(state, t, astate, batches=None):
+        def fused(data, state, t, astate, batches=None):
             # `batches` mirrors the flat faulted signature (run_round
             # always passes it); async is device-resident-only, so it
             # is always None (validated at init).
-            return async_core(state, t, astate)
+            return async_core(data, state, t, astate)
 
-        def async_span(state, t0, count, astate):
+        def async_span(data, state, t0, count, astate):
             # Always a scan (static count): the stacked per-round
             # pytree carries the async_* counts with or without
             # telemetry — 'async' events are per-round, like 'fault'.
             def body(carry, i):
                 s, bad, a = carry
-                s2, _, b, tele, a = async_core(s, t0 + i, a)
+                s2, _, b, tele, a = async_core(data, s, t0 + i, a)
                 if self._check_attack_nan:
                     bad = bad | b
                 return (s2, bad, a), tele
@@ -2199,7 +2273,7 @@ class FederatedExperiment:
         # rides the carry and the stacked-scan outputs add aliasing
         # surface beyond what _donate_kw's CPU rationale distrusts.
         self._fused_round = jax.jit(fused)
-        self._async_span = jax.jit(async_span, static_argnums=2)
+        self._async_span = jax.jit(async_span, static_argnums=3)
         self._staged = False
 
     # ------------------------------------------------------------------
@@ -2302,11 +2376,12 @@ class FederatedExperiment:
                 # under their own ledger names (the buffer state rides
                 # the signatures).
                 entries.append(("async_round", lambda: self._fused_round
-                                .lower(self.state, t0,
+                                .lower(self.data, self.state, t0,
                                        self._async_state, batches)))
                 entries.append(
                     ("async_span", lambda: self._async_span.lower(
-                        self.state, t0, span_len, self._async_state)))
+                        self.data, self.state, t0, span_len,
+                        self._async_state)))
             elif self.traffic is not None:
                 # Traffic engines expose their two jitted entry points
                 # under their own ledger names; the schedule operands
@@ -2318,8 +2393,8 @@ class FederatedExperiment:
                 act_sds = jax.ShapeDtypeStruct((), jnp.int32)
                 entries.append(("traffic_round", lambda:
                                 self._fused_round.lower(
-                                    self.state, t0, sid_sds, arr_sds,
-                                    act_sds, self._fault_state)))
+                                    self.data, self.state, t0, sid_sds,
+                                    arr_sds, act_sds, self._fault_state)))
                 sids_sds = jax.ShapeDtypeStruct((span_len, self.m),
                                                 jnp.int32)
                 arrs_sds = jax.ShapeDtypeStruct((span_len, self.m),
@@ -2327,18 +2402,18 @@ class FederatedExperiment:
                 acts_sds = jax.ShapeDtypeStruct((span_len,), jnp.int32)
                 entries.append(("traffic_span", lambda:
                                 self._traffic_span.lower(
-                                    self.state, t0, span_len, sids_sds,
-                                    arrs_sds, acts_sds,
+                                    self.data, self.state, t0, span_len,
+                                    sids_sds, arrs_sds, acts_sds,
                                     self._fault_state)))
             elif self.faults is None:
                 entries.append((round_name, lambda: self._fused_round
-                                .lower(self.state, t0, batches)))
+                                .lower(self.data, self.state, t0, batches)))
                 if not self._streaming:
                     # Span length is a traced operand: one compilation
                     # covers every span, so one analysis does too.
                     entries.append(
                         (span_name, lambda: self._fused_span.lower(
-                            self.state, t0,
+                            self.data, self.state, t0,
                             jnp.asarray(span_len, jnp.int32))))
                     if cfg.telemetry or cfg.margins or cfg.numerics:
                         # Hierarchical engines ledger their telemetry
@@ -2349,29 +2424,30 @@ class FederatedExperiment:
                         entries.append(
                             ("hier_tele_span" if hier else "tele_span",
                              lambda: self._tele_span.lower(
-                                 self.state, t0, span_len)))
+                                 self.data, self.state, t0, span_len)))
             else:
                 entries.append(("fused_round", lambda: self._fused_round
-                                .lower(self.state, t0, self._fault_state,
-                                       batches)))
+                                .lower(self.data, self.state, t0,
+                                       self._fault_state, batches)))
                 entries.append(
                     ("fault_span", lambda: self._fault_span.lower(
-                        self.state, t0, span_len, self._fault_state)))
+                        self.data, self.state, t0, span_len,
+                        self._fault_state)))
         else:
             entries.append(("compute_grads", lambda: self._compute_grads
-                            .lower(self.state, t0, batches)))
+                            .lower(self.data, self.state, t0, batches)))
             grads_sds = jax.ShapeDtypeStruct((self.m, d), self._grad_dtype)
             if hasattr(self._aggregate, "lower"):
                 # The staged CPU Krum/Bulyan aggregation runs EAGERLY
                 # (host BLAS) — nothing compiled to analyze there.
                 entries.append(("aggregate", lambda: self._aggregate.lower(
-                    self.state, grads_sds, t0)))
+                    self.data, self.state, grads_sds, t0)))
             if ((cfg.telemetry or cfg.margins
                     or getattr(self, "_kernel_numerics", False))
                     and hasattr(self._aggregate_tele, "lower")):
                 entries.append(
                     ("aggregate_tele", lambda: self._aggregate_tele.lower(
-                        self.state, grads_sds, t0)))
+                        self.data, self.state, grads_sds, t0)))
 
         # The wired defense kernel in isolation: the per-cell
         # defense-cost row of the attack x defense grid (ALIE vs Bulyan
@@ -2439,7 +2515,7 @@ class FederatedExperiment:
         round is noise on CPU; correctness isn't."""
         if jax.default_backend() == "cpu":
             return {}
-        return {"donate_argnums": 0}
+        return {"donate_argnums": 1}    # argument 0 is the RoundData
 
     @staticmethod
     def _host_copy(tree):
@@ -2579,11 +2655,11 @@ class FederatedExperiment:
             t0 = jnp.asarray(0, jnp.int32)
             if self._async is not None:
                 low = self._async_span.lower(
-                    self.state, t0, int(count), self._async_state)
+                    self.data, self.state, t0, int(count), self._async_state)
             elif self.traffic is not None and name == "traffic_span":
                 c = int(count)
                 low = self._traffic_span.lower(
-                    self.state, t0, c,
+                    self.data, self.state, t0, c,
                     jax.ShapeDtypeStruct((c, self.m), jnp.int32),
                     jax.ShapeDtypeStruct((c, self.m), jnp.bool_),
                     jax.ShapeDtypeStruct((c,), jnp.int32),
@@ -2591,19 +2667,22 @@ class FederatedExperiment:
             elif self.faults is not None:
                 if self._placement is not None:
                     low = self._fault_span.lower(
-                        self.state, t0, int(count), self._fault_state,
+                        self.data, self.state, t0, int(count),
+                        self._fault_state,
                         jax.ShapeDtypeStruct((int(count),), jnp.int32))
                 else:
                     low = self._fault_span.lower(
-                        self.state, t0, int(count), self._fault_state)
+                        self.data, self.state, t0, int(count),
+                        self._fault_state)
             elif (self.cfg.telemetry or self.cfg.margins
                     or self.cfg.numerics or self._secagg is not None):
-                low = self._tele_span.lower(self.state, t0, int(count))
+                low = self._tele_span.lower(self.data, self.state, t0,
+                                            int(count))
             else:
                 # Span length is a traced operand: one compilation
                 # covers every span length, so one text does too.
                 low = self._fused_span.lower(
-                    self.state, t0, jnp.asarray(count, jnp.int32))
+                    self.data, self.state, t0, jnp.asarray(count, jnp.int32))
             cache[key] = low.compile().as_text()
         return cache[key]
 
@@ -2709,7 +2788,7 @@ class FederatedExperiment:
                     # per-round, telemetry on or off) and the buffer state
                     # rides the carry.
                     (self.state, bad, self._async_state, stacked) = (
-                        self._async_span(self.state,
+                        self._async_span(self.data, self.state,
                                          jnp.asarray(start, jnp.int32),
                                          int(count), self._async_state))
                     self.last_span_telemetry = (int(start), stacked)
@@ -2725,7 +2804,8 @@ class FederatedExperiment:
                         {e["round"]: e for e in sched.events})
                     (self.state, bad, self._fault_state, stacked) = (
                         self._traffic_span(
-                            self.state, jnp.asarray(start, jnp.int32),
+                            self.data, self.state,
+                            jnp.asarray(start, jnp.int32),
                             int(count), jnp.asarray(sched.shard_ids),
                             jnp.asarray(sched.arrived),
                             jnp.asarray(sched.action), self._fault_state))
@@ -2743,13 +2823,13 @@ class FederatedExperiment:
                     if self._placement is not None:
                         acts = self._fault_plan(int(start), int(count))
                         self.state, bad, self._fault_state, stacked = (
-                            self._fault_span(self.state,
+                            self._fault_span(self.data, self.state,
                                              jnp.asarray(start, jnp.int32),
                                              int(count), self._fault_state,
                                              jnp.asarray(acts)))
                     else:
                         self.state, bad, self._fault_state, stacked = (
-                            self._fault_span(self.state,
+                            self._fault_span(self.data, self.state,
                                              jnp.asarray(start, jnp.int32),
                                              int(count), self._fault_state))
                     self.last_span_telemetry = (int(start), stacked)
@@ -2761,11 +2841,12 @@ class FederatedExperiment:
                     # back stacked even with cfg.telemetry off, exactly
                     # like the fault counts do under faults.
                     self.state, bad, stacked = self._tele_span(
-                        self.state, jnp.asarray(start, jnp.int32), int(count))
+                        self.data, self.state,
+                        jnp.asarray(start, jnp.int32), int(count))
                     self.last_span_telemetry = (int(start), stacked)
                 else:
                     self.state, bad = self._fused_span(
-                        self.state, jnp.asarray(start, jnp.int32),
+                        self.data, self.state, jnp.asarray(start, jnp.int32),
                         jnp.asarray(count, jnp.int32))
             if self._check_attack_nan:
                 # the one host sync of a span whose attack can
@@ -2795,14 +2876,14 @@ class FederatedExperiment:
             if self._async is not None:
                 (self.state, diag, bad, tele,
                  self._async_state) = self._fused_round(
-                    self.state, t, self._async_state, batches)
+                    self.data, self.state, t, self._async_state, batches)
             elif self._traffic_span is not None:
                 sched = self._traffic_plan(t_host, 1)
                 self._traffic_events.update(
                     {e["round"]: e for e in sched.events})
                 (self.state, diag, bad, tele,
                  self._fault_state) = self._fused_round(
-                    self.state, t, jnp.asarray(sched.shard_ids[0]),
+                    self.data, self.state, t, jnp.asarray(sched.shard_ids[0]),
                     jnp.asarray(sched.arrived[0]),
                     jnp.asarray(sched.action[0]), self._fault_state)
             elif self.faults is not None:
@@ -2810,30 +2891,30 @@ class FederatedExperiment:
                     act = self._fault_plan(t_host, 1)[0]
                     (self.state, diag, bad, tele,
                      self._fault_state) = self._fused_round(
-                        self.state, t, jnp.asarray(act, jnp.int32),
+                        self.data, self.state, t, jnp.asarray(act, jnp.int32),
                         self._fault_state, batches)
                 else:
                     (self.state, diag, bad, tele,
                      self._fault_state) = self._fused_round(
-                        self.state, t, self._fault_state, batches)
+                        self.data, self.state, t, self._fault_state, batches)
             else:
                 self.state, diag, bad, tele = self._fused_round(
-                    self.state, t, batches)
+                    self.data, self.state, t, batches)
             if diag:
                 self.last_round_stats = diag
             if tele:
                 self.last_round_telemetry = tele
             self._raise_if_attack_nan(bad)
         else:
-            grads = self._compute_grads(self.state, t, batches)
-            tele = (self._attack_envelope(grads, self.state, t)
+            grads = self._compute_grads(self.data, self.state, t, batches)
+            tele = (self._attack_envelope(self.data, grads, self.state, t)
                     if self.cfg.telemetry else {})
             pre_attack = grads if self.cfg.margins else None
             grads = self.attacker.apply(grads, self.m_mal,
                                         self._ctx_for(self.state, t))
             if self.cfg.margins:
                 tele = {**tele, **self._attack_margins(
-                    pre_attack, grads, self.state, t)}
+                    self.data, pre_attack, grads, self.state, t)}
             if self.cfg.numerics:
                 # Staged twin of the fused engine counters (eager —
                 # the staged path crosses the host every round anyway).
@@ -2843,7 +2924,7 @@ class FederatedExperiment:
             mask = None
             if self.faults is not None:
                 grads, mask, self._fault_state, fstats = self._fault_step(
-                    grads, t, self._fault_state)
+                    self.data, grads, t, self._fault_state)
                 tele = {**tele, **fstats}
             if self.cfg.numerics:
                 tele = {**tele, "num_nonfinite_post":
@@ -2854,9 +2935,8 @@ class FederatedExperiment:
                 # The defense returns its own diagnostics (single
                 # distance computation; the Krum mask marks the
                 # aggregated row by construction).
-                self.state, ddiag = self._aggregate_tele(self.state,
-                                                         grads, t,
-                                                         mask=mask)
+                self.state, ddiag = self._aggregate_tele(
+                    self.data, self.state, grads, t, mask=mask)
                 tele = self._finish_telemetry(tele, grads, ddiag)
                 if (self._krum_select_fn is not None
                         and "selection_mask" in ddiag):
@@ -2874,8 +2954,8 @@ class FederatedExperiment:
                     sel = self._krum_select_fn(grads, self.m, self.m_mal)
                     aux["krum_selected"] = sel
                     agg = grads[sel]
-                self.state = self._aggregate(self.state, grads, t, agg,
-                                             mask=mask)
+                self.state = self._aggregate(self.data, self.state, grads,
+                                             t, agg, mask=mask)
                 if tele:
                     self.last_round_telemetry = tele
             if self.cfg.numerics:

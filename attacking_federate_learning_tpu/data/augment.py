@@ -45,7 +45,14 @@ def reflect_crop_flip(images, key, pad: int = 4):
     return out.reshape(images.shape)
 
 
+def augment_key(seed: int):
+    """The augmentation's own key stream, derived from the experiment
+    seed; the engine passes it to the round program as an operand
+    (core/engine.py RoundData) and folds the round index in there."""
+    return jax.random.key(seed ^ 0x5EED_A06)
+
+
 def round_augment_key(seed: int, t):
     """Per-round augmentation key: fold the round index into the
     experiment's seed stream (works with a traced ``t`` inside jit)."""
-    return jax.random.fold_in(jax.random.key(seed ^ 0x5EED_A06), t)
+    return jax.random.fold_in(augment_key(seed), t)

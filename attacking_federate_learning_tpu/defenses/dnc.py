@@ -51,11 +51,19 @@ def _top_direction(Sc, key):
     return v
 
 
+def sketch_key(seed: int):
+    """The sketch key stream of an experiment seed."""
+    return jax.random.key(seed ^ 0xD0C)
+
+
 @DEFENSES.register("DnC")
 def dnc(users_grads, users_count, corrupted_count, n_iters: int = _N_ITERS,
         filter_frac: float = _FILTER_FRAC, sketch_dim: int = _SKETCH_DIM,
-        seed: int = 0, round=0, telemetry=False):
-    """``telemetry=True`` additionally returns ``{'survivor_mask': (n,)
+        seed: int = 0, round=0, telemetry=False, key=None):
+    """``key``: the sketch stream ``sketch_key(seed)`` as an array — the
+    engine's round programs pass it as an operand so the compiled text
+    does not carry the seed; None derives it from ``seed`` here.
+    ``telemetry=True`` additionally returns ``{'survivor_mask': (n,)
     f32 0/1 — clients no iteration marked as outliers, 'survivor_count':
     () int32}``."""
     G = users_grads.astype(jnp.float32)
@@ -76,8 +84,9 @@ def dnc(users_grads, users_count, corrupted_count, n_iters: int = _N_ITERS,
         # power iteration converges to the same dominant direction from
         # any (random) init — one iteration suffices.
         n_iters = 1
-    base_key = jax.random.fold_in(jax.random.key(seed ^ 0xD0C),
-                                  jnp.asarray(round, jnp.int32))
+    base_key = jax.random.fold_in(
+        sketch_key(seed) if key is None else key,
+        jnp.asarray(round, jnp.int32))
 
     good = jnp.ones((n,), bool)
     for i in range(n_iters):

@@ -306,7 +306,7 @@ def test_flat_hlo_byte_identical_under_async_knobs(tmp_path):
         cfg = _cfg(tmp_path, aggregation="flat", async_buffer=0, **kw)
         exp = _engine(cfg)
         return exp._fused_round.lower(
-            exp.state, jnp.asarray(0, jnp.int32)).as_text()
+            exp.data, exp.state, jnp.asarray(0, jnp.int32)).as_text()
 
     base = lowered()
     knobbed = lowered(async_max_staleness=7, staleness_weight="poly")

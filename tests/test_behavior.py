@@ -101,12 +101,12 @@ def test_backdoor_shadow_training_embeds_trigger():
         rng.standard_normal((2, flat.dim)).astype(np.float32) * 0.01)
     mean = mal_grads.mean(0)
     lr = jnp.asarray(0.1)
-    crafted = atk._craft(mal_grads, w, lr)
+    crafted = atk._craft(mal_grads, w, lr, atk.operands())
     # Invert the gradient re-expression (backdoor.py:59-60) to recover the
     # shadow-trained parameters; unclipped because z is huge.
     start = w - lr * mean
     mal_params = start - lr * crafted - lr * mean
-    _, correct = atk._poison_metrics(mal_params)
+    _, correct = atk._poison_metrics(mal_params, atk.operands())
     assert float(correct) == atk.poison_count  # 100% trigger accuracy
 
 
@@ -133,7 +133,8 @@ def test_backdoor_crafted_grads_respect_clip_envelope():
     rng = np.random.default_rng(1)
     mal_grads = jnp.asarray(
         rng.standard_normal((3, flat.dim)).astype(np.float32) * 0.01)
-    crafted = np.asarray(atk._craft(mal_grads, w, jnp.asarray(0.1)))
+    crafted = np.asarray(atk._craft(mal_grads, w, jnp.asarray(0.1),
+                                    atk.operands()))
     mean = np.asarray(mal_grads.mean(0))
     sigma = np.asarray(mal_grads.std(0))
     assert (crafted <= mean + 1.5 * sigma + 1e-6).all()

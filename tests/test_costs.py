@@ -167,7 +167,7 @@ def test_cost_report_leaves_round_hlo_byte_identical(tmp_path):
         if run_report:
             exp.cost_report()
         return exp._fused_round.lower(
-            exp.state, jnp.asarray(0, jnp.int32)).as_text()
+            exp.data, exp.state, jnp.asarray(0, jnp.int32)).as_text()
 
     assert lowered_text(False) == lowered_text(True)
 
@@ -374,11 +374,11 @@ def _round_compiled(exp):
     t0 = jnp.asarray(0, jnp.int32)
     if exp._async is not None:
         return exp._fused_round.lower(
-            exp.state, t0, exp._async_state, None).compile()
+            exp.data, exp.state, t0, exp._async_state, None).compile()
     if exp.faults is not None:
         return exp._fused_round.lower(
-            exp.state, t0, exp._fault_state, None).compile()
-    return exp._fused_round.lower(exp.state, t0).compile()
+            exp.data, exp.state, t0, exp._fault_state, None).compile()
+    return exp._fused_round.lower(exp.data, exp.state, t0).compile()
 
 
 # Topology overrides per defense family.  Bulyan's 4f+3 validity bound

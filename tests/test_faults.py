@@ -197,9 +197,9 @@ def test_no_fault_round_hlo_bit_identical(tmp_path):
         cfg = _cfg(tmp_path, epochs=2, defense="Krum", faults=faults)
         exp = FederatedExperiment(cfg, attacker=DriftAttack(1.0),
                                   dataset=ds)
-        args = ((exp.state, jnp.asarray(0, jnp.int32))
+        args = ((exp.data, exp.state, jnp.asarray(0, jnp.int32))
                 if exp.faults is None
-                else (exp.state, jnp.asarray(0, jnp.int32),
+                else (exp.data, exp.state, jnp.asarray(0, jnp.int32),
                       exp._fault_state))
         return exp._fused_round.lower(*args).as_text()
 

@@ -63,8 +63,8 @@ def _spy(exp):
     def keep(kind):
         return lambda *a: seen[kind].append([np.asarray(v) for v in a])
 
-    def spy_gather(t, participants=None):
-        xs, ys = gather(t, participants)
+    def spy_gather(data, t, participants=None):
+        xs, ys = gather(data, t, participants)
         ids = (jnp.arange(exp.n) if participants is None else participants)
         jax.debug.callback(keep("gather"), t, ids, xs, ys)
         return xs, ys
@@ -94,10 +94,10 @@ def _assert_styled(exp, got, rows, ids):
     'femnist_style' row i through client ids[i]'s a*x + b (to an ulp:
     the compiled program may fuse the multiply-add; a row under another
     client's style is off by far more)."""
-    if exp._style is None:
+    if exp.data.style is None:
         np.testing.assert_array_equal(got, rows)
     else:
-        want = np.asarray(exp._apply_style(jnp.asarray(rows),
+        want = np.asarray(exp._apply_style(exp.data, jnp.asarray(rows),
                                            jnp.asarray(ids)))
         np.testing.assert_allclose(got, want, rtol=0, atol=1e-6)
 
@@ -160,7 +160,7 @@ def test_partial_participation_gathers_the_cohorts_rows(shape, partition):
     assert len(seen["gather"]) == 3
     for (t, ids, xs, ys), (cx, _) in zip(seen["gather"], seen["client"]):
         np.testing.assert_array_equal(
-            ids, np.asarray(exp._participants(jnp.asarray(t))))
+            ids, np.asarray(exp._participants(exp.data, jnp.asarray(t))))
         assert len(ids) == exp.m < n
         ref_x, ref_y = _reference(exp, ds, t, ids)
         np.testing.assert_array_equal(xs, ref_x)
@@ -175,7 +175,7 @@ def test_image_samples_get_their_shape_back_after_the_gather():
                           batch_size=4, model="cifar10_cnn",
                           data_augment=True)
     assert ds.train_x.shape[1:] == (3, 32, 32)
-    xs, ys = exp._gather_batches(jnp.asarray(2, jnp.int32))
+    xs, ys = exp._gather_batches(exp.data, jnp.asarray(2, jnp.int32))
     ref_x, ref_y = _reference(exp, ds, 2, np.arange(4))
     assert xs.shape == (4, 4, 3, 32, 32)
     np.testing.assert_array_equal(np.asarray(xs), ref_x)
@@ -189,9 +189,9 @@ def _parent_gather(exp, ds):
     gathered in its sample shape."""
     x4d, y = jnp.asarray(ds.train_x), jnp.asarray(ds.train_y)
 
-    def gather(t, participants=None):
-        shards = (exp.shards if participants is None
-                  else exp.shards[participants])
+    def gather(data, t, participants=None):
+        shards = (data.shards if participants is None
+                  else data.shards[participants])
         idx = round_batch_indices(
             shards, t, exp.cfg.batch_size * exp.cfg.local_steps)
         return x4d[idx], y[idx]
@@ -221,16 +221,18 @@ def test_three_rounds_equal_the_parent_formulation(kw):
 @pytest.mark.parametrize("partition", ["iid", "dirichlet", "femnist_style"])
 def test_one_f32_row_store_on_the_device(partition):
     """Every partition gets the same storage — f32 rows, (N, F), feature
-    axis minor — and it is the only copy of the set on the device."""
+    axis minor — and it is the only copy of the set on the device: one
+    leaf of the round programs' operand pytree, no attribute beside it."""
     exp, ds = _experiment(96, partition=partition)
     n_train, feat = len(ds.train_x), int(np.prod(ds.train_x.shape[1:]))
-    assert exp.train_x.shape == (n_train, feat)
-    assert exp.train_x.dtype == jnp.float32 == ds.train_x.dtype
-    np.testing.assert_array_equal(np.asarray(exp.train_x),
+    assert exp.data.train_x.shape == (n_train, feat)
+    assert exp.data.train_x.dtype == jnp.float32 == ds.train_x.dtype
+    np.testing.assert_array_equal(np.asarray(exp.data.train_x),
                                   ds.train_x.reshape(n_train, feat))
     held = [k for k, v in vars(exp).items()
-            if isinstance(v, jax.Array) and v.size == n_train * feat]
-    assert held == ["train_x"]
+            for leaf in jax.tree.leaves(v)
+            if isinstance(leaf, jax.Array) and leaf.size == n_train * feat]
+    assert held == ["data"]
 
 
 # --- what the chip's compiler makes of it (no chip needed) -----------------
@@ -264,57 +266,108 @@ def _no_persistent_cache():
 
 
 def _gather_result_layouts(hlo_text):
-    """(shape, minor_to_major) of every fusion / instruction of the entry
-    computation that gathers floating-point rows."""
+    """(shape, minor_to_major) of every fusion / instruction, in any
+    computation (the span's gather sits in the round loop's body), that
+    gathers floating-point rows."""
     gathering = set(re.findall(
         r"^%?([\w.\-]+) \([^\n]*\{\n(?:(?!^\}).*\n)*?"
         r"[^\n]*= (?:f32|bf16)\[[\d,]*\]\S* gather\(", hlo_text, re.M))
-    entry = hlo_text[hlo_text.index("\nENTRY "):]
     out = []
     for m in re.finditer(
             r"= (?:f32|bf16)\[([\d,]*)\]\{([\d,]*)[^ ]* "
-            r"(?:fusion\([^\n]*calls=%([\w.\-]+)|gather\()", entry):
-        if m.group(3) is None or m.group(3) in gathering:
+            r"(?:fusion\([^\n]*calls=%([\w.\-]+)|gather\()", hlo_text):
+        if m.group(3) in gathering:
             out.append(([int(v) for v in m.group(1).split(",")],
                         [int(v) for v in m.group(2).split(",")]))
     return out
 
 
-def test_v5e_compiles_the_gather_to_whole_rows(one_chip):
-    """The v5e compiler, given the round's gather + client step at the
-    MLP cell's widths (n cut to 1,024), writes the gathered batch with
-    the FEATURE axis minor.  Gathered in sample shape it wrote
-    f32[n*B,28,28]{0,2,1} — the sample axis minor, one element at a
-    time (ledger, PR 25: fusion.71, 2.1 s of a 13.7 s window)."""
-    n, B, n_train = 1024, 32, 60000
-    exp, _ = _experiment(2 * n, users_count=n, batch_size=B, mal_prop=0.0,
-                         defense="NoDefense")
-    feat = exp.train_x.shape[1]
-    shard_len = -(-n_train // n)
-
-    def deliver(w, x, y, shards, t):
-        exp.train_x, exp.train_y, exp.shards = x, y, shards
-        return exp._compute_grads_impl(exp.state._replace(weights=w), t)
+def _described(exp, one_chip, n_train):
+    """``exp``'s round operands and state as shapes on the described
+    chip, the set and the shards at the MLP cell's sizes.  Every array is
+    given the layout a device buffer has at run time — row-major, which
+    is what ``jnp.asarray`` places — because for a described chip the
+    compiler would otherwise choose the parameters' layouts itself (it
+    takes the set column-major and pays a transposing copy a span)."""
+    from jax.experimental.layout import Format, Layout
 
     def arg(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=Format(
+            Layout(tuple(range(len(shape)))), one_chip))
 
-    placed = exp.train_x, exp.train_y, exp.shards
-    try:
-        with _no_persistent_cache():
-            text = jax.jit(deliver).lower(
-                arg((exp.flat.dim,), jnp.float32),
-                arg((n_train, feat), jnp.float32),
-                arg((n_train,), jnp.int32),
-                arg((n, shard_len), jnp.int32), arg((), jnp.int32),
-            ).compile().as_text()
-    finally:
-        exp.train_x, exp.train_y, exp.shards = placed
+    n, feat = exp.n, exp.data.train_x.shape[1]
+    data = exp.data._replace(
+        train_x=arg((n_train, feat), jnp.float32),
+        train_y=arg((n_train,), jnp.int32),
+        shards=arg((n, -(-n_train // n)), jnp.int32))
+    state = jax.tree.map(lambda a: arg(a.shape, a.dtype), exp.state)
+    return arg, data, state
+
+
+def _assert_whole_rows(text, feat):
     layouts = _gather_result_layouts(text)
     assert layouts, "no floating-point gather in the compiled program"
     for shape, minor_to_major in layouts:
         assert shape[-1] == feat, (shape, minor_to_major)
         assert minor_to_major[0] == len(shape) - 1, (shape, minor_to_major)
+
+
+def test_v5e_compiles_the_gather_to_whole_rows(one_chip):
+    """The v5e compiler, given the round's gather + client step at the
+    MLP cell's widths (n cut to 1,024) with the set as an ARGUMENT in the
+    runtime's row-major layout, writes the gathered batch with the
+    FEATURE axis minor.  Gathered in sample shape it wrote
+    f32[n*B,28,28]{0,2,1} — the sample axis minor, one element at a
+    time (ledger, PR 25: fusion.71, 2.1 s of a 13.7 s window)."""
+    n, B, n_train = 1024, 32, 60000
+    exp, _ = _experiment(2 * n, users_count=n, batch_size=B, mal_prop=0.0,
+                         defense="NoDefense")
+    arg, data, state = _described(exp, one_chip, n_train)
+
+    def deliver(data, state, t):
+        return exp._compute_grads_impl(state, t, data=data)
+
+    with _no_persistent_cache():
+        text = jax.jit(deliver).lower(
+            data, state, arg((), jnp.int32)).compile().as_text()
+    _assert_whole_rows(text, data.train_x.shape[1])
+
+
+def test_v5e_compiles_the_span_with_the_set_as_an_operand(one_chip):
+    """The whole span (Krum + ALIE, the MLP cell's widths, n cut to
+    1,024) as ``run_span`` dispatches it, the set, the shards and the
+    state its entry parameters (PERF.md section 6, PR 34).  The gather in
+    the round loop's body still moves whole rows, and the text carries
+    no constant of a megabyte.  What the compiler writes of the set's
+    shape is ONE ``convert`` to bf16 in the entry computation, before the
+    loop: the first layer's default-precision rounding, moved through
+    the gather (so the rows gathered are bf16, as they were) and out of
+    the loop — the closure form folded that same convert into its 188 MB
+    constant at compile time.  No layout copy, no transpose, nothing
+    set-shaped computed inside the loop (the compiler may move the bf16
+    set between memory spaces there: ``copy-start`` / ``copy-done``)."""
+    n, B, n_train = 1024, 32, 60000
+    exp, _ = _experiment(2 * n, users_count=n, batch_size=B, mal_prop=0.24,
+                         defense="Krum", num_std=1.5)
+    arg, data, state = _described(exp, one_chip, n_train)
+    feat = data.train_x.shape[1]
+    with _no_persistent_cache():
+        text = exp._fused_span.lower(
+            data, state, arg((), jnp.int32),
+            arg((), jnp.int32)).compile().as_text()
+    _assert_whole_rows(text, feat)
+    assert " while(" in text
+    entry_at = text.index("\nENTRY ")
+    written = [(m.start() > entry_at, m.group(1)) for m in re.finditer(
+        r"= (?:f32|bf16)\[%d,%d\]\S* ([\w\-]+)\(" % (n_train, feat), text)
+        if m.group(1) not in ("parameter", "get-tuple-element")]
+    moves = {"copy-start", "copy-done"}
+    assert [w for w in written if w[1] not in moves] == [(True, "convert")], \
+        written
+    big = [m.group(0)[:160] for m in re.finditer(
+               r"= \w+\[([\d,]+)\][^\n]* constant\(", text)
+           if np.prod([int(v) for v in m.group(1).split(",")]) * 2 > 2 ** 20]
+    assert not big, big
 
 
 def test_v5e_compiles_the_gram_panels_without_copying_the_wire_matrix(

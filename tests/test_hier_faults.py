@@ -230,9 +230,9 @@ def test_no_fault_hier_round_hlo_bit_identical(tmp_path):
         exp = FederatedExperiment(cfg, attacker=DriftAttack(1.0),
                                   dataset=_dataset())
         if exp.faults is None:
-            args = (exp.state, jnp.asarray(0, jnp.int32))
+            args = (exp.data, exp.state, jnp.asarray(0, jnp.int32))
         else:
-            args = (exp.state, jnp.asarray(0, jnp.int32),
+            args = (exp.data, exp.state, jnp.asarray(0, jnp.int32),
                     jnp.asarray(0, jnp.int32), exp._fault_state)
         return exp._fused_round.lower(*args).as_text()
 

@@ -210,7 +210,7 @@ def test_flat_hlo_byte_identical_whatever_the_hier_knobs(tmp_path):
         exp = FederatedExperiment(cfg, attacker=DriftAttack(1.0),
                                   dataset=ds)
         return exp._fused_round.lower(
-            exp.state, jnp.asarray(0, jnp.int32)).as_text()
+            exp.data, exp.state, jnp.asarray(0, jnp.int32)).as_text()
 
     base = lowered()
     knobbed = lowered(megabatch=4, tier2_defense="Median",
@@ -499,9 +499,9 @@ def test_hier_telemetry_on_off_bit_identical_and_hlo_clean(tmp_path):
     # norm tensors are f32[3,4]; the off program must not contain one
     # (compiled-HLO text, the wire_hlo_facts convention).
     text_off = off._fused_round.lower(
-        off.state, jnp.asarray(0, jnp.int32)).compile().as_text()
+        off.data, off.state, jnp.asarray(0, jnp.int32)).compile().as_text()
     text_on = on._fused_round.lower(
-        on.state, jnp.asarray(0, jnp.int32)).compile().as_text()
+        on.data, on.state, jnp.asarray(0, jnp.int32)).compile().as_text()
     assert "f32[3,4]" not in text_off
     assert "f32[3,4]" in text_on          # non-vacuous
     # Stacked telemetry shapes: (rounds, S, m) tier-1, (rounds, S)

@@ -136,7 +136,7 @@ def test_local_steps_reduction_is_exact_under_server_lr():
     )
     loss = make_loss_fn(exp.model, exp.flat)
     grad = jax.grad(loss)
-    xs, ys = exp._gather_batches(jnp.asarray(0, jnp.int32))
+    xs, ys = exp._gather_batches(exp.data, jnp.asarray(0, jnp.int32))
     xs = np.asarray(xs).reshape(1, 3, 8, *np.asarray(xs).shape[2:])
     ys = np.asarray(ys).reshape(1, 3, 8)
     lr = float(faded_learning_rate(cfg.learning_rate, cfg.fading_rate, 0))

@@ -276,7 +276,7 @@ def test_spmd_hlo_truly_sharded_and_collective_pinned(tmp_path):
                               dataset=_dataset(),
                               shardings=make_plan((8, 1)))
     compiled = exp._fused_round.lower(
-        exp.state, jnp.asarray(0, jnp.int32), None).compile()
+        exp.data, exp.state, jnp.asarray(0, jnp.int32), None).compile()
     text = compiled.as_text()
     d, S = exp.flat.dim, 16
     for shape in (f"f32[64,{d}]", f"bf16[64,{d}]", f"f32[16,4,{d}]",
@@ -302,7 +302,7 @@ def test_one_device_clients_axis_keeps_scan_path(tmp_path):
             _cfg(tmp_path), attacker=DriftAttack(1.5),
             dataset=_dataset(), shardings=shardings)
         return exp, compiled_cost_facts(exp._fused_round.lower(
-            exp.state, jnp.asarray(0, jnp.int32), None).compile())
+            exp.data, exp.state, jnp.asarray(0, jnp.int32), None).compile())
 
     plan1 = make_plan((1, 1), devices=jax.devices()[:1])
     exp1, f1 = facts(plan1)

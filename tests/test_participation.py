@@ -33,15 +33,16 @@ def test_cohort_sizes_static_and_scaled():
 
 def test_participants_structure_and_variation():
     exp = _exp()
-    p0 = np.asarray(exp._participants(0))
-    p1 = np.asarray(exp._participants(1))
+    p0 = np.asarray(exp._participants(exp.data, 0))
+    p1 = np.asarray(exp._participants(exp.data, 1))
     assert len(p0) == exp.m
     assert len(set(p0.tolist())) == exp.m          # no duplicates
     assert np.all(p0[: exp.m_mal] < exp.f)         # malicious first
     assert np.all(p0[exp.m_mal:] >= exp.f)         # honest rest
     assert not np.array_equal(p0, p1)              # resampled per round
     # deterministic per (seed, round)
-    np.testing.assert_array_equal(p0, np.asarray(exp._participants(0)))
+    np.testing.assert_array_equal(
+        p0, np.asarray(exp._participants(exp.data, 0)))
 
 
 def test_training_runs_and_defense_sees_cohort():

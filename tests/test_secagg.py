@@ -476,7 +476,8 @@ def test_secagg_off_hlo_has_no_protocol_trace(tmp_path):
     exp = FederatedExperiment(_cfg(tmp_path), attacker=DriftAttack(1.0),
                               dataset=ds)
     text = exp._fused_round.lower(
-        exp.state, jnp.asarray(0, jnp.int32), None).compile().as_text()
+        exp.data, exp.state, jnp.asarray(0, jnp.int32),
+        None).compile().as_text()
     facts = sa.wire_hlo_facts(text, 12, exp.flat.dim)
     assert not facts["wire_present"]
     assert facts["unmask_instructions"] == 0
@@ -493,7 +494,8 @@ def test_vanilla_wire_hlo_pin(tmp_path):
     exp = FederatedExperiment(_cfg(tmp_path, secagg="vanilla"),
                               attacker=DriftAttack(1.0), dataset=ds)
     text = exp._fused_round.lower(
-        exp.state, jnp.asarray(0, jnp.int32), None).compile().as_text()
+        exp.data, exp.state, jnp.asarray(0, jnp.int32),
+        None).compile().as_text()
     facts = sa.wire_hlo_facts(text, 12, exp.flat.dim)
     assert facts["wire_present"]
     assert facts["unmask_instructions"] >= 1

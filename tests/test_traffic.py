@@ -106,10 +106,10 @@ def test_engine_participants_route_through_population(tmp_path):
                       synth_test=cfg.synth_test)
     exp = FederatedExperiment(cfg, attacker=DriftAttack(1.0), dataset=ds)
     for t in (0, 2, 7):
-        want = P.legacy_cohort(exp._part_key, t, exp.n, exp.f, exp.m,
+        want = P.legacy_cohort(exp.data.part_key, t, exp.n, exp.f, exp.m,
                                exp.m_mal)
-        np.testing.assert_array_equal(np.asarray(exp._participants(t)),
-                                      np.asarray(want))
+        np.testing.assert_array_equal(
+            np.asarray(exp._participants(exp.data, t)), np.asarray(want))
 
 
 # ---------------------------------------------------------------------------

@@ -402,8 +402,8 @@ def test_profile_every_leaves_hlo_fingerprint_identical():
         f_on = hlo_fingerprint(on._span_hlo_text(3))
     assert f_off == f_on
     t0 = jnp.asarray(0, jnp.int32)
-    r_off = off._fused_round.lower(off.state, t0).as_text()
-    r_on = on._fused_round.lower(on.state, t0).as_text()
+    r_off = off._fused_round.lower(off.data, off.state, t0).as_text()
+    r_on = on._fused_round.lower(on.data, on.state, t0).as_text()
     assert hlo_fingerprint(r_off) == hlo_fingerprint(r_on)
 
 

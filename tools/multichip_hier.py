@@ -133,7 +133,8 @@ def main(argv=None) -> int:
     for tag, exp in (("sharded", exp_spmd), ("scan", build(None))):
         t0 = time.perf_counter()
         facts = compiled_cost_facts(
-            exp._fused_round.lower(exp.state, jnp.asarray(0, jnp.int32),
+            exp._fused_round.lower(exp.data, exp.state,
+                                   jnp.asarray(0, jnp.int32),
                                    None).compile())
         rec[tag] = {"compile_s": round(time.perf_counter() - t0, 2),
                     "temp_bytes": int(facts["temp_bytes"]),

@@ -186,8 +186,8 @@ def memproof() -> int:
     exp = FederatedExperiment(cfg, dataset=ds)
     d = exp.flat.dim
     assert d == MEMPROOF["d"], f"wire dim moved: {d}"
-    lowered = exp._fused_round.lower(exp.state, jnp.asarray(0, jnp.int32),
-                                     None)
+    lowered = exp._fused_round.lower(
+        exp.data, exp.state, jnp.asarray(0, jnp.int32), None)
     text = lowered.as_text()
     problems = []
     for shape in (f"f32[{n},{d}]", f"bf16[{n},{d}]", f"f32[{n},{n}]"):
@@ -251,8 +251,9 @@ def wireproof() -> int:
         secagg="vanilla")
     ds = load_dataset(cfg.dataset, seed=0, synth_train=256, synth_test=64)
     exp = FederatedExperiment(cfg, attacker=DriftAttack(1.5), dataset=ds)
-    text = exp._fused_round.lower(exp.state, jnp.asarray(0, jnp.int32),
-                                  None).compile().as_text()
+    text = exp._fused_round.lower(
+        exp.data, exp.state, jnp.asarray(0, jnp.int32),
+        None).compile().as_text()
     facts = wire_hlo_facts(text, n, exp.flat.dim)
     problems = []
     if not facts["wire_present"]:
@@ -382,7 +383,7 @@ def shardproof() -> int:
         aggregation="hierarchical", megabatch=m)
     d, S = exp8.flat.dim, n // m
     compiled = exp8._fused_round.lower(
-        exp8.state, jnp.asarray(0, jnp.int32), None).compile()
+        exp8.data, exp8.state, jnp.asarray(0, jnp.int32), None).compile()
     text = compiled.as_text()
     for shape in (f"f32[{n},{d}]", f"bf16[{n},{d}]",
                   f"f32[{S},{m},{d}]", f"f32[{n},{n}]"):
@@ -467,11 +468,11 @@ def _round_compiled(exp):
     t0 = jnp.asarray(0, jnp.int32)
     if exp._async is not None:
         return exp._fused_round.lower(
-            exp.state, t0, exp._async_state, None).compile()
+            exp.data, exp.state, t0, exp._async_state, None).compile()
     if exp.faults is not None:
         return exp._fused_round.lower(
-            exp.state, t0, exp._fault_state, None).compile()
-    return exp._fused_round.lower(exp.state, t0).compile()
+            exp.data, exp.state, t0, exp._fault_state, None).compile()
+    return exp._fused_round.lower(exp.data, exp.state, t0).compile()
 
 
 def stageproof(cells=None) -> int:
