@@ -4,6 +4,8 @@ Reproduces reference ``Cifar10Net`` (data_sets.py:33-61): conv1 3->16 k3
 (xavier weight, data_sets.py:37), MaxPool(3); conv2 16->64 k4, MaxPool(4);
 fc 64 -> 384 -> 192 -> 10.  Spatial trace on 32x32 NCHW input:
 32 -conv3-> 30 -pool3-> 10 -conv4-> 7 -pool4-> 1.
+ReLU behind the pool: same function and gradient, see
+``layers.relu_max_pool2d``.
 Parameter order conv1.{weight,bias}, conv2.{weight,bias}, fc1..fc3 —
 d = 117,706.
 """
@@ -32,8 +34,8 @@ def _init(key):
 
 def _apply(params, x):
     x = x.reshape((x.shape[0], 3, 32, 32))
-    x = L.max_pool2d(jax.nn.relu(L.conv2d(params["conv1"], x)), 3)
-    x = L.max_pool2d(jax.nn.relu(L.conv2d(params["conv2"], x)), 4)
+    x = L.relu_max_pool2d(L.conv2d(params["conv1"], x), 3)
+    x = L.relu_max_pool2d(L.conv2d(params["conv2"], x), 4)
     x = x.reshape((x.shape[0], -1))
     x = jax.nn.relu(L.linear(params["fc1"], x))
     x = jax.nn.relu(L.linear(params["fc2"], x))

@@ -100,6 +100,20 @@ def max_pool2d(x, ksize, stride=None):
     )
 
 
+def relu_max_pool2d(x, ksize):
+    # relu(max_pool(x)), which IS torch's max_pool(relu(x)) — forward and
+    # gradient, to the bit — because ReLU is monotone and keeps positives
+    # as they are: max(relu(a_i)) == relu(max(a_i)) with no rounding; a
+    # window whose maximum is > 0 has the same first arg-max before and
+    # after ReLU, so the pool routes g to the same element and ReLU's
+    # factor there is 1; a window whose maximum is <= 0 gets 0 in both
+    # orders (pool-to-first-zero then ReLU'(x <= 0) = 0, or 0 * g first).
+    # ReLU and its backward then run on the pooled tensor, k*k times
+    # smaller (PERF.md section 6, PR 35; tests/test_conv_block.py holds
+    # both orders equal as bit patterns).
+    return jax.nn.relu(max_pool2d(x, ksize))
+
+
 def avg_pool2d(x, ksize, stride=None):
     stride = stride or ksize
     summed = lax.reduce_window(

@@ -6,6 +6,8 @@ the MLP for MNIST (reference data_sets.py:13-30).  Architecture follows the
 classic torch MNIST example: conv1 1->10 k5, MaxPool(2); conv2 10->20 k5,
 MaxPool(2); fc 320 -> 50 -> 10.  Spatial trace on 28x28 NCHW input:
 28 -conv5-> 24 -pool2-> 12 -conv5-> 8 -pool2-> 4.
+ReLU behind the pool: same function and gradient, see
+``layers.relu_max_pool2d``.
 Parameter order conv1.{weight,bias}, conv2.{weight,bias}, fc1, fc2 —
 d = 21,840.
 """
@@ -33,8 +35,8 @@ def _init(key):
 
 def _apply(params, x):
     x = x.reshape((x.shape[0], 1, 28, 28))
-    x = L.max_pool2d(jax.nn.relu(L.conv2d(params["conv1"], x)), 2)
-    x = L.max_pool2d(jax.nn.relu(L.conv2d(params["conv2"], x)), 2)
+    x = L.relu_max_pool2d(L.conv2d(params["conv1"], x), 2)
+    x = L.relu_max_pool2d(L.conv2d(params["conv2"], x), 2)
     x = x.reshape((x.shape[0], -1))
     x = jax.nn.relu(L.linear(params["fc1"], x))
     return L.log_softmax(L.linear(params["fc2"], x))

@@ -425,3 +425,56 @@ def test_v5e_compiles_krum_scores_without_a_sort(one_chip):
                if re.search(r"= \w+\[%d,%d\]" % (n, n), line)
                and " fusion(" in line]
     assert not written, written
+
+
+def test_v5e_compiles_the_conv_client_step_without_relu_at_conv_size(
+        one_chip):
+    """The client step of ``cifar10_cnn`` at the CNN cell's own shape
+    (n = 256, batch 128), where conv-1's output f32[256,128,16,30,30] is
+    1.89 GB and every pass over it is ~4.6 ms of a memory-bound step
+    (at n = 8 it fits the fast memory and nothing shows).  With ReLU
+    behind the pool (``layers.relu_max_pool2d``; PERF.md section 6, PR
+    35) the v5e compiler writes four values of that size — the
+    convolution, its relayout for the pool, the bias add, the pool's
+    ``select-and-scatter`` — where the reference order, compiled beside
+    it, also writes ReLU's backward (``compare_select_fusion``, bf16)
+    and a relayout of the scattered gradient for the masked bias
+    gradient, and reads the activation a third time to pack ReLU's mask:
+    39.0 GB of bytes accessed against 29.7."""
+    from attacking_federate_learning_tpu.core.client import (
+        make_client_grad_fn
+    )
+    from attacking_federate_learning_tpu.models import get_model
+    from attacking_federate_learning_tpu.utils.flatten import make_flattener
+    from test_conv_block import old_order
+
+    n, B = 256, 128
+    model = get_model("cifar10_cnn")
+    flat = make_flattener(model.init(jax.random.key(0)))
+    operands = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+                for shape, dtype in (((flat.dim,), jnp.float32),
+                                     ((n, B) + model.input_shape, jnp.float32),
+                                     ((n, B), jnp.int32))]
+    conv1 = n * B * 16 * 30 * 30
+
+    def compiled(net):
+        with _no_persistent_cache():
+            c = jax.jit(make_client_grad_fn(net, flat)).lower(
+                *operands).compile()
+        text = c.as_text()
+        # name = result type (a tuple's every shape counts) opcode(
+        written = [
+            m.group(1) for m in re.finditer(
+                r"^\s*(?:ROOT )?%?([\w.\-]+) = (.*?) ([\w\-]+)\(",
+                text[text.index("\nENTRY "):], re.M)
+            if m.group(3) not in ("bitcast", "parameter")
+            and any(np.prod([int(v) for v in shape.split(",")]) == conv1
+                    for shape in re.findall(r"\w+\[([\d,]+)\]", m.group(2)))]
+        return written, c.cost_analysis()["bytes accessed"]
+
+    written, moved = compiled(model)
+    assert 1 <= len(written) <= 4, written
+    assert not [w for w in written if w.startswith("compare_select_fusion")]
+    was_written, was_moved = compiled(old_order("cifar10_cnn"))
+    assert len(was_written) > len(written), (was_written, written)
+    assert moved < 0.85 * was_moved, (moved, was_moved)
