@@ -48,7 +48,10 @@ class AttackContext(NamedTuple):
 
 def cohort_stats(mal_grads):
     """Mean and population std over the malicious cohort
-    (reference malicious.py:18-19: np.var ** 0.5, i.e. ddof=0)."""
+    (reference malicious.py:18-19: np.var ** 0.5, i.e. ddof=0).  A bf16
+    wire's rows go in as they are and the statistics come out (d,) f32:
+    the upcast fuses into the reductions, no f32 copy of the rows."""
+    mal_grads = mal_grads.astype(jnp.float32)
     mean = jnp.mean(mal_grads, axis=0)
     stdev = jnp.sqrt(jnp.var(mal_grads, axis=0))
     return mean, stdev
@@ -61,6 +64,7 @@ def masked_cohort_stats(mal_grads, delivered):
     :func:`cohort_stats` up to summation order (mean-of-all vs
     sum/count are the same reduction here: sum over the full axis
     divided by the full count)."""
+    mal_grads = mal_grads.astype(jnp.float32)
     e = jnp.maximum(jnp.sum(delivered), 1)
     mean = jnp.sum(jnp.where(delivered[:, None], mal_grads, 0.0),
                    axis=0) / e
@@ -119,7 +123,8 @@ class Attack:
         if f == 0 or self.num_std == 0:
             return users_grads
         crafted = self.craft(users_grads[:f], ctx)
-        return users_grads.at[:f].set(crafted[None, :])
+        return users_grads.at[:f].set(
+            crafted[None, :].astype(users_grads.dtype))
 
     def envelope_stats(self, users_grads, corrupted_count: int,
                        ctx: Optional[AttackContext] = None) -> dict:

@@ -89,13 +89,15 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("-s", "--dataset", default=C.MNIST,
                    choices=[C.MNIST, C.CIFAR10, C.CIFAR100, C.SYNTH_MNIST,
                             C.SYNTH_CIFAR10, C.SYNTH_MNIST_HARD,
-                            C.SYNTH_CIFAR10_HARD],
+                            C.SYNTH_CIFAR10_HARD, C.SYNTH_TOKENS,
+                            C.SYNTH_TOKENS_TINY],
                    help="CIFAR100 runs the WRN-40-4 the reference defines "
                         "but never exposes (reference main.py:114 excludes "
                         "it; data_sets.py:108-173 defines it)")
     p.add_argument("--model", default=None,
                    choices=["mnist_mlp", "mnist_cnn", "cifar10_cnn",
-                            "resnet20", "wideresnet40_4"],
+                            "resnet20", "wideresnet40_4",
+                            "smallthinker_21b_a3b_ep8", "seq_tiny"],
                    help="override the dataset's canonical model "
                         "(default: MLP for MNIST, CNN for CIFAR10, "
                         "WRN-40-4 for CIFAR100)")
@@ -139,6 +141,16 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--synth-test", default=ExperimentConfig.synth_test,
                    type=int,
                    help="test examples for SYNTH_* / fallback datasets")
+    p.add_argument("--seq-len", default=None, type=int,
+                   help="tokens a context of a SYNTH_TOKENS* dataset has "
+                        "(default: the dataset's own, 8,192; the tiny one's "
+                        "24); -c then "
+                        "counts contexts a client a round")
+    p.add_argument("--grad-dtype", default=ExperimentConfig.grad_dtype,
+                   choices=["float32", "bfloat16"],
+                   help="dtype of the (n, d) wire matrix; bfloat16 halves "
+                        "its memory (distances and attack statistics "
+                        "still accumulate in f32)")
     p.add_argument("--backend", default="auto",
                    choices=["auto", "cpu", "tpu"],
                    help="JAX platform; must be chosen before jax initializes")
@@ -549,6 +561,8 @@ def config_from_args(args) -> ExperimentConfig:
         stream_prefetch=args.stream_prefetch,
         stream_workers=args.stream_workers,
         remat=args.remat,
+        grad_dtype=args.grad_dtype,
+        seq_len=args.seq_len,
         krum_paper_scoring=args.krum_paper_scoring,
         krum_scoring_method=args.krum_scoring_method,
         distance_impl=args.distance_impl,
@@ -703,7 +717,8 @@ def main(argv=None):
 
         dataset = load_dataset(cfg.dataset, cfg.data_dir, cfg.seed,
                                synth_train=cfg.synth_train,
-                               synth_test=cfg.synth_test)
+                               synth_test=cfg.synth_test,
+                               seq_len=cfg.seq_len)
         attacker = make_attacker(cfg, dataset=dataset,
                                  name=None if args.attack == "auto"
                                  else args.attack)

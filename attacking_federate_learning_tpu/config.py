@@ -24,6 +24,11 @@ SYNTH_MNIST = "SYNTH_MNIST"      # MNIST-shaped deterministic synthetic data
 SYNTH_CIFAR10 = "SYNTH_CIFAR10"  # CIFAR10-shaped deterministic synthetic data
 SYNTH_MNIST_HARD = "SYNTH_MNIST_HARD"  # low-SNR variant for behavioral tests
 SYNTH_CIFAR10_HARD = "SYNTH_CIFAR10_HARD"  # low-SNR CIFAR-shaped variant
+# Seeded synthetic token contexts for the sequence models (data/datasets.py
+# make_synthetic_tokens): ids from a vocabulary slice of 18,992 / of 96.
+SYNTH_TOKENS = "SYNTH_TOKENS"
+SYNTH_TOKENS_TINY = "SYNTH_TOKENS_TINY"
+TOKEN_DATASETS = (SYNTH_TOKENS, SYNTH_TOKENS_TINY)
 
 # Per-dataset LR fading constants, reference main.py:144-149.
 FADING_RATES = {CIFAR10: 2000.0, MNIST: 10000.0, CIFAR100: 1500.0,
@@ -336,6 +341,10 @@ class ExperimentConfig:
     # them and --resume rebuilds the identical dataset.
     synth_train: int = 10000
     synth_test: int = 2000
+    # Tokens a context of a token dataset has (TOKEN_DATASETS); None:
+    # the dataset's own length (data/datasets.py TOKEN_SEQ_LEN: 8,192,
+    # 24 for the tiny one).
+    seq_len: Optional[int] = None
 
     # --- data partition -------------------------------------------------
     # 'iid' (DistributedSampler-equivalent, reference user.py:49-54) |
@@ -592,6 +601,15 @@ class ExperimentConfig:
                     f"model {self.model!r} expects {MODEL_FAMILY[self.model]}"
                     f"-shaped inputs but dataset {self.dataset!r} is "
                     f"{want}-shaped")
+        if self.seq_len is not None and (
+                self.dataset not in TOKEN_DATASETS or self.seq_len < 2):
+            raise ValueError(
+                f"seq_len={self.seq_len!r} needs a token dataset "
+                f"{TOKEN_DATASETS} and at least 2 tokens a context")
+        if self.grad_dtype not in ("float32", "bfloat16"):
+            raise ValueError(
+                f"grad_dtype must be 'float32' or 'bfloat16', got "
+                f"{self.grad_dtype!r}")
         if self.krum_scoring_method not in ("sort", "topk", "auto"):
             raise ValueError(
                 f"krum_scoring_method must be 'sort', 'topk' or 'auto', "
@@ -867,8 +885,12 @@ class ExperimentConfig:
 # pairing otherwise surfaces as a reshape error deep inside the jit trace).
 MODEL_FAMILY = {"mnist_mlp": "mnist", "mnist_cnn": "mnist",
                 "cifar10_cnn": "cifar", "resnet20": "cifar",
-                "wideresnet40_4": "cifar"}
-DATASET_FAMILY = {MNIST: "mnist", SYNTH_MNIST: "mnist",
+                "wideresnet40_4": "cifar",
+                "smallthinker_21b_a3b_ep8": "tokens_18992",
+                "seq_tiny": "tokens_96"}
+DATASET_FAMILY = {SYNTH_TOKENS: "tokens_18992",
+                  SYNTH_TOKENS_TINY: "tokens_96",
+                  MNIST: "mnist", SYNTH_MNIST: "mnist",
                   SYNTH_MNIST_HARD: "mnist", CIFAR10: "cifar",
                   SYNTH_CIFAR10: "cifar", SYNTH_CIFAR10_HARD: "cifar",
                   CIFAR100: "cifar"}
@@ -880,4 +902,6 @@ def default_model_for(dataset: str) -> str:
         CIFAR10: "cifar10_cnn", SYNTH_CIFAR10: "cifar10_cnn",
         SYNTH_CIFAR10_HARD: "cifar10_cnn",
         CIFAR100: "wideresnet40_4",
+        SYNTH_TOKENS: "smallthinker_21b_a3b_ep8",
+        SYNTH_TOKENS_TINY: "seq_tiny",
     }.get(dataset, "mnist_mlp")

@@ -16,10 +16,13 @@ parallelism (SURVEY.md §2.2).
 from __future__ import annotations
 
 import jax
+import jax.numpy as jnp
 
 from attacking_federate_learning_tpu.models.base import Model
 from attacking_federate_learning_tpu.models.layers import nll_loss
-from attacking_federate_learning_tpu.utils.flatten import FlatParams
+from attacking_federate_learning_tpu.utils.flatten import (
+    FlatParams, write_row
+)
 
 
 def make_loss_fn(model: Model, flat: FlatParams, remat: bool = False):
@@ -32,11 +35,58 @@ def make_loss_fn(model: Model, flat: FlatParams, remat: bool = False):
     where the vmapped (n, B, activations) footprint dominates memory.
     """
 
+    params_loss = make_params_loss_fn(model)
+
     def loss_fn(flat_w, x, y):
-        params = flat.unravel(flat_w)
-        return nll_loss(model.apply(params, x), y)
+        return params_loss(flat.unravel(flat_w), x, y)
 
     return jax.checkpoint(loss_fn) if remat else loss_fn
+
+
+def make_params_loss_fn(model: Model):
+    """The same loss on the params pytree: the model's own where it has
+    one (a sequence model's, chunked over rows), else ``nll_loss`` of
+    ``apply``."""
+    if model.loss is not None:
+        return model.loss
+    return lambda params, x, y: nll_loss(model.apply(params, x), y)
+
+
+def cohort_fits(n: int, d: int, wire_dtype, device_bytes) -> bool:
+    """Do the cohort's f32 gradients (what ``vmap(grad)`` makes, n x d x 4
+    bytes) fit on the device beside the (n, d) wire in ``wire_dtype`` and
+    the server's weights and momentum?  ``device_bytes`` None (a backend
+    that reports no limit): they do."""
+    if device_bytes is None:
+        return True
+    need = n * d * (4 + jnp.dtype(wire_dtype).itemsize) + 2 * 4 * d
+    return need <= device_bytes
+
+
+def make_scanned_client_grad_fn(model: Model, flat: FlatParams, wire_dtype):
+    """(d,), (n, B, ...), (n, B) -> (n, d) in ``wire_dtype``, one client
+    at a time: a ``lax.scan`` over clients whose body takes one client's
+    gradient as a pytree and writes it, cast leaf by leaf, into that
+    client's row of the wire (utils/flatten.py:write_row).  For a cohort
+    whose f32 gradients do not fit beside the wire (:func:`cohort_fits`):
+    one client's gradient is alive at a time, and no f32 row is
+    concatenated.  The weights are unravelled once, outside the scan."""
+    grad_fn = jax.grad(make_params_loss_fn(model))
+
+    def clients_grads(flat_w, xs, ys):
+        params = flat.unravel(flat_w)
+
+        def one_client(wire, batch):
+            i, x, y = batch
+            return write_row(wire, i, grad_fn(params, x, y)), None
+
+        n = xs.shape[0]
+        wire, _ = jax.lax.scan(
+            one_client, jnp.zeros((n, flat.dim), wire_dtype),
+            (jnp.arange(n), xs, ys))
+        return wire
+
+    return clients_grads
 
 
 def make_client_grad_fn(model: Model, flat: FlatParams, remat: bool = False):
@@ -50,7 +100,8 @@ def make_client_grad_fn(model: Model, flat: FlatParams, remat: bool = False):
 
 
 def make_client_update_fn(model: Model, flat: FlatParams,
-                          local_steps: int = 1, remat: bool = False):
+                          local_steps: int = 1, remat: bool = False,
+                          scan_dtype=None):
     """FedAvg-style local training (beyond-reference: the reference is
     strictly FedSGD — one minibatch gradient, never a local optimizer
     step, user.py:80).
@@ -64,11 +115,22 @@ def make_client_update_fn(model: Model, flat: FlatParams,
     reference quirk, is the constant base lr while clients fade,
     reference server.py:89 vs :50-52).
 
+    ``scan_dtype``: the wire's dtype where the cohort is to be scanned
+    client by client (:func:`make_scanned_client_grad_fn`; the engine
+    asks for it where :func:`cohort_fits` says no), None for the vmapped
+    form.
+
     Signature: (d,), (n, k, B, ...), (n, k, B), lr_train, lr_report
     -> (n, d).
     """
+    if scan_dtype is not None and local_steps != 1:
+        raise ValueError(
+            "a cohort too wide for vmap(grad) is scanned client by "
+            "client, which is built for local_steps=1 only")
     if local_steps == 1:
-        base = make_client_grad_fn(model, flat, remat)
+        base = (make_client_grad_fn(model, flat, remat)
+                if scan_dtype is None else
+                make_scanned_client_grad_fn(model, flat, scan_dtype))
 
         def clients_update(flat_w, xs, ys, lr_train, lr_report):
             # Squeeze the k=1 step axis; lrs are unused (parity: the

@@ -46,7 +46,7 @@ from attacking_federate_learning_tpu.attacks.base import (
 )
 from attacking_federate_learning_tpu.config import ExperimentConfig
 from attacking_federate_learning_tpu.core.client import (
-    make_client_update_fn, make_loss_fn
+    cohort_fits, make_client_update_fn, make_loss_fn
 )
 from attacking_federate_learning_tpu.core.evaluate import make_eval_fn
 from attacking_federate_learning_tpu.core.server import (
@@ -55,7 +55,9 @@ from attacking_federate_learning_tpu.core.server import (
 from attacking_federate_learning_tpu.data.augment import (
     augment_key, reflect_crop_flip
 )
-from attacking_federate_learning_tpu.data.datasets import load_dataset
+from attacking_federate_learning_tpu.data.datasets import (
+    crop_contexts, load_dataset
+)
 from attacking_federate_learning_tpu.data.partition import (
     make_shards, round_batch_indices
 )
@@ -120,8 +122,9 @@ class FederatedExperiment:
                  dataset=None, shardings=None):
         self.cfg = cfg
         self.attacker = attacker or NoAttack()
-        self.dataset = dataset or load_dataset(cfg.dataset, cfg.data_dir,
-                                               cfg.seed)
+        self.dataset = crop_contexts(
+            dataset or load_dataset(cfg.dataset, cfg.data_dir, cfg.seed,
+                                    seq_len=cfg.seq_len), cfg.seq_len)
         self.model = get_model(cfg.model)
         self.n = cfg.users_count
         self.f = cfg.corrupted_count
@@ -379,9 +382,26 @@ class FederatedExperiment:
                 f"data_augment needs (N, C, H, W) images, got "
                 f"shape {np.shape(self.dataset.train_x)} for {cfg.dataset}")
         self._grad_dtype = jnp.dtype(cfg.grad_dtype)
-        self._client_update = make_client_update_fn(self.model, self.flat,
-                                                    cfg.local_steps,
-                                                    remat=cfg.remat)
+        # A flat cohort on one device whose f32 gradients do not fit
+        # beside the wire is scanned client by client into the wire
+        # (core/client.py); read from n, d and the device, no option.  A
+        # sequence model's client step (its own loss: grouped products
+        # and loops of traced length) cannot be vmapped at all.
+        limit = (jax.local_devices()[0].memory_stats() or {}).get(
+            "bytes_limit")
+        one_device = cfg.aggregation == "flat" and shardings is None
+        if self.model.loss is not None and not one_device:
+            raise ValueError(
+                f"model {cfg.model!r} steps its clients one at a time "
+                f"(models/sequence.py): flat aggregation on one device "
+                f"only, no mesh")
+        self._scan_clients = one_device and (
+            self.model.loss is not None
+            or not cohort_fits(self.m, self.flat.dim, self._grad_dtype,
+                               limit))
+        self._client_update = make_client_update_fn(
+            self.model, self.flat, cfg.local_steps, remat=cfg.remat,
+            scan_dtype=self._grad_dtype if self._scan_clients else None)
         self._needs_server_grad = getattr(self.defense_fn,
                                           "needs_server_grad", False)
         self.metadata = (self.collect_metadata()
@@ -770,7 +790,7 @@ class FederatedExperiment:
         xs = self._maybe_augment(data, xs, t)
         k, B = self.cfg.local_steps, self.cfg.batch_size
         xs = xs.reshape((xs.shape[0], k, B) + xs.shape[2:])
-        return xs, ys.reshape((ys.shape[0], k, B))
+        return xs, ys.reshape((ys.shape[0], k, B) + ys.shape[2:])
 
     def _compute_grads_impl(self, state: ServerState, t, batches=None,
                             part=None, data=None):
@@ -3189,7 +3209,7 @@ class FederatedExperiment:
         cfg = self.cfg
         own_logger = logger is None
         logger = logger or RunLogger(cfg, cfg.output, cfg.log_dir)
-        test_size = len(self.dataset.test_y)
+        test_size = self.dataset.test_y.size    # samples, or tokens
         self._telemetry_winners = []
         self.wall_booking_failures = 0
 

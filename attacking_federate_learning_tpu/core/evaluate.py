@@ -27,9 +27,9 @@ def pad_to_batches(x, y, batch_size):
     pad = n_batches * batch_size - n
     mask = np.concatenate([np.ones(n, np.float32), np.zeros(pad, np.float32)])
     xp = np.concatenate([x, np.zeros((pad,) + x.shape[1:], x.dtype)])
-    yp = np.concatenate([y, np.zeros(pad, y.dtype)])
+    yp = np.concatenate([y, np.zeros((pad,) + y.shape[1:], y.dtype)])
     shape = (n_batches, batch_size)
-    return (xp.reshape(shape + x.shape[1:]), yp.reshape(shape),
+    return (xp.reshape(shape + x.shape[1:]), yp.reshape(shape + y.shape[1:]),
             mask.reshape(shape))
 
 
@@ -37,14 +37,21 @@ def masked_nll_metrics(apply_fn, params, bx, by, bm):
     """Scan batched (nb, B, ...) data: returns (sum of per-batch masked-mean
     NLLs, masked correct count) — the reference's exact eval arithmetic
     (server.py:104-110), shared by server eval and the backdoor ASR check
-    (backdoor.py:89-94)."""
+    (backdoor.py:89-94).  A sequence model's (B, L, V) log-probs against
+    (B, L) next tokens give the same two over tokens: the batch's mean
+    token loss and the count of tokens predicted."""
 
     def batch_metrics(carry, batch):
         x, y, m = batch
         logp = apply_fn(params, x)
-        per_ex = -jnp.take_along_axis(logp, y[:, None], axis=1).squeeze(1)
-        batch_mean = jnp.sum(per_ex * m) / jnp.maximum(jnp.sum(m), 1.0)
-        correct = jnp.sum((jnp.argmax(logp, axis=1) == y) * m)
+        per_ex = -jnp.take_along_axis(logp, y[..., None],
+                                      axis=-1).squeeze(-1)
+        count = jnp.sum(m)
+        if per_ex.ndim > 1:             # tokens: (B, L) under a (B,) mask
+            m = m.reshape(m.shape + (1,) * (per_ex.ndim - 1))
+            count = count * (per_ex.size // m.size)
+        batch_mean = jnp.sum(per_ex * m) / jnp.maximum(count, 1.0)
+        correct = jnp.sum((jnp.argmax(logp, axis=-1) == y) * m)
         loss_sum, correct_sum = carry
         return (loss_sum + batch_mean, correct_sum + correct), None
 

@@ -36,8 +36,8 @@ from attacking_federate_learning_tpu.utils.profiling import span
 
 class Dataset(NamedTuple):
     name: str
-    train_x: np.ndarray   # (N, ...) normalized float32
-    train_y: np.ndarray   # (N,) int32
+    train_x: np.ndarray   # (N, ...) normalized float32; (N, L) int32 ids
+    train_y: np.ndarray   # (N,) int32; (N, L) int32 next tokens
     test_x: np.ndarray
     test_y: np.ndarray
     num_classes: int
@@ -188,13 +188,84 @@ def make_synthetic(shape, num_classes: int, n_train: int, n_test: int,
 
 
 # --------------------------------------------------------------------------
+# deterministic synthetic token contexts (the sequence models' data)
+# --------------------------------------------------------------------------
+
+TOKEN_VOCAB = {C.SYNTH_TOKENS: 18_992, C.SYNTH_TOKENS_TINY: 96}
+# tokens a context has unless --seq-len says
+TOKEN_SEQ_LEN = {C.SYNTH_TOKENS: 8192, C.SYNTH_TOKENS_TINY: 24}
+TOKEN_FOLLOW = 0.5          # P(next token = the previous one's successor)
+
+
+def make_synthetic_tokens(vocab: int, seq_len: int, n_train: int,
+                          n_test: int, seed: int, name: str) -> Dataset:
+    """Contexts of ``seq_len`` token ids below ``vocab``, one context one
+    document (no packing), with the next token at every position as the
+    label: ``x`` (N, L) and ``y`` (N, L) with ``y[:, t]`` the token after
+    ``x[:, t]`` (contexts are drawn one token longer), int32 throughout.
+
+    Zipf-like unigram frequencies, p(id) ~ 1 / (id + shift) with shift =
+    max(3, vocab / 192) (98.9 at 18,992 ids: the most frequent token is
+    0.19 % of the stream; with a head as heavy as shift 3 gives, 3.8 %, how
+    many tokens a chip's held experts draw is decided by where a dozen
+    token ids happen to route, and a round's time moves 5 % with the seed:
+    PERF.md section 6, PR 36), with a first-order dependence a model can
+    learn: with probability
+    ``TOKEN_FOLLOW`` the next token is the previous token's successor
+    under a fixed seeded permutation of the vocabulary, else a fresh
+    unigram draw.  A model that learns the unigram beats ln(vocab); one
+    that learns the successor table halves what is left."""
+    rng = np.random.default_rng(seed)
+    successor = rng.permutation(vocab).astype(np.int32)
+    cdf = np.cumsum(1.0 / (np.arange(vocab) + max(3.0, vocab / 192)))
+    cdf /= cdf[-1]
+
+    def gen(n):
+        fresh = np.minimum(np.searchsorted(
+            cdf, rng.random((n, seq_len + 1))), vocab - 1).astype(np.int32)
+        follow = rng.random((n, seq_len + 1)) < TOKEN_FOLLOW
+        tokens = fresh
+        for t in range(1, seq_len + 1):
+            tokens[:, t] = np.where(follow[:, t],
+                                    successor[tokens[:, t - 1]],
+                                    fresh[:, t])
+        return (np.ascontiguousarray(tokens[:, :-1]),
+                np.ascontiguousarray(tokens[:, 1:]))
+
+    tx, ty = gen(n_train)
+    vx, vy = gen(n_test)
+    return Dataset(name, tx, ty, vx, vy, vocab)
+
+
+def crop_contexts(dataset: Dataset, seq_len: Optional[int]) -> Dataset:
+    """A token dataset's contexts cut to their first ``seq_len`` tokens
+    (labels with them); anything else, or ``seq_len`` None, as it is."""
+    if seq_len is None or dataset.name not in TOKEN_VOCAB:
+        return dataset
+    have = dataset.train_x.shape[1]
+    if seq_len > have:
+        raise ValueError(f"seq_len={seq_len} but {dataset.name} was made "
+                         f"with contexts of {have} tokens")
+    if seq_len == have:
+        return dataset
+    return dataset._replace(**{
+        field: np.ascontiguousarray(getattr(dataset, field)[:, :seq_len])
+        for field in ("train_x", "train_y", "test_x", "test_y")})
+
+
+# --------------------------------------------------------------------------
 # dispatch
 # --------------------------------------------------------------------------
 
 @span("setup.dataset")
 def load_dataset(name: str, data_dir: str = "data", seed: int = 0,
                  synth_train: int = 10000, synth_test: int = 2000,
-                 ) -> Dataset:
+                 seq_len: Optional[int] = None) -> Dataset:
+    if name in TOKEN_VOCAB:
+        return make_synthetic_tokens(
+            TOKEN_VOCAB[name],
+            TOKEN_SEQ_LEN[name] if seq_len is None else seq_len,
+            synth_train, synth_test, seed, name)
     if name == C.MNIST:
         try:
             return load_mnist(data_dir)

@@ -8,3 +8,4 @@ from attacking_federate_learning_tpu.models import mnist_cnn  # noqa: F401
 from attacking_federate_learning_tpu.models import cifar10  # noqa: F401
 from attacking_federate_learning_tpu.models import wideresnet  # noqa: F401
 from attacking_federate_learning_tpu.models import resnet  # noqa: F401
+from attacking_federate_learning_tpu.models import sequence  # noqa: F401

@@ -131,7 +131,9 @@ def log_softmax(x):
 
 def nll_loss(log_probs, targets):
     # torch NLLLoss(mean) over log-probabilities (reference user.py:36,
-    # server.py:17).
+    # server.py:17): (batch, classes) against (batch,) for a classifier,
+    # (batch, length, vocabulary) against (batch, length) next tokens for
+    # a sequence model.
     return -jnp.take_along_axis(
-        log_probs, targets[:, None], axis=1
-    ).squeeze(1).mean()
+        log_probs, targets[..., None], axis=-1
+    ).squeeze(-1).mean()

@@ -41,6 +41,18 @@ from attacking_federate_learning_tpu.utils.costs import stage_scope
 GRAM_BLOCK_ROWS = 1024
 
 
+# Columns from which a single-device self-Gram is summed over column blocks
+# (:func:`_wide_sq_distances`), and the block's width.  One dot over 3.7e8
+# columns accumulates every product into one f32 accumulator per entry: on
+# correlated bf16 rows (cosine 0.5) its Krum scores read 1.64e-05 from
+# float64 on the chip, over the benchmark's tie band of 1e-5; blocks of
+# 2**20 columns keep each accumulator's run short, add the 354 partial Grams
+# afterwards and read 1.96e-06 (PERF.md section 6, PR 36).  Both existing
+# cells are three orders of magnitude narrower and keep their program.
+WIDE_COLUMNS = 1 << 26
+GRAM_COLUMN_BLOCK = 1 << 20
+
+
 def _sq_norms(A):
     return jnp.sum(A.astype(jnp.float32) * A.astype(jnp.float32), axis=-1)
 
@@ -97,6 +109,9 @@ def _self_sq_distances(G, precision=None, block=None):
     symmetric.  ``block`` defaults to :data:`GRAM_BLOCK_ROWS`, read at
     trace time (the CPU tests lower it to reach the path at n = 48)."""
     block = GRAM_BLOCK_ROWS if block is None else block
+    if (G.shape[1] >= WIDE_COLUMNS
+            and jax.typeof(G).sharding.mesh.size <= 1):
+        return _wide_sq_distances(G, precision)
     # A mesh of several devices in the operand's type: static row slices
     # of a row-sharded G make GSPMD reshard every panel (PERF.md §6, PR
     # 31), so a sharded cohort keeps the one dot GSPMD partitions whole.
@@ -104,6 +119,30 @@ def _self_sq_distances(G, precision=None, block=None):
         return cross_sq_distances(G, G, precision)
     sq = _sq_norms(G)
     return _sq_from_gram(sq, sq, _symmetric_gram(G, precision, block))
+
+
+def _wide_sq_distances(G, precision=None, width=None):
+    """:func:`cross_sq_distances` of a very wide G with itself: Gram and
+    squared norms summed over column blocks of ``width``, in f32, with G
+    read in its own dtype block by block (no f32 copy of the matrix)."""
+    width = GRAM_COLUMN_BLOCK if width is None else width
+    n, d = G.shape
+    whole = d // width
+
+    def part(B):
+        return _gram(B, B, precision), _sq_norms(B)
+
+    def body(i, acc):
+        gram, sq = part(lax.dynamic_slice_in_dim(G, i * width, width, axis=1))
+        return acc[0] + gram, acc[1] + sq
+
+    gram, sq = lax.fori_loop(
+        0, whole, body,
+        (jnp.zeros((n, n), jnp.float32), jnp.zeros((n,), jnp.float32)))
+    if whole * width < d:
+        tail_gram, tail_sq = part(G[:, whole * width:])
+        gram, sq = gram + tail_gram, sq + tail_sq
+    return _sq_from_gram(sq, sq, gram)
 
 
 def pairwise_sq_distances(G, precision=None):

@@ -88,12 +88,19 @@ _STAGE_SET = frozenset(STAGES)
 # ``client_step`` the vmapped client update, ``craft`` the attacker's
 # rewrite of rows [0, f); ``gram`` the pairwise squared distances
 # (ops/distances.py), ``select`` Krum's scoring, sort / top-k and argmin.
+# Inside ``client_step``, a sequence model's own two (models/sequence.py):
+# ``attention`` (scores, mask, softmax, values; forward and backward, both
+# kinds of layer) and ``experts`` (top-k, dispatch, the grouped products,
+# combine; not the router's matmul).  With innermost booking
+# ``client_step`` then holds the rest: projections, norms, head and loss,
+# the row's write.
 # Only the measured booking (utils/walls.py) reads them:
 # :func:`stage_attribution` and ``hlo_stage_map`` filter on
 # :data:`STAGES`, so an op under ``deliver/gather`` still books to
 # ``deliver`` there.
 SUBSTAGES = {"gather": "deliver", "client_step": "deliver",
              "craft": "deliver",
+             "attention": "deliver", "experts": "deliver",
              "gram": "tier1_aggregate", "select": "tier1_aggregate"}
 
 _STAGE_ENV = "FL_STAGE_SCOPES"
