@@ -20,8 +20,9 @@ import jax.numpy as jnp
 
 from attacking_federate_learning_tpu.models.base import Model
 from attacking_federate_learning_tpu.models.layers import nll_loss
+from attacking_federate_learning_tpu.utils.costs import note_wire
 from attacking_federate_learning_tpu.utils.flatten import (
-    FlatParams, write_row
+    FlatParams, wire_path, write_row
 )
 
 
@@ -70,17 +71,22 @@ def make_scanned_client_grad_fn(model: Model, flat: FlatParams, wire_dtype):
     client's row of the wire (utils/flatten.py:write_row).  For a cohort
     whose f32 gradients do not fit beside the wire (:func:`cohort_fits`):
     one client's gradient is alive at a time, and no f32 row is
-    concatenated.  The weights are unravelled once, outside the scan."""
+    concatenated.  The weights are unravelled once, outside the scan.
+    Each trace logs how the rows are written (utils/costs.py:note_wire):
+    the wire's layout is the compiler's own."""
     grad_fn = jax.grad(make_params_loss_fn(model))
 
     def clients_grads(flat_w, xs, ys):
         params = flat.unravel(flat_w)
+        n = xs.shape[0]
+        note_wire({"rows": n, "cols": flat.dim,
+                   "dtype": jnp.dtype(wire_dtype).name, "layout": "default",
+                   "path": wire_path(n)})
 
         def one_client(wire, batch):
             i, x, y = batch
             return write_row(wire, i, grad_fn(params, x, y)), None
 
-        n = xs.shape[0]
         wire, _ = jax.lax.scan(
             one_client, jnp.zeros((n, flat.dim), wire_dtype),
             (jnp.arange(n), xs, ys))

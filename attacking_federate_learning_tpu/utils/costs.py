@@ -234,6 +234,7 @@ class _CacheCounters:
     trace_lower: list = []  # one record per jaxpr trace / MLIR lowering
     attention: list = []    # one record per traced run of attention layers
     pending: list = []      # attention records since the last compile
+    pending_wire: list = []  # wire records since the last compile
     booked = (0, 0)         # (hits, misses) already attributed
 
 
@@ -283,6 +284,9 @@ def install_cache_counters() -> None:
         if _CacheCounters.pending:
             record["attention"] = _CacheCounters.pending
             _CacheCounters.pending = []
+        if _CacheCounters.pending_wire:
+            record["wire"] = _CacheCounters.pending_wire
+            _CacheCounters.pending_wire = []
         _CacheCounters.compiles.append(record)
 
     jax.monitoring.register_event_listener(listen)
@@ -301,7 +305,8 @@ def compile_log() -> list:
     persistent cache), 'miss' (compiled and written) or 'uncached'
     (compiled, under the persistence threshold or cache disabled), and
     ``"attention"`` where the program traced attention layers
-    (:func:`note_attention`)."""
+    (:func:`note_attention`), ``"wire"`` where it traced a scanned client
+    step (:func:`note_wire`)."""
     return list(_CacheCounters.compiles)
 
 
@@ -331,6 +336,15 @@ def note_attention(record: dict) -> None:
 def attention_log() -> list:
     """Every :func:`note_attention` record, in order."""
     return list(_CacheCounters.attention)
+
+
+def note_wire(record: dict) -> None:
+    """Log, at trace time, how a scanned client step writes its rows
+    into the wire (core/client.py: ``{"rows", "cols", "dtype", "layout",
+    "path"}``, ``path`` as ``utils/flatten.py:wire_path`` chose it).  The
+    next backend compile's :func:`compile_log` record carries the
+    records traced for it under ``"wire"``."""
+    _CacheCounters.pending_wire.append(record)
 
 
 def compilation_cache_dir() -> Optional[str]:

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 
 
 LANES = 128     # a TPU vector's lanes: the width leaves are joined at
+TILE_ROWS = 8   # the rows one TPU tile of an f32 or a bf16 matrix holds
 
 
 class FlatParams(NamedTuple):
@@ -66,6 +67,24 @@ def _join_lanes(leaves):
         [leaf.reshape(-1, LANES) for leaf in leaves]).reshape(-1)
 
 
+def _platform():
+    return jax.default_backend()
+
+
+def wire_path(rows: int) -> str:
+    """How :func:`write_row` writes a row into an (n, d) wire of ``rows``
+    = n on the default backend: ``"tiles"`` where every tile of the
+    matrix holds every row, ``"plain"`` elsewhere.  The TPU compiler
+    tiles an f32 or a bf16 wire 8 rows deep ((8, 128), and (8, 128)(2, 1)
+    with rows packed in pairs), so on a TPU a wire of at most
+    :data:`TILE_ROWS` rows is one band of tiles and any row's write reads
+    and writes all of it: a dynamic update does so at a third of the
+    chip's bandwidth behind a padded copy of the row, a select over the
+    wire streams it, in place as the scan's carry (PERF.md, section 6).
+    The CPU keeps rows whole and updates one in place."""
+    return "tiles" if _platform() == "tpu" and rows <= TILE_ROWS else "plain"
+
+
 def write_row(wire, row, tree):
     """``tree``'s leaves written into row ``row`` (traced) of the (n, d)
     wire matrix in the matrix's own dtype: the row :func:`ravel` would
@@ -75,8 +94,11 @@ def write_row(wire, row, tree):
     (:func:`ravel`) it costs seconds (PERF.md section 6, PR 36)."""
     leaves = [leaf.astype(wire.dtype) for leaf in jax.tree.leaves(tree)]
     assert sum(leaf.size for leaf in leaves) == wire.shape[1], wire.shape
-    return jax.lax.dynamic_update_slice(wire, ravel(leaves)[None, :],
-                                        (row, 0))
+    flat = ravel(leaves)
+    if wire_path(wire.shape[0]) == "tiles":
+        i = jax.lax.broadcasted_iota(jnp.int32, (wire.shape[0], 1), 0)
+        return jnp.where(i == row, flat[None, :], wire)
+    return jax.lax.dynamic_update_slice(wire, flat[None, :], (row, 0))
 
 
 def ravel_batch(trees) -> jax.Array:
