@@ -90,14 +90,15 @@ def build_parser() -> argparse.ArgumentParser:
                    choices=[C.MNIST, C.CIFAR10, C.CIFAR100, C.SYNTH_MNIST,
                             C.SYNTH_CIFAR10, C.SYNTH_MNIST_HARD,
                             C.SYNTH_CIFAR10_HARD, C.SYNTH_TOKENS,
-                            C.SYNTH_TOKENS_TINY],
+                            C.SYNTH_TOKENS_TINY, C.SYNTH_TOKENS_16032],
                    help="CIFAR100 runs the WRN-40-4 the reference defines "
                         "but never exposes (reference main.py:114 excludes "
                         "it; data_sets.py:108-173 defines it)")
     p.add_argument("--model", default=None,
                    choices=["mnist_mlp", "mnist_cnn", "cifar10_cnn",
                             "resnet20", "wideresnet40_4",
-                            "smallthinker_21b_a3b_ep8", "seq_tiny"],
+                            "smallthinker_21b_a3b_ep8", "seq_tiny",
+                            "kanana_2_30b_a3b_ep16", "mla_tiny"],
                    help="override the dataset's canonical model "
                         "(default: MLP for MNIST, CNN for CIFAR10, "
                         "WRN-40-4 for CIFAR100)")

@@ -26,9 +26,29 @@ SYNTH_MNIST_HARD = "SYNTH_MNIST_HARD"  # low-SNR variant for behavioral tests
 SYNTH_CIFAR10_HARD = "SYNTH_CIFAR10_HARD"  # low-SNR CIFAR-shaped variant
 # Seeded synthetic token contexts for the sequence models (data/datasets.py
 # make_synthetic_tokens): ids from a vocabulary slice of 18,992 / of 96.
+# SYNTH_TOKENS_<V> draws from a slice of V ids (kanana_2_30b_a3b_ep16's).
 SYNTH_TOKENS = "SYNTH_TOKENS"
 SYNTH_TOKENS_TINY = "SYNTH_TOKENS_TINY"
-TOKEN_DATASETS = (SYNTH_TOKENS, SYNTH_TOKENS_TINY)
+SYNTH_TOKENS_16032 = "SYNTH_TOKENS_16032"
+
+
+def token_vocab(dataset: str) -> Optional[int]:
+    """The vocabulary slice a token dataset's ids are drawn from, by its
+    name; None for a dataset of another kind."""
+    if dataset == SYNTH_TOKENS_TINY:
+        return 96
+    if dataset == SYNTH_TOKENS:
+        return 18_992
+    tail = dataset[len(SYNTH_TOKENS) + 1:]
+    if dataset.startswith(SYNTH_TOKENS + "_") and tail.isdigit():
+        return int(tail)
+    return None
+
+
+def dataset_family(dataset: str) -> Optional[str]:
+    """The input shape a dataset gives (matched against MODEL_FAMILY)."""
+    vocab = token_vocab(dataset)
+    return f"tokens_{vocab}" if vocab else DATASET_FAMILY.get(dataset)
 
 # Per-dataset LR fading constants, reference main.py:144-149.
 FADING_RATES = {CIFAR10: 2000.0, MNIST: 10000.0, CIFAR100: 1500.0,
@@ -595,17 +615,17 @@ class ExperimentConfig:
 
     def __post_init__(self):
         if self.model is not None and self.model in MODEL_FAMILY:
-            want = DATASET_FAMILY.get(self.dataset)
+            want = dataset_family(self.dataset)
             if want is not None and MODEL_FAMILY[self.model] != want:
                 raise ValueError(
                     f"model {self.model!r} expects {MODEL_FAMILY[self.model]}"
                     f"-shaped inputs but dataset {self.dataset!r} is "
                     f"{want}-shaped")
         if self.seq_len is not None and (
-                self.dataset not in TOKEN_DATASETS or self.seq_len < 2):
+                token_vocab(self.dataset) is None or self.seq_len < 2):
             raise ValueError(
                 f"seq_len={self.seq_len!r} needs a token dataset "
-                f"{TOKEN_DATASETS} and at least 2 tokens a context")
+                f"({SYNTH_TOKENS}*) and at least 2 tokens a context")
         if self.grad_dtype not in ("float32", "bfloat16"):
             raise ValueError(
                 f"grad_dtype must be 'float32' or 'bfloat16', got "
@@ -887,10 +907,10 @@ MODEL_FAMILY = {"mnist_mlp": "mnist", "mnist_cnn": "mnist",
                 "cifar10_cnn": "cifar", "resnet20": "cifar",
                 "wideresnet40_4": "cifar",
                 "smallthinker_21b_a3b_ep8": "tokens_18992",
-                "seq_tiny": "tokens_96"}
-DATASET_FAMILY = {SYNTH_TOKENS: "tokens_18992",
-                  SYNTH_TOKENS_TINY: "tokens_96",
-                  MNIST: "mnist", SYNTH_MNIST: "mnist",
+                "seq_tiny": "tokens_96",
+                "kanana_2_30b_a3b_ep16": "tokens_16032",
+                "mla_tiny": "tokens_96"}
+DATASET_FAMILY = {MNIST: "mnist", SYNTH_MNIST: "mnist",
                   SYNTH_MNIST_HARD: "mnist", CIFAR10: "cifar",
                   SYNTH_CIFAR10: "cifar", SYNTH_CIFAR10_HARD: "cifar",
                   CIFAR100: "cifar"}
@@ -904,4 +924,5 @@ def default_model_for(dataset: str) -> str:
         CIFAR100: "wideresnet40_4",
         SYNTH_TOKENS: "smallthinker_21b_a3b_ep8",
         SYNTH_TOKENS_TINY: "seq_tiny",
+        SYNTH_TOKENS_16032: "kanana_2_30b_a3b_ep16",
     }.get(dataset, "mnist_mlp")

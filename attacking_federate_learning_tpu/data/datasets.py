@@ -191,9 +191,9 @@ def make_synthetic(shape, num_classes: int, n_train: int, n_test: int,
 # deterministic synthetic token contexts (the sequence models' data)
 # --------------------------------------------------------------------------
 
-TOKEN_VOCAB = {C.SYNTH_TOKENS: 18_992, C.SYNTH_TOKENS_TINY: 96}
-# tokens a context has unless --seq-len says
-TOKEN_SEQ_LEN = {C.SYNTH_TOKENS: 8192, C.SYNTH_TOKENS_TINY: 24}
+# tokens a context has unless --seq-len says: 24 in the tiny set, 8,192
+# in every set of a published model's slice (C.token_vocab names the slice)
+TOKEN_SEQ_LEN = {C.SYNTH_TOKENS_TINY: 24}
 TOKEN_FOLLOW = 0.5          # P(next token = the previous one's successor)
 
 
@@ -240,7 +240,7 @@ def make_synthetic_tokens(vocab: int, seq_len: int, n_train: int,
 def crop_contexts(dataset: Dataset, seq_len: Optional[int]) -> Dataset:
     """A token dataset's contexts cut to their first ``seq_len`` tokens
     (labels with them); anything else, or ``seq_len`` None, as it is."""
-    if seq_len is None or dataset.name not in TOKEN_VOCAB:
+    if seq_len is None or C.token_vocab(dataset.name) is None:
         return dataset
     have = dataset.train_x.shape[1]
     if seq_len > have:
@@ -261,10 +261,10 @@ def crop_contexts(dataset: Dataset, seq_len: Optional[int]) -> Dataset:
 def load_dataset(name: str, data_dir: str = "data", seed: int = 0,
                  synth_train: int = 10000, synth_test: int = 2000,
                  seq_len: Optional[int] = None) -> Dataset:
-    if name in TOKEN_VOCAB:
+    if C.token_vocab(name) is not None:
         return make_synthetic_tokens(
-            TOKEN_VOCAB[name],
-            TOKEN_SEQ_LEN[name] if seq_len is None else seq_len,
+            C.token_vocab(name),
+            TOKEN_SEQ_LEN.get(name, 8192) if seq_len is None else seq_len,
             synth_train, synth_test, seed, name)
     if name == C.MNIST:
         try:

@@ -4,9 +4,15 @@ query tile can see, with online softmax, so that no (queries, keys) score,
 probability or gradient tensor reaches HBM.
 
 Layouts are the projections' own, so nothing is transposed around a call:
-q and the output (B, L, heads * D), k and v (B, L, kv_heads * D), the
-``heads / kv_heads`` query heads of a kv group side by side in one
-(tile, R * D) block that every key tile of the group is read once for.
+q (B, L, heads * Dk), k (B, L, kv_heads * Dk), v (B, L, kv_heads * Dv) and
+the output (B, L, heads * Dv), the ``heads / kv_heads`` query heads of a kv
+group side by side in one (tile, R * Dk) block that every key tile of the
+group is read once for.  The q.k width Dk may differ from the value width
+Dv and need not be whole lanes (latent attention's 192 = 128 + 64 against
+values of 128): q and k are then zero-padded to whole lanes, which adds
+nothing to a score, and the scale stays ``1 / sqrt(Dk)`` of the unpadded
+width.  On the MXU a contraction of 192 takes the same two 128-deep passes
+as one of 256.
 The backward keeps q, k, v and the log-sum-exp of every query row,
 (B, kv_heads, R, L) f32 (``checkpoint_name`` ``attention_lse``), and
 recomputes scores and probabilities from it tile by tile; the dq kernel
@@ -53,10 +59,11 @@ NT = (((1,), (1,)), ((), ()))       # x @ y.T
 NN = (((1,), (0,)), ((), ()))       # x @ y
 
 
-def fits(length, head_dim, tile):
-    """Whether the kernels take this shape: whole lane-wide heads, tiles
-    of whole lanes and a context of whole tiles."""
-    return head_dim % LANES == 0 and tile % LANES == 0 and length % tile == 0
+def fits(length, v_dim, tile):
+    """Whether the kernels take this shape: values of whole lanes (q and k
+    are padded to them), tiles of whole lanes and a context of whole
+    tiles."""
+    return v_dim % LANES == 0 and tile % LANES == 0 and length % tile == 0
 
 
 def back(length, window, tile):
@@ -115,10 +122,10 @@ def _tiled(x, width):
 # --------------------------------------------------------------------------
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc, *,
-                heads, head_dim, tile, back, window, scale):
+                heads, qk_dim, v_dim, tile, back, window, scale):
     i, step = pl.program_id(2), pl.program_id(3)
     j = jnp.maximum(i - back, 0) + step
-    D = head_dim
+    K, D = qk_dim, v_dim
 
     @pl.when(step == 0)
     def _():
@@ -131,7 +138,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc, *,
         v = v_ref[...].astype(MXU)
         seen = _visible(i, j, tile, window) if masked else None
         for r in range(heads):
-            q = q_ref[:, r * D:(r + 1) * D].astype(MXU)
+            q = q_ref[:, r * K:(r + 1) * K].astype(MXU)
             s = lax.dot_general(q, k, NT,
                                 preferred_element_type=jnp.float32) * scale
             if masked:
@@ -160,14 +167,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc, *,
             lse_ref[r:r + 1, :] = jnp.transpose(lse)[0:1, :]
 
 
-def _forward(q, k, v, D, window, tile, interpret):
-    B, L, HD = q.shape
-    G = k.shape[2] // D
-    R = HD // (G * D)
+def _forward(q, k, v, K, D, scale, window, tile, interpret):
+    B, L, HK = q.shape
+    G = k.shape[2] // K
+    R = HK // (G * K)
     tiles, b = L // tile, back(L, window, tile)
     kernel = functools.partial(
-        _fwd_kernel, heads=R, head_dim=D, tile=tile, back=b, window=window,
-        scale=1.0 / math.sqrt(D))
+        _fwd_kernel, heads=R, qk_dim=K, v_dim=D, tile=tile, back=b,
+        window=window, scale=scale)
 
     def kv_tile(bi, g, i, step):
         return bi, jnp.minimum(jnp.maximum(i - b, 0) + step, i), g
@@ -176,8 +183,8 @@ def _forward(q, k, v, D, window, tile, interpret):
         kernel,
         grid=(B, G, tiles, b + 1),
         in_specs=[
-            pl.BlockSpec((None, tile, R * D), lambda bi, g, i, s: (bi, i, g)),
-            pl.BlockSpec((None, tile, D), kv_tile),
+            pl.BlockSpec((None, tile, R * K), lambda bi, g, i, s: (bi, i, g)),
+            pl.BlockSpec((None, tile, K), kv_tile),
             pl.BlockSpec((None, tile, D), kv_tile),
         ],
         out_specs=[
@@ -185,7 +192,7 @@ def _forward(q, k, v, D, window, tile, interpret):
             pl.BlockSpec((None, None, R, tile),
                          lambda bi, g, i, s: (bi, g, 0, i)),
         ],
-        out_shape=[jax.ShapeDtypeStruct(q.shape, jnp.float32),
+        out_shape=[jax.ShapeDtypeStruct((B, L, G * R * D), jnp.float32),
                    jax.ShapeDtypeStruct((B, G, R, L), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((R, tile, LANES), jnp.float32),
                         pltpu.VMEM((R, tile, LANES), jnp.float32),
@@ -200,7 +207,7 @@ def _forward(q, k, v, D, window, tile, interpret):
 # --------------------------------------------------------------------------
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dq_ref, di_ref, acc_sc,
-               di_sc, *, heads, head_dim, tile, back, window, scale):
+               di_sc, *, heads, qk_dim, v_dim, tile, back, window, scale):
     """Two sweeps over the key tiles a query tile reads: the first sums
     ``di = rowsum(P * dP)`` (which is ``rowsum(dO * O)``, so that the
     output need not be kept for the backward), the second the gradient."""
@@ -208,7 +215,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dq_ref, di_ref, acc_sc,
     span = back + 1
     second = step >= span
     j = jnp.maximum(i - back, 0) + step % span
-    D = head_dim
+    K, D = qk_dim, v_dim
 
     @pl.when(step == 0)
     def _():
@@ -220,15 +227,15 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dq_ref, di_ref, acc_sc,
         v = v_ref[...].astype(MXU)
         seen = _visible(i, j, tile, window) if masked else None
         for r in range(heads):
-            cols = slice(r * D, (r + 1) * D)
+            cols = slice(r * K, (r + 1) * K)
             q = q_ref[:, cols].astype(MXU)
             s = lax.dot_general(q, k, NT,
                                 preferred_element_type=jnp.float32) * scale
             if masked:
                 s = jnp.where(seen, s, MASKED)
             p = jnp.exp(s - jnp.expand_dims(lse_ref[r], -1))
-            dp = lax.dot_general(do_ref[:, cols].astype(MXU), v, NT,
-                                 preferred_element_type=jnp.float32)
+            dp = lax.dot_general(do_ref[:, r * D:(r + 1) * D].astype(MXU), v,
+                                 NT, preferred_element_type=jnp.float32)
             if not second:
                 di_sc[r] += lax.broadcast_in_dim(
                     (p * dp).sum(axis=-1), di_sc.shape[1:], (0,))
@@ -252,10 +259,11 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dq_ref, di_ref, acc_sc,
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dk_ref, dv_ref,
-                dk_sc, dv_sc, *, heads, head_dim, tile, tiles, window, scale):
+                dk_sc, dv_sc, *, heads, qk_dim, v_dim, tile, tiles, window,
+                scale):
     j, step = pl.program_id(2), pl.program_id(3)
     i = j + step
-    D = head_dim
+    K, D = qk_dim, v_dim
 
     @pl.when(step == 0)
     def _():
@@ -270,9 +278,8 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dk_ref, dv_ref,
         seen = (_visible(i, j, tile, window, transposed=True) if masked
                 else None)
         for r in range(heads):
-            cols = slice(r * D, (r + 1) * D)
-            q = q_ref[:, cols].astype(MXU)
-            do = do_ref[:, cols].astype(MXU)
+            q = q_ref[:, r * K:(r + 1) * K].astype(MXU)
+            do = do_ref[:, r * D:(r + 1) * D].astype(MXU)
             s = lax.dot_general(k, q, NT,
                                 preferred_element_type=jnp.float32) * scale
             if masked:
@@ -297,13 +304,14 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dk_ref, dv_ref,
         dv_ref[...] = dv_sc[...]
 
 
-def _backward(q, k, v, lse, do, D, window, tile, interpret):
-    B, L, HD = q.shape
-    G = k.shape[2] // D
-    R = HD // (G * D)
+def _backward(q, k, v, lse, do, K, D, scale, window, tile, interpret):
+    B, L, HK = q.shape
+    G = k.shape[2] // K
+    R = HK // (G * K)
     tiles, b = L // tile, back(L, window, tile)
-    scale = 1.0 / math.sqrt(D)
-    rows = pl.BlockSpec((None, tile, R * D), lambda bi, g, i, s: (bi, i, g))
+    rows = pl.BlockSpec((None, tile, R * K), lambda bi, g, i, s: (bi, i, g))
+    out_rows = pl.BlockSpec((None, tile, R * D),
+                            lambda bi, g, i, s: (bi, i, g))
     stats = pl.BlockSpec((None, None, R, tile),
                          lambda bi, g, i, s: (bi, g, 0, i))
 
@@ -311,15 +319,15 @@ def _backward(q, k, v, lse, do, D, window, tile, interpret):
         return bi, jnp.minimum(jnp.maximum(i - b, 0) + step % (b + 1), i), g
 
     dq, di = pl.pallas_call(
-        functools.partial(_dq_kernel, heads=R, head_dim=D, tile=tile,
+        functools.partial(_dq_kernel, heads=R, qk_dim=K, v_dim=D, tile=tile,
                           back=b, window=window, scale=scale),
         grid=(B, G, tiles, 2 * (b + 1)),
-        in_specs=[rows, pl.BlockSpec((None, tile, D), kv_tile),
-                  pl.BlockSpec((None, tile, D), kv_tile), rows, stats],
+        in_specs=[rows, pl.BlockSpec((None, tile, K), kv_tile),
+                  pl.BlockSpec((None, tile, D), kv_tile), out_rows, stats],
         out_specs=[rows, stats],
         out_shape=[jax.ShapeDtypeStruct(q.shape, jnp.float32),
                    jax.ShapeDtypeStruct(lse.shape, jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((tile, R * D), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((tile, R * K), jnp.float32),
                         pltpu.VMEM((R, tile, LANES), jnp.float32)],
         compiler_params=PARAMS, interpret=interpret, name="flash_dq",
     )(q, k, v, do, lse)
@@ -330,18 +338,20 @@ def _backward(q, k, v, lse, do, D, window, tile, interpret):
     def q_stats(bi, g, j, step):
         return bi, g, 0, jnp.minimum(j + step, tiles - 1)
 
-    keys = pl.BlockSpec((None, tile, D), lambda bi, g, j, s: (bi, j, g))
-    q_rows = pl.BlockSpec((None, tile, R * D), q_tile)
+    keys = pl.BlockSpec((None, tile, K), lambda bi, g, j, s: (bi, j, g))
+    values = pl.BlockSpec((None, tile, D), lambda bi, g, j, s: (bi, j, g))
+    q_rows = pl.BlockSpec((None, tile, R * K), q_tile)
+    do_rows = pl.BlockSpec((None, tile, R * D), q_tile)
     q_stat = pl.BlockSpec((None, None, R, tile), q_stats)
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, heads=R, head_dim=D, tile=tile,
+        functools.partial(_dkv_kernel, heads=R, qk_dim=K, v_dim=D, tile=tile,
                           tiles=tiles, window=window, scale=scale),
         grid=(B, G, tiles, b + 1),
-        in_specs=[q_rows, keys, keys, q_rows, q_stat, q_stat],
-        out_specs=[keys, keys],
+        in_specs=[q_rows, keys, values, do_rows, q_stat, q_stat],
+        out_specs=[keys, values],
         out_shape=[jax.ShapeDtypeStruct(k.shape, jnp.float32),
                    jax.ShapeDtypeStruct(v.shape, jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((tile, D), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((tile, K), jnp.float32),
                         pltpu.VMEM((tile, D), jnp.float32)],
         compiler_params=PARAMS, interpret=interpret, name="flash_dkv",
     )(q, k, v, do, lse, di)
@@ -353,37 +363,42 @@ def _backward(q, k, v, lse, do, D, window, tile, interpret):
 # --------------------------------------------------------------------------
 
 def attention(q, k, v, window, tile, interpret=None):
-    """Causal grouped-query attention, (B, L, heads, D) x (B, L, kv_heads,
-    D) -> (B, L, heads * D) in f32; ``window`` None or the look-back of a
-    window layer; tiles of ``tile`` queries by ``tile`` keys (:func:`fits`
-    says which shapes).  ``interpret`` None runs the kernels compiled on a
-    TPU and interpreted elsewhere."""
-    B, L, H, D = q.shape
+    """Causal grouped-query attention, (B, L, heads, Dk) x (B, L, kv_heads,
+    Dk) x (B, L, kv_heads, Dv) -> (B, L, heads * Dv) in f32; ``window`` None
+    or the look-back of a window layer; tiles of ``tile`` queries by
+    ``tile`` keys (:func:`fits` says which shapes).  ``interpret`` None runs
+    the kernels compiled on a TPU and interpreted elsewhere."""
+    B, L, H, Dk = q.shape
+    D = v.shape[-1]
     if not fits(L, D, tile):
         raise ValueError(f"no tiles of {tile} fit a context of {L} with "
-                         f"heads of {D}")
+                         f"values of {D}")
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    K = -(-Dk // LANES) * LANES             # the q.k width the kernels run
+    if K != Dk:
+        pad = ((0, 0), (0, 0), (0, 0), (0, K - Dk))
+        q, k = jnp.pad(q, pad), jnp.pad(k, pad)
     flat = lambda x: x.reshape(B, L, -1)
-    return _attention(flat(q), flat(k), flat(v), D, window, tile,
-                      bool(interpret))
+    return _attention(flat(q), flat(k), flat(v), K, D, 1.0 / math.sqrt(Dk),
+                      window, tile, bool(interpret))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _attention(q, k, v, D, window, tile, interpret):
-    return _forward(q, k, v, D, window, tile, interpret)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _attention(q, k, v, K, D, scale, window, tile, interpret):
+    return _forward(q, k, v, K, D, scale, window, tile, interpret)[0]
 
 
-def _attention_fwd(q, k, v, D, window, tile, interpret):
-    o, lse = _forward(q, k, v, D, window, tile, interpret)
+def _attention_fwd(q, k, v, K, D, scale, window, tile, interpret):
+    o, lse = _forward(q, k, v, K, D, scale, window, tile, interpret)
     # named, so that a rematerialized layer that keeps its output keeps
     # this too, and its backward does not run the forward kernel again
     return o, (q, k, v, checkpoint_name(lse, "attention_lse"))
 
 
-def _attention_bwd(D, window, tile, interpret, res, do):
+def _attention_bwd(K, D, scale, window, tile, interpret, res, do):
     with stage_scope("attention"):
-        return _backward(*res, do, D, window, tile, interpret)
+        return _backward(*res, do, K, D, scale, window, tile, interpret)
 
 
 _attention.defvjp(_attention_fwd, _attention_bwd)

@@ -88,12 +88,15 @@ _STAGE_SET = frozenset(STAGES)
 # ``client_step`` the vmapped client update, ``craft`` the attacker's
 # rewrite of rows [0, f); ``gram`` the pairwise squared distances
 # (ops/distances.py), ``select`` Krum's scoring, sort / top-k and argmin.
-# Inside ``client_step``, a sequence model's own two (models/sequence.py):
+# Inside ``client_step``, a sequence model's own (models/sequence.py):
 # ``attention`` (scores, mask, softmax, values; forward and backward, both
-# kinds of layer) and ``experts`` (top-k, dispatch, the grouped products,
-# combine; not the router's matmul).  With innermost booking
-# ``client_step`` then holds the rest: projections, norms, head and loss,
-# the row's write.
+# kinds of layer), ``experts`` (top-k, dispatch, the grouped products,
+# combine; not the router's matmul), and in a latent-attention model
+# ``latent`` (the latent's down-projection, norm and up-projection, the
+# rotation and broadcast of the q.k part with positions) and
+# ``shared_experts`` (the experts every token passes).  With innermost
+# booking ``client_step`` then holds the rest: projections, norms, dense
+# layers, head and loss, the row's write.
 # Only the measured booking (utils/walls.py) reads them:
 # :func:`stage_attribution` and ``hlo_stage_map`` filter on
 # :data:`STAGES`, so an op under ``deliver/gather`` still books to
@@ -101,6 +104,7 @@ _STAGE_SET = frozenset(STAGES)
 SUBSTAGES = {"gather": "deliver", "client_step": "deliver",
              "craft": "deliver",
              "attention": "deliver", "experts": "deliver",
+             "latent": "deliver", "shared_experts": "deliver",
              "gram": "tier1_aggregate", "select": "tier1_aggregate"}
 
 _STAGE_ENV = "FL_STAGE_SCOPES"
@@ -432,10 +436,12 @@ _FRAME_TABLE_RE = None
 
 def canonical_hlo(text: str) -> str:
     """The computation-identity view of an HLO module text: op metadata
-    and the header's stack-frame tables stripped and every
-    %value/%computation name rewritten to its
-    first-appearance ordinal.  Two programs are computation-identical
-    iff their canonical texts match — op_name scopes, source lines and
+    and the header's stack-frame tables stripped, every Pallas kernel's
+    serialized body replaced by a hash of its text without source
+    locations (:func:`_kernel_body`), and every %value/%computation name
+    rewritten to its first-appearance ordinal.  Two programs are
+    computation-identical iff their canonical texts match — op_name
+    scopes, source lines (the program's and its kernels') and
     instruction-id drift are all erased, while opcodes, shapes, operand
     wiring and attributes all still compare."""
     import re
@@ -449,12 +455,37 @@ def canonical_hlo(text: str) -> str:
             r"^(?:FileNames|FunctionNames|FileLocations|StackFrames)\n"
             r"(?:\d+ .*\n)*\n?", re.MULTILINE)
     stripped = _FRAME_TABLE_RE.sub("", _METADATA_RE.sub("", text))
+    stripped = re.sub(r'"body":"([A-Za-z0-9+/=]+)"', _kernel_body, stripped)
     names: dict = {}
 
     def rename(m):
         return names.setdefault(m.group(0), f"%v{len(names)}")
 
     return _VALUE_NAME_RE.sub(rename, stripped)
+
+
+def _kernel_body(m) -> str:
+    """A ``tpu_custom_call``'s body (base64 of the kernel's MLIR
+    bytecode, which carries the file and line of every operation traced
+    into it) as the sha256 of its text printed without locations; a body
+    that does not parse as MLIR as it is."""
+    import base64
+    import hashlib
+
+    from jax._src.interpreters import mlir
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+
+    ctx = mlir.make_ir_context()
+    tpu.register_dialect(ctx)
+    ctx.allow_unregistered_dialects = True
+    with ctx:
+        try:
+            module = ir.Module.parse(base64.b64decode(m.group(1)))
+        except ir.MLIRError:
+            return m.group(0)
+        body = module.operation.get_asm(enable_debug_info=False)
+    return '"body":"%s"' % hashlib.sha256(body.encode()).hexdigest()
 
 
 def hlo_fingerprint(text: str) -> str:

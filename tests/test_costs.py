@@ -454,10 +454,12 @@ def test_stage_scopes_are_metadata_only(tmp_path):
         on, off = compiled_text(True), compiled_text(False)
     assert costs.hlo_fingerprint(on) == costs.hlo_fingerprint(off)
     assert costs.canonical_hlo(on) == costs.canonical_hlo(off)
-    # every round has these; "attention" and "experts" only a sequence
-    # model's (tests/test_sequence_model.py holds them to the same proof)
+    # every round has these; "attention", "experts", "latent" and
+    # "shared_experts" only a sequence model's (tests/test_sequence_model.py
+    # and tests/test_mla_model.py hold them to the same proof)
     scopes = costs.STAGES[:1] + costs.STAGES[3:4] + tuple(
-        s for s in costs.SUBSTAGES if s not in ("attention", "experts"))
+        s for s in costs.SUBSTAGES if s not in (
+            "attention", "experts", "latent", "shared_experts"))
     assert set(scopes) >= {"deliver", "tier1_aggregate", "gather",
                            "client_step", "craft", "gram", "select"}
     for token in scopes:
@@ -630,3 +632,24 @@ def test_bench_result_embeds_env_and_cache(tmp_path):
         assert set(rec["compile_cache"]) == {"hits", "misses"}
     finally:
         bench.RESULT.clear()
+
+
+def test_a_kernels_body_is_compared_without_its_source_locations():
+    """A Pallas kernel's serialized body carries the file and line of every
+    operation in it: the canonical text hashes it printed without them, so
+    the same kernel traced from another line or checkout compares equal; a
+    body that is not MLIR is left as it is."""
+    import base64
+
+    def text(module):
+        body = base64.b64encode(module.encode()).decode()
+        return ('%c = f32[8]{0} custom-call(f32[8]{0} %a), custom_call_target='
+                '"tpu_custom_call", backend_config={"custom_call_config":'
+                '{"body":"' + body + '"}}')
+
+    here = 'module {\n} loc("/a/flash_attention.py":10:4)'
+    there = 'module {\n} loc("/b/flash_attention.py":99:4)'
+    assert costs.canonical_hlo(text(here)) == costs.canonical_hlo(text(there))
+    assert "flash_attention" not in costs.canonical_hlo(text(here))
+    assert costs.canonical_hlo(text("not mlir")) == costs.canonical_hlo(
+        text("not mlir")) != costs.canonical_hlo(text("not mlir either"))

@@ -180,3 +180,65 @@ def test_the_fused_model_is_the_blocked_one(monkeypatch):
     assert float(l1) == pytest.approx(float(l0), rel=1e-6)
     for a, b in zip(jax.tree.leaves(g1), jax.tree.leaves(g0)):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-6)
+
+
+@pytest.mark.parametrize("qk,dv,heads,kv", [
+    (192, 128, 2, 2), (64, 128, 4, 2), (128, 256, 2, 1)],
+    ids=["latent", "narrow-qk", "wide-v"])
+@pytest.mark.parametrize("window", [256, None], ids=["window", "global"])
+def test_a_qk_width_apart_from_the_value_width_is_the_blocked_path(
+        qk, dv, heads, kv, window, monkeypatch):
+    """q.k of a width that is no whole number of lanes (latent attention's
+    192, zero-padded to 256 inside) or narrower than one, against values of
+    another: forward and every gradient as the blocked path gives them,
+    products in f32 so that only accumulation order parts the two."""
+    monkeypatch.setattr(F, "MXU", jnp.float32)
+    ks = jax.random.split(jax.random.key(qk + dv), 4)
+    q = jax.random.normal(ks[0], (2, L, heads, qk))
+    k = jax.random.normal(ks[1], (2, L, kv, qk))
+    v = jax.random.normal(ks[2], (2, L, kv, dv))
+    w = jax.random.normal(ks[3], (2, L, heads * dv))
+    got, want = fused(q, k, v, window), blocked(q, k, v, window)
+    assert got.shape == want.shape == (2, L, heads * dv)
+    assert rel(got, want) < 1e-5
+    grads = [jax.grad(lambda *a, f=f: jnp.sum(f(*a, window) * w),
+                      argnums=(0, 1, 2))(q, k, v) for f in (fused, blocked)]
+    for a, b in zip(*grads):
+        assert a.shape == b.shape and a.dtype == jnp.float32
+        assert rel(a, b) < 1e-4
+
+
+def test_the_latent_model_takes_the_kernels_at_its_widths(monkeypatch):
+    """On a TPU the published latent-attention sizes take the fused path in
+    both runs (the dense layer, the expert layers), and the record carries
+    the q.k width apart from the value width."""
+    monkeypatch.setattr(S, "_on_tpu", lambda: True)
+    model = S.make_sequence_model("t", S.KANANA_EP16)
+    params = jax.eval_shape(model.init, jax.random.key(0))
+    x = jax.ShapeDtypeStruct((1, 8192), jnp.int32)
+    got = _new_records(lambda: jax.eval_shape(model.loss, params, x, x))
+    assert [(r["path"], r["layers"], r["qk_dim"], r["v_dim"])
+            for r in got] == [("fused", 1, 192, 128), ("fused", 4, 192, 128)]
+    assert got[0]["pairs_ratio"] == pytest.approx(35_651_584 / 33_558_528)
+
+
+# the tiny latent model with values of one lane width (q.k of 128 + 64)
+WIDE_MLA = S.MLA_TINY._replace(head_dim=128, rope_dim=64, query_block=128)
+
+
+def test_the_fused_latent_model_is_the_blocked_one(monkeypatch):
+    """The tiny wide latent model's loss and gradient on both paths,
+    products in f32."""
+    monkeypatch.setattr(F, "MXU", jnp.float32)
+    model = S.make_sequence_model("t", WIDE_MLA)
+    params = model.init(jax.random.key(0))
+    x = jax.random.randint(jax.random.key(1), (1, 2 * WIDE_MLA.query_block),
+                           0, WIDE_MLA.vocab)
+    got = {}
+    for on in (False, True):
+        monkeypatch.setattr(S, "_on_tpu", lambda on=on: on)
+        got[on] = jax.jit(jax.value_and_grad(model.loss))(params, x, x)
+    (l0, g0), (l1, g1) = got[False], got[True]
+    assert float(l1) == pytest.approx(float(l0), rel=1e-6)
+    for a, b in zip(jax.tree.leaves(g1), jax.tree.leaves(g0)):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-6)

@@ -27,6 +27,7 @@ from attacking_federate_learning_tpu.data.datasets import load_dataset
 from attacking_federate_learning_tpu.data.partition import (
     make_shards, round_batch_indices
 )
+from attacking_federate_learning_tpu.utils import costs
 
 _DS = {}
 
@@ -511,3 +512,79 @@ def test_v5e_compiles_the_fused_attention_at_the_cells_widths(one_chip):
             assert re.findall(r"\b(\w+)\[", result) and set(
                 re.findall(r"\b(\w+)\[", result)) == {"f32"}, (name, result)
         assert compiled.memory_analysis().temp_size_in_bytes < 200e6
+
+
+def _smallthinker_client_program(length, sharding=None):
+    """SmallThinker's scanned client step (8 silos into a bf16 wire, the
+    sequence cell's program less the defense) lowered from shapes."""
+    from attacking_federate_learning_tpu.core import client
+    from attacking_federate_learning_tpu.models.base import get_model
+    from attacking_federate_learning_tpu.utils.flatten import make_flattener
+
+    model = get_model("smallthinker_21b_a3b_ep8")
+    flat = make_flattener(jax.eval_shape(model.init, jax.random.key(0)))
+    step = client.make_scanned_client_grad_fn(model, flat, jnp.bfloat16)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    return jax.jit(step).lower(arg((flat.dim,), jnp.float32),
+                               arg((8, 1, length), jnp.int32),
+                               arg((8, 1, length), jnp.int32))
+
+
+def test_smallthinkers_client_program_is_the_one_before_latent_attention(
+        one_chip, monkeypatch):
+    """The latent-attention model shares the sequence model's code, the
+    attention kernels and the expert block with SmallThinker; SmallThinker's
+    program is what it was before the second form came (the digests were
+    read on that tree): as lowered at the cell's length, and as the v5e
+    compiler builds it at 1,024 tokens with the TPU's paths (fused
+    attention, the wire's select)."""
+    from attacking_federate_learning_tpu.models import sequence as S
+    from attacking_federate_learning_tpu.utils import flatten
+
+    lowered = _smallthinker_client_program(8192).as_text(dialect="hlo")
+    assert costs.hlo_fingerprint(lowered) == (
+        "2cbcda6e1b5d2576285ed2cf7c6bd698"
+        "97032fb5abbc7706c2d8e085cf1f6faf")
+    monkeypatch.setattr(S, "_on_tpu", lambda: True)
+    monkeypatch.setattr(flatten, "_platform", lambda: "tpu")
+    with _no_persistent_cache():
+        compiled = _smallthinker_client_program(1024, one_chip).compile()
+    assert costs.hlo_fingerprint(compiled.as_text()) == (
+        "dec7c83409f59771a4fdfa64c4148c7b"
+        "33ca297f3a03b7b1eaa6878d13d93861")
+
+
+def test_v5e_compiles_the_fused_latent_attention_at_the_cells_widths(
+        one_chip):
+    """The kernels at the latent-attention cell's shapes (8,192 tokens, 32
+    heads, q.k of 192 padded to 256 lanes, values of 128, tiles of 512),
+    forward and backward: Mosaic builds each, every operand and result at
+    their boundary is f32, and the temporaries are the padded q and k and
+    their gradients (4 x 268 MB) and no more: a query tile's scores alone
+    would be 537 MB, the whole (queries, keys) tensor 8.6 GB."""
+    from attacking_federate_learning_tpu.ops import flash_attention as F
+
+    B, L, H = 1, 8192, 32
+
+    def arg(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    q, k, v, w = (arg((B, L, H, 192)), arg((B, L, H, 192)),
+                  arg((B, L, H, 128)), arg((B, L, H * 128)))
+    grad = jax.grad(lambda q, k, v, w: jnp.sum(F.attention(
+        q, k, v, None, 512, interpret=False) * w), argnums=(0, 1, 2))
+    with _no_persistent_cache():
+        compiled = jax.jit(grad).lower(q, k, v, w).compile()
+    calls = re.findall(r"%(?:jvp_)?(flash_[a-z]+)\S* = (.*?) custom-call\(.*"
+                       r"custom_call_target=\"tpu_custom_call\"",
+                       compiled.as_text())
+    assert sorted(c[0] for c in calls) == [
+        "flash_dkv", "flash_dq", "flash_fwd"], calls
+    for name, result in calls:
+        assert set(re.findall(r"\b(\w+)\[", result)) == {"f32"}, (name,
+                                                                 result)
+    padded = B * L * H * 256 * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * padded + 2**20

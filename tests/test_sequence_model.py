@@ -371,4 +371,4 @@ def test_the_published_share_has_the_wire_its_configuration_states():
     assert make_flattener(shapes).dim == ref.WIRE_DIM == 370_547_200
     assert [tuple(leaf.shape) for leaf in jax.tree.leaves(shapes)] == [
         shape for _, shape in ref.shapes_of(ref.SIZES)]
-    assert model.sizes.runs == [(0, 0, 1), (1, 1, 3)]
+    assert model.sizes.runs == [(0, 0, 0, 1), (1, 1, 0, 3)]
