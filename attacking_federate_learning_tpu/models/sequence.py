@@ -317,9 +317,15 @@ def _note_path(length, window, layers, s: SeqSizes):
               "window": window, "qk_dim": s.head_dim + s.rope_dim,
               "v_dim": s.head_dim}
     if takes_kernel(length, s.head_dim, s.query_block):
-        record.update(path="fused", pairs_ratio=(
-            flash_attention.computed_pairs(length, window, s.query_block)
-            / flash_attention.visible_pairs(length, window)))
+        heads = s.heads // s.kv_heads
+        record.update(
+            path="fused", pairs_ratio=(
+                flash_attention.computed_pairs(length, window, s.query_block)
+                / flash_attention.visible_pairs(length, window)),
+            groups_per_step=flash_attention.groups_per_step(heads,
+                                                            s.kv_heads),
+            grid_steps=flash_attention.grid_steps(
+                length, window, s.query_block, heads, s.kv_heads))
     note_attention(record)
 
 

@@ -329,9 +329,11 @@ def trace_lower_log() -> list:
 def note_attention(record: dict) -> None:
     """Log, at trace time, which path a run of attention layers took
     (models/sequence.py: ``{"path": "fused" | "blocked", "layers",
-    "tokens", "window"}``, and for the fused path ``pairs_ratio``, the
-    (query, key) pairs its tiles compute over those the mask leaves
-    visible).  The next backend compile's :func:`compile_log` record
+    "tokens", "window", "qk_dim", "v_dim"}``, and for the fused path
+    ``pairs_ratio``, the (query, key) pairs its tiles compute over those
+    the mask leaves visible, ``groups_per_step``, the kv groups a grid step
+    of its kernels carries, and ``grid_steps``, the three kernels' steps for
+    one context through one layer).  The next backend compile's :func:`compile_log` record
     carries the records traced for it under ``"attention"``."""
     _CacheCounters.attention.append(record)
     _CacheCounters.pending.append(record)

@@ -1,8 +1,9 @@
 """The fused attention kernels (ops/flash_attention.py) in Pallas interpret
 mode on the CPU: against the blocked path of ``models/sequence.py`` forward
-and backward, at the window's edge, the tiles they compute, which path a
-model takes (the cost log) and what a rematerialized layer keeps.  Their
-compile for the chip is in tests/test_gather_layout.py."""
+and backward, at the window's edge, several kv groups a grid step against
+one, the tiles and grid steps they compute, which path a model takes (the
+cost log) and what a rematerialized layer keeps.  Their compile for the
+chip is in tests/test_gather_layout.py."""
 
 import jax
 import jax.numpy as jnp
@@ -89,6 +90,67 @@ def test_the_tiles_computed_at_the_cells_shape():
     assert not F.fits(24, 16, 8) and not F.fits(8192, 128, 384)
 
 
+@pytest.mark.parametrize("heads,kv,qk,window", [
+    (8, 8, 192, None), (8, 8, 192, 256), (8, 4, 128, None)],
+    ids=["one-head-a-group", "one-head-a-group-window", "two-heads-a-group"])
+def test_groups_a_step_together_are_one_group_a_step_bit_for_bit(
+        heads, kv, qk, window, monkeypatch):
+    """Several kv groups a grid step (latent attention's one query head a
+    group, q.k 192 padded to 256 against values of 128; two heads a group)
+    give every head the same products in the same key-tile order as one
+    group a step: the output and dq, dk, dv are equal to the bit, with the
+    products' operands in bf16 as shipped."""
+    ks = jax.random.split(jax.random.key(heads * kv + qk), 4)
+    q = jax.random.normal(ks[0], (1, L, heads, qk))
+    k = jax.random.normal(ks[1], (1, L, kv, qk))
+    v = jax.random.normal(ks[2], (1, L, kv, D))
+    w = jax.random.normal(ks[3], (1, L, heads * D))
+    R = heads // kv
+
+    def run():
+        grad = jax.grad(lambda *a: jnp.sum(fused(*a, window) * w),
+                        argnums=(0, 1, 2))
+        grids = {name: grid[1] for name, grid
+                 in _kernels(jax.make_jaxpr(grad)(q, k, v).jaxpr)}
+        return fused(q, k, v, window), grad(q, k, v), grids
+
+    h = F.groups_per_step(R, kv)
+    assert h > 1 and h * R <= F.HEADS_PER_STEP
+    out, grads, grids = run()
+    assert grids == dict.fromkeys(("flash_fwd", "flash_dq", "flash_dkv"),
+                                  kv // h)
+    monkeypatch.setattr(F, "HEADS_PER_STEP", R)     # one group a step
+    out1, grads1, grids1 = run()
+    assert set(grids1.values()) == {kv}
+    np.testing.assert_array_equal(out, out1)
+    for a, b in zip(grads, grads1):
+        np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("heads,groups,want", [
+    (1, 32, 8), (7, 4, 1), (1, 12, 6), (2, 12, 4), (3, 4, 2), (1, 7, 7),
+    (16, 2, 1), (1, 1, 1)])
+def test_a_step_carries_the_most_groups_that_divide_within_the_cap(
+        heads, groups, want, monkeypatch):
+    """The largest divisor of the kv groups whose query heads stay within
+    the cap of 8, and one group where even two exceed it (SmallThinker's 7
+    heads a group, 4 groups)."""
+    monkeypatch.setattr(F, "HEADS_PER_STEP", 8)
+    assert F.groups_per_step(heads, groups) == want
+
+
+def test_the_grid_steps_at_the_cells_shapes():
+    """A layer and context of 8,192 in tiles of 512: latent attention's 32
+    groups of one head take 32,768 steps one group a step, 32,768 / cap
+    carried together; SmallThinker's 4 groups of 7 (one a step) 4,096 on
+    the global layer and 2,304 on a window layer of 4,096."""
+    cap = F.HEADS_PER_STEP
+    assert F.groups_per_step(1, 32) == cap
+    assert F.grid_steps(8192, None, 512, 1, 32) == 32_768 // cap
+    assert F.grid_steps(8192, None, 512, 7, 4) == 4 * 4 * 16 * 16
+    assert F.grid_steps(8192, 4096, 512, 7, 4) == 4 * 4 * 16 * 9
+
+
 def _new_records(run):
     before = len(costs.attention_log())
     run()
@@ -125,16 +187,22 @@ def test_the_published_sizes_take_the_kernels_and_the_tiny_model_does_not(
 WIDE = S.SEQ_TINY._replace(head_dim=128, query_block=128, window=128)
 
 
+def _kernels(jaxpr):
+    """(name, grid) of every ``pallas_call`` in a jaxpr and every jaxpr
+    inside it."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append((eqn.params["name"],
+                          eqn.params["grid_mapping"].grid))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _kernels(sub)
+    return found
+
+
 def _forward_kernels(jaxpr):
     """``flash_fwd`` calls in a jaxpr and every jaxpr inside it."""
-    count = 0
-    for eqn in jaxpr.eqns:
-        if (eqn.primitive.name == "pallas_call"
-                and eqn.params["name"] == "flash_fwd"):
-            count += 1
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            count += _forward_kernels(sub)
-    return count
+    return sum(name == "flash_fwd" for name, _ in _kernels(jaxpr))
 
 
 @pytest.mark.parametrize("saved,kernels", [
@@ -224,6 +292,27 @@ def test_the_latent_model_takes_the_kernels_at_its_widths(monkeypatch):
 
 # the tiny latent model with values of one lane width (q.k of 128 + 64)
 WIDE_MLA = S.MLA_TINY._replace(head_dim=128, rope_dim=64, query_block=128)
+
+
+def test_the_record_counts_the_groups_a_grid_step_carries(monkeypatch):
+    """Traced for a TPU, the latent model's records carry its kv groups a
+    step (the cap at the published 32 heads, all 4 of the tiny wide
+    model's) and the kernels' grid steps a layer and context;
+    SmallThinker's one group a step."""
+    monkeypatch.setattr(S, "_on_tpu", lambda: True)
+
+    def trace(s, length):
+        model = S.make_sequence_model("t", s)
+        params = jax.eval_shape(model.init, jax.random.key(0))
+        x = jax.ShapeDtypeStruct((1, length), jnp.int32)
+        return [(r["groups_per_step"], r["grid_steps"]) for r in
+                _new_records(lambda: jax.eval_shape(model.loss, params, x,
+                                                    x))]
+
+    cap = F.HEADS_PER_STEP
+    assert trace(S.KANANA_EP16, 8192) == [(cap, 32_768 // cap)] * 2
+    assert trace(WIDE_MLA, 256) == [(4, 4 * 1 * 2 * 2)] * 2
+    assert trace(S.SMALLTHINKER_EP8, 8192) == [(1, 4_096), (1, 2_304)]
 
 
 def test_the_fused_latent_model_is_the_blocked_one(monkeypatch):

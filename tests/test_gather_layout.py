@@ -561,10 +561,12 @@ def test_v5e_compiles_the_fused_latent_attention_at_the_cells_widths(
         one_chip):
     """The kernels at the latent-attention cell's shapes (8,192 tokens, 32
     heads, q.k of 192 padded to 256 lanes, values of 128, tiles of 512),
-    forward and backward: Mosaic builds each, every operand and result at
-    their boundary is f32, and the temporaries are the padded q and k and
-    their gradients (4 x 268 MB) and no more: a query tile's scores alone
-    would be 537 MB, the whole (queries, keys) tensor 8.6 GB."""
+    forward and backward, each grid step carrying the cap's kv groups of
+    one head: Mosaic builds each (the wider blocks' fast memory fits),
+    every operand and result at their boundary is f32, and the temporaries
+    are the padded q and k and their gradients (4 x 268 MB) and no more: a
+    query tile's scores alone would be 537 MB, the whole (queries, keys)
+    tensor 8.6 GB."""
     from attacking_federate_learning_tpu.ops import flash_attention as F
 
     B, L, H = 1, 8192, 32
@@ -576,6 +578,13 @@ def test_v5e_compiles_the_fused_latent_attention_at_the_cells_widths(
                   arg((B, L, H, 128)), arg((B, L, H * 128)))
     grad = jax.grad(lambda q, k, v, w: jnp.sum(F.attention(
         q, k, v, None, 512, interpret=False) * w), argnums=(0, 1, 2))
+    assert F.groups_per_step(1, H) == F.HEADS_PER_STEP > 1
+    grids = [e.params["grid_mapping"].grid
+             for e in jax.jit(grad).trace(q, k, v, w).jaxpr.eqns
+             if e.primitive.name == "pallas_call"]
+    steps = H // F.HEADS_PER_STEP
+    assert sorted(grids) == [(B, steps, 16, 16), (B, steps, 16, 16),
+                             (B, steps, 16, 32)], grids
     with _no_persistent_cache():
         compiled = jax.jit(grad).lower(q, k, v, w).compile()
     calls = re.findall(r"%(?:jvp_)?(flash_[a-z]+)\S* = (.*?) custom-call\(.*"

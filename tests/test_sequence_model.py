@@ -325,7 +325,12 @@ def test_scopes_are_metadata_only_and_book_forward_and_backward(
     booking finds them as plain path components on forward,
     rematerialized and backward operations alike: on the blocked path,
     and on the fused kernels (in interpret mode, on heads of 128 and a
-    context of one tile), whose backward is a ``custom_vjp`` rule."""
+    context of two tiles), whose backward is a ``custom_vjp`` rule.  The
+    kernels' grids must be more than one step: the two kv groups of this
+    model share a step, and with one tile a kernel would be a loop of one
+    trip, which XLA's CPU compiler removes, and the constants and
+    broadcasts it hoists from it carry a path without ``attention`` (a
+    model with one kv group did so before groups shared a step)."""
     from conftest import metadata_in_cache_key
     from perfbench.tracereduce import hlo_scope_paths, innermost
 
@@ -334,7 +339,7 @@ def test_scopes_are_metadata_only_and_book_forward_and_backward(
         monkeypatch.setattr(S, "_on_tpu", lambda: True)
         monkeypatch.setattr(S, "SEQ_TINY", S.SEQ_TINY._replace(
             head_dim=128, query_block=128))
-        over["seq_len"] = 128
+        over["seq_len"] = 256
 
     def text(enabled):
         prev = costs.set_stage_scopes(enabled)
